@@ -22,10 +22,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import jax  # noqa: E402
 
 from sparknet_tpu.models import lenet  # noqa: E402

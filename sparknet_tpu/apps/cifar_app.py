@@ -69,8 +69,8 @@ def main(argv=None) -> dict[str, Any]:
     ap.add_argument("--log-dir", default=".")
     args = ap.parse_args(argv)
 
-    from ..utils.platform import honor_platform_env
-    honor_platform_env()
+    from ..utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     log = PhaseLogger(os.path.join(
         args.log_dir, f"training_log_{int(time.time())}.txt"))

@@ -93,8 +93,8 @@ def main(argv=None) -> dict[str, Any]:
     ap.add_argument("--log-dir", default=".")
     args = ap.parse_args(argv)
 
-    from ..utils.platform import honor_platform_env
-    honor_platform_env()
+    from ..utils.compile_cache import use_compile_cache
+    use_compile_cache()
     crop = args.crop or (227 if args.model in ("alexnet", "caffenet") else 224)
 
     log = PhaseLogger(os.path.join(

@@ -17,7 +17,7 @@ Math (reference: caffe/src/caffe/layers/lrn_layer.cpp):
 
 Layout: (N, C, H, W) -> grid over (batch, spatial tiles), block (C, TS)
 so the windowed sum runs along sublanes and the spatial axis rides the
-128-wide lanes.  Runs in interpreter mode off-TPU (tests/CPU rig).
+128-wide lanes.
 """
 
 from __future__ import annotations
@@ -30,9 +30,10 @@ from jax.experimental import pallas as pl
 
 _TS = 512  # spatial tile (lanes); f32 block C×TS stays well under VMEM
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Every call below is the compiled Mosaic kernel.  The CPU tests that
+# check the kernels' math patch this to True (the Pallas interpreter);
+# nothing else does, so what runs never depends on the backend JAX found.
+_INTERPRET = False
 
 
 def _window_sum(v: jnp.ndarray, pre: int, post: int) -> jnp.ndarray:
@@ -120,7 +121,8 @@ def _fwd_call(x, size, alpha, beta, k, relu):
         grid=grid,
         in_specs=[spec],
         out_specs=(spec, spec),
-        interpret=_interpret(),
+        interpret=_INTERPRET,
+        name="relu_lrn_fwd",
     )(xs)
     return y.reshape(x.shape), scale.reshape(x.shape)
 
@@ -144,7 +146,8 @@ def relu_lrn_across_channels(x, size: int, alpha: float, beta: float,
         grid=grid,
         in_specs=[spec],
         out_specs=spec,
-        interpret=_interpret(),
+        interpret=_INTERPRET,
+        name="relu_lrn_infer",
     )(xs)
     return y.reshape(x.shape)
 
@@ -165,7 +168,8 @@ def _lrn_vjp_bwd(size, alpha, beta, k, relu, res, dy):
         grid=grid,
         in_specs=[spec, spec, spec],
         out_specs=spec,
-        interpret=_interpret(),
+        interpret=_INTERPRET,
+        name="relu_lrn_bwd",
     )(x.reshape(n, c, h * w), scale.reshape(n, c, h * w),
       dy.reshape(n, c, h * w))
     return (dx.reshape(x.shape),)
@@ -372,7 +376,8 @@ def _maxpool_bwd_call(x, dy, kh, kw, sh, sw, ph, pw, oh, ow):
                   pl.BlockSpec((None, ct, oh, ow),
                                lambda i, j: (i, j, 0, 0))],
         out_specs=pl.BlockSpec((None, ct, h, w), lambda i, j: (i, j, 0, 0)),
-        interpret=_interpret(),
+        interpret=_INTERPRET,
+        name="maxpool_bwd",
     )(x, dy)
 
 

@@ -496,6 +496,17 @@ def lrn_window_sum(sq, pre: int, post: int, use_cumsum: bool | None = None):
     c_dim = sq.shape[1]
     if use_cumsum is None:
         use_cumsum = lrn_use_cumsum(c_dim)
+    if sq.shape[0] < 8 and jax.default_backend() == "tpu":
+        # XLA's TPU SpaceToBatchConverter rewrites convolutions whose
+        # batch is below 8 and carries the rewrite into their users.
+        # Carried into a window sum over channels it fails the compile
+        # (reduce_window: "Binary op with incompatible shapes:
+        # bf16[27,8,4,96] and bf16[27,8,4,92]" at CaffeNet norm1 in bf16,
+        # every batch from 1 to 7) or aborts the compiler (cumsum: check
+        # at space_to_batch_converter.cc:2824); libtpu 0.0.34.  A barrier
+        # on the operand stops the rewrite here; batch 8 and up is not
+        # rewritten and compiles as before.
+        sq = lax.optimization_barrier(sq)
     if sq.ndim == 4 and use_cumsum:
         cs = jnp.cumsum(sq.astype(jnp.float32), axis=1)
         cs = jnp.concatenate([jnp.zeros_like(cs[:, :1]), cs], axis=1)

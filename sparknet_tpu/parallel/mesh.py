@@ -35,6 +35,9 @@ def make_mesh(n_devices: int | None = None, *, model_parallel: int = 1,
     """
     devs = list(devices if devices is not None else jax.devices())
     if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(
+                f"mesh of {n_devices} devices asked for, have {len(devs)}")
         devs = devs[:n_devices]
     n = len(devs)
     if n % model_parallel:

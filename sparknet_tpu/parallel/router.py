@@ -738,7 +738,14 @@ class ServingFleet:
 
     ``run_background()`` drives scheduler steps + polling on a daemon
     thread (the long-lived ``tools/serve.py --fleet`` posture); tests
-    and harnesses may instead call ``step()`` themselves."""
+    and harnesses may instead call ``step()`` themselves.
+
+    Replicas are separate processes and none is bound to a chip, while
+    a TPU belongs to one process at a time: on a ``tpu`` backend the
+    constructor refuses (``FleetError``) before anything is launched.
+    The tier simulates placement on CPU devices; on a chip, serve from
+    one process (``InferenceEngine``, ``tools/serve.py`` without
+    ``--fleet``)."""
 
     def __init__(self, workdir: str, devices: int, *,
                  tenant: str = "serving", priority: int = 0,
@@ -747,7 +754,16 @@ class ServingFleet:
                  router_cfg: RouterConfig | None = None,
                  replica_timeout_s: float = 30.0,
                  scheduler=None, tick_s: float = 0.05, **sched_kw):
-        from .fleet import FleetScheduler
+        import jax
+        from .fleet import FleetError, FleetScheduler
+        if jax.default_backend() == "tpu":
+            raise FleetError(
+                "the serving fleet starts one process per replica and "
+                "binds none of them to a chip; a TPU belongs to one "
+                "process at a time, so replicas on a tpu backend would "
+                "fail or hang.  Run this tier under JAX_PLATFORMS=cpu, or "
+                "serve from one process (InferenceEngine, tools/serve.py "
+                "without --fleet)")
         self.workdir = os.path.abspath(workdir)
         self.sched = scheduler or FleetScheduler(self.workdir, devices,
                                                  **sched_kw)

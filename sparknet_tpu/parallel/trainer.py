@@ -44,7 +44,6 @@ import collections
 import dataclasses
 import glob
 import hashlib
-import inspect
 import json
 import os
 import sys
@@ -67,22 +66,6 @@ from .mesh import (
     CHIP_AXIS, DATA_AXIS, HOST_AXIS, make_mesh, make_pod_mesh,
     put_global_tree, replicated, stage_local,
 )
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# the replication-check kwarg was renamed check_rep -> check_vma across
-# jax versions; resolve whichever this jax spells, once at import
-_sm_params = inspect.signature(shard_map).parameters
-if "check_vma" in _sm_params:
-    _SM_NOCHECK: dict[str, bool] = {"check_vma": False}
-elif "check_rep" in _sm_params:
-    _SM_NOCHECK = {"check_rep": False}
-else:  # pragma: no cover
-    _SM_NOCHECK = {}
-del _sm_params
 
 
 @dataclasses.dataclass(frozen=True)
@@ -736,11 +719,11 @@ class DistributedTrainer:
         # batches: [tau, global_batch, ...] sharded on the batch axis
         batch_spec = P(None, self._batch_axes)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(params_in_spec, state_spec, P(), batch_spec, P(), P()),
             out_specs=(params_out_spec, state_spec, P()),
-            **_SM_NOCHECK,
+            check_vma=False,
         )
         # compressed path: the replicated input params stay live as the
         # delta reference for encode/decode — only the state may donate
@@ -1282,9 +1265,9 @@ class DistributedTrainer:
 
         params_spec = (P() if plan is None
                        else plan.spec_tree(self.params))
-        mapped = shard_map(fingerprint, mesh=self.mesh,
-                           in_specs=(params_spec,),
-                           out_specs=P(), **_SM_NOCHECK)
+        mapped = jax.shard_map(fingerprint, mesh=self.mesh,
+                               in_specs=(params_spec,),
+                               out_specs=P(), check_vma=False)
         return jax.jit(mapped)
 
     def _audit_groups(self) -> list[list[int]]:
@@ -1383,14 +1366,26 @@ class DistributedTrainer:
                 break
         if leaf is None:
             return
+        def words(x):
+            # a writable copy in C order: what a TPU hands back keeps the
+            # device's dimension order in its strides, and reshape(-1) of
+            # that is a copy the flip would be lost in
+            return np.array(x, order="C").reshape(-1).view(np.uint32)
+
         arrays = []
         for shard in leaf.addressable_shards:
             data = np.asarray(shard.data)
             if shard.device == target:
-                data = np.array(data)       # writable copy
-                flat = data.reshape(-1).view(np.uint32)
+                flat = words(data)
                 flat[0] ^= np.uint32(1 << 22)
-            arrays.append(jax.device_put(data, shard.device))
+                data = flat.view(data.dtype).reshape(data.shape)
+                on_target = jax.device_put(data, shard.device)
+                if words(on_target)[0] != flat[0]:
+                    raise RuntimeError(
+                        f"bitflip_params: the flip did not land on {target}")
+                arrays.append(on_target)
+            else:
+                arrays.append(jax.device_put(data, shard.device))
         self.params[name][0] = jax.make_array_from_single_device_arrays(
             leaf.shape, leaf.sharding, arrays)
 
@@ -1446,11 +1441,11 @@ class DistributedTrainer:
 
             params_spec = (P() if plan is None
                            else plan.spec_tree(self.params))
-            self._test_fwd = jax.jit(shard_map(
+            self._test_fwd = jax.jit(jax.shard_map(
                 worker, mesh=self.mesh,
                 in_specs=(params_spec, P(self._batch_axes),
                           P(self._batch_axes)),
-                out_specs=P(), **_SM_NOCHECK))
+                out_specs=P(), check_vma=False))
         sharding = NamedSharding(self.mesh, P(self._batch_axes))
         local_workers = max(self.n_workers // jax.process_count(), 1)
         totals: dict[str, Any] = {}

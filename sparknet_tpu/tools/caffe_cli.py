@@ -335,6 +335,8 @@ def main(argv=None) -> int:
     p = sub.add_parser("device_query")
     p.set_defaults(fn=_device_query)
     args = ap.parse_args(argv)
+    from ..utils.compile_cache import use_compile_cache
+    use_compile_cache()
     return args.fn(args)
 
 

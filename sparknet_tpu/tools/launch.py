@@ -68,7 +68,6 @@ def _proc_env(base: dict, coordinator: str, nprocs: int, pid: int,
     env["SPARKNET_PROC_ID"] = str(pid)
     if platform:
         env["JAX_PLATFORMS"] = platform
-        env["JAX_PLATFORM_NAME"] = platform
     if devices_per_proc:
         flags = env.get("XLA_FLAGS", "")
         env["XLA_FLAGS"] = (

@@ -52,8 +52,8 @@ def main(argv=None) -> None:
                     help="keep the profiler trace here (default: temp)")
     args = ap.parse_args(argv)
 
-    from ..utils.platform import honor_platform_env
-    honor_platform_env()
+    from ..utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp

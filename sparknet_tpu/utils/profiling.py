@@ -134,7 +134,6 @@ def step_cost_flops(solver, batch) -> float | None:
                                      jax.random.PRNGKey(1))
         cost = lowered.compile().cost_analysis()
         if cost:
-            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
             return float(cost.get("flops", 0.0)) or None
     except Exception as e:
         print(f"[profiling] cost_analysis unavailable: {e}", file=sys.stderr)
@@ -166,7 +165,6 @@ def fwd_cost_flops(jitted_fwd, *args) -> float | None:
         lowered = jitted_fwd.lower(*args)
         cost = lowered.compile().cost_analysis()
         if cost:
-            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
             return float(cost.get("flops", 0.0)) or None
     except Exception as e:
         print(f"[profiling] cost_analysis unavailable: {e}", file=sys.stderr)

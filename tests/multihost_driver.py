@@ -84,7 +84,6 @@ def main() -> None:
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.local_devices}").strip()
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 
     import jax
     jax.config.update("jax_platforms", "cpu")

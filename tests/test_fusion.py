@@ -367,10 +367,12 @@ def test_relu_lrn_reference_gradcheck(np_rng):
             assert num == pytest.approx(float(g[idx]), rel=2e-2, abs=1e-5)
 
 
-def test_pallas_relu_lrn_epilogue_matches_reference(np_rng):
+def test_pallas_relu_lrn_epilogue_matches_reference(np_rng, monkeypatch):
     """The Pallas kernel face (interpret mode on CPU) against the XLA
     reference: forward and VJP, relu folded and not."""
+    from sparknet_tpu.ops import pallas_kernels
     from sparknet_tpu.ops.pallas_kernels import relu_lrn_across_channels
+    monkeypatch.setattr(pallas_kernels, "_INTERPRET", True)
     from sparknet_tpu.ops.vision import relu_lrn_reference
     x = jnp.asarray(np_rng.normal(size=(2, 8, 3, 5)), jnp.float32)
     for relu in (False, True):

@@ -24,7 +24,6 @@ def _clean_env():
         if k.startswith("SPARKNET_"):
             env.pop(k)
     env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
     return env
 
 

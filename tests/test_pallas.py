@@ -1,6 +1,6 @@
-"""Pallas kernel tests (interpret mode on the CPU rig): the fused LRN
-must match the XLA lowering in forward and VJP, including through the
-LRNLayer dispatch."""
+"""Pallas kernel tests (the Pallas interpreter, asked for here: the CPU
+has no Mosaic compiler): the fused LRN must match the XLA lowering in
+forward and VJP, including through the LRNLayer dispatch."""
 
 import os
 
@@ -15,6 +15,12 @@ from sparknet_tpu.ops import get_layer_impl
 from sparknet_tpu.ops.pallas_kernels import lrn_across_channels
 
 SIZE, ALPHA, BETA, K = 5, 1e-2, 0.75, 1.0
+
+
+@pytest.fixture(autouse=True)
+def _interpreted(monkeypatch):
+    from sparknet_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_INTERPRET", True)
 
 
 def _xla_lrn(x, size=SIZE, alpha=ALPHA, beta=BETA, k=K):

@@ -41,6 +41,15 @@ def test_mesh_shapes():
         make_mesh(6, model_parallel=4)
 
 
+def test_mesh_of_more_devices_than_exist_is_an_error():
+    """Never a silently narrower mesh: `--workers 8` on four chips must
+    not train four workers."""
+    import jax
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match=f"have {n}"):
+        make_mesh(n + 1)
+
+
 @pytest.mark.parametrize("strategy", ["sync", "local_sgd"])
 def test_distributed_loss_decreases(strategy, np_rng):
     # lr 0.01: local_sgd workers see batch 4 — 0.05 genuinely diverges there

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import reference_path
 
 from sparknet_tpu.graph import Net
 from sparknet_tpu.ops import register_python_layer
@@ -190,12 +191,9 @@ def test_reference_pyloss_matches_formula():
     """The reference's own examples/pycaffe/layers/pyloss.py runs
     unmodified; its loss and gradients match the Euclidean-loss formula
     (and hence the C++ EuclideanLossLayer it mirrors)."""
-    import os
     import sys
     _install_shim()
-    layers_dir = "/root/reference/caffe/examples/pycaffe/layers"
-    if not os.path.isdir(layers_dir):
-        pytest.skip("reference pycaffe examples not present")
+    layers_dir = reference_path("caffe/examples/pycaffe/layers")
     if layers_dir not in sys.path:
         sys.path.insert(0, layers_dir)
     txt = """

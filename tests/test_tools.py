@@ -460,6 +460,42 @@ def test_bench_feed_overlap_nondegenerate(tmp_path):
     assert feed["bound"] == "feed"
 
 
+def test_compile_cache_placed_from_outside_else_in_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX (nothing is set
+    in code); otherwise the cache is <checkout>/.jax_cache, never a
+    temporary name."""
+    import jax
+
+    from sparknet_tpu.utils.compile_cache import use_compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert use_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """Off the chip chip_smoke.py exits non-zero before building anything,
+    names the backend it found, and prints no result."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")],
+        capture_output=True, timeout=120, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode != 0
+    assert b"'cpu'" in proc.stderr and b"not 'tpu'" in proc.stderr
+    assert proc.stdout.strip() == b""
+
+
 def test_bench_rejects_bad_dtype():
     import subprocess
     import sys

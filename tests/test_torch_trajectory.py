@@ -24,6 +24,7 @@ torch = pytest.importorskip("torch")
 import torch.nn.functional as F  # noqa: E402
 
 import jax  # noqa: E402
+from conftest import reference_path  # noqa: E402
 
 from sparknet_tpu.proto import (  # noqa: E402
     load_net_prototxt,
@@ -32,14 +33,14 @@ from sparknet_tpu.proto import (  # noqa: E402
 )
 from sparknet_tpu.solvers import Solver  # noqa: E402
 
-REF_NET = "/root/reference/caffe/examples/cifar10/cifar10_quick_train_test.prototxt"
+REF_NET = "caffe/examples/cifar10/cifar10_quick_train_test.prototxt"
 SOLVER_TXT = ("base_lr: 0.001\nmomentum: 0.9\nweight_decay: 0.004\n"
               'lr_policy: "fixed"\n')
 BATCH = 16
 
 
 def _make_solver(compute_dtype=None):
-    netp = load_net_prototxt(open(REF_NET).read())
+    netp = load_net_prototxt(open(reference_path(REF_NET)).read())
     netp = replace_data_layers(netp, BATCH, BATCH, 3, 32, 32)
     sp = load_solver_prototxt_with_net(SOLVER_TXT, netp)
     import jax.numpy as jnp
@@ -208,7 +209,7 @@ def test_multistep_lr_trajectory_tracks_torch(tmp_path):
     must agree with an independent transcription — rate factor at iter i
     is gamma^#{v : i >= v}."""
     n_steps = 75
-    netp = load_net_prototxt(open(REF_NET).read())
+    netp = load_net_prototxt(open(reference_path(REF_NET)).read())
     netp = replace_data_layers(netp, BATCH, BATCH, 3, 32, 32)
     sp = load_solver_prototxt_with_net(
         ("base_lr: 0.001\nmomentum: 0.9\nweight_decay: 0.004\n"
@@ -244,7 +245,7 @@ def test_multistep_lr_trajectory_tracks_torch(tmp_path):
 
 # -- BN-bearing net (cifar10_full_sigmoid_bn shape) --------------------------
 
-BN_NET = ("/root/reference/caffe/examples/cifar10/"
+BN_NET = ("caffe/examples/cifar10/"
           "cifar10_full_sigmoid_train_test_bn.prototxt")
 
 
@@ -333,7 +334,7 @@ def test_bn_trajectory_and_running_stats_track_torch(tmp_path):
     pinning caffe's BN update semantics end to end
     (batch_norm_layer.cpp + sgd_solver.cpp)."""
     n_steps = 60
-    netp = load_net_prototxt(open(BN_NET).read())
+    netp = load_net_prototxt(open(reference_path(BN_NET)).read())
     netp = replace_data_layers(netp, BATCH, BATCH, 3, 32, 32)
     sp = load_solver_prototxt_with_net(SOLVER_TXT, netp)
     solver = Solver(sp, seed=0)
@@ -588,7 +589,7 @@ def test_rule_trajectory_tracks_torch(rule, tmp_path):
     transcribed update must reproduce this framework's losses step for
     step (adam/adadelta/adagrad/nesterov/rmsprop_solver.cpp)."""
     n_steps = 30
-    netp = load_net_prototxt(open(REF_NET).read())
+    netp = load_net_prototxt(open(reference_path(REF_NET)).read())
     netp = replace_data_layers(netp, BATCH, BATCH, 3, 32, 32)
     sp = load_solver_prototxt_with_net(RULE_SOLVERS[rule], netp)
     solver = Solver(sp, seed=0)

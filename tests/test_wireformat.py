@@ -11,6 +11,7 @@ import sys
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import reference_path
 
 from sparknet_tpu.models import lenet
 from sparknet_tpu.proto import (
@@ -31,7 +32,7 @@ from sparknet_tpu.proto.caffemodel import (
 from sparknet_tpu.proto.wireformat import decode, encode
 from sparknet_tpu.solvers import Solver
 
-REF_PROTO = "/root/reference/caffe/src/caffe/proto/caffe.proto"
+REF_PROTO = "caffe/src/caffe/proto/caffe.proto"
 SOLVER_TXT = 'base_lr: 0.01\nmomentum: 0.9\nlr_policy: "fixed"\n'
 
 
@@ -40,8 +41,8 @@ SOLVER_TXT = 'base_lr: 0.01\nmomentum: 0.9\nlr_policy: "fixed"\n'
 # ---------------------------------------------------------------------------
 
 def test_solver_prototxt_binary_roundtrip():
-    text = open(
-        "/root/reference/caffe/models/bvlc_googlenet/solver.prototxt").read()
+    text = open(reference_path(
+        "caffe/models/bvlc_googlenet/solver.prototxt")).read()
     m = parse(text)
     raw = encode(m, "SolverParameter")
     sp = SolverParameter.from_pmsg(decode(raw, "SolverParameter"))
@@ -56,8 +57,8 @@ def test_solver_prototxt_binary_roundtrip():
 
 
 def test_net_prototxt_binary_roundtrip():
-    text = open(
-        "/root/reference/caffe/models/bvlc_alexnet/train_val.prototxt").read()
+    text = open(reference_path(
+        "caffe/models/bvlc_alexnet/train_val.prototxt")).read()
     m = parse(text)
     raw = encode(m, "NetParameter")
     got = NetParameter.from_pmsg(decode(raw, "NetParameter"))
@@ -342,7 +343,7 @@ def caffe_pb2(tmp_path_factory):
     if shutil.which("protoc") is None:
         pytest.skip("protoc not available")
     gen = tmp_path_factory.mktemp("protogen")
-    shutil.copy(REF_PROTO, gen / "caffe.proto")
+    shutil.copy(reference_path(REF_PROTO), gen / "caffe.proto")
     subprocess.run(["protoc", "--python_out=.", "caffe.proto"],
                    cwd=gen, check=True)
     sys.path.insert(0, str(gen))

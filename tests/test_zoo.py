@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import reference_path
 
 from sparknet_tpu.graph import Net
 from sparknet_tpu.proto import (
@@ -28,7 +29,10 @@ from sparknet_tpu.proto import (
     replace_data_layers,
 )
 
-REF = "/root/reference/caffe"
+
+def _ref(rel=""):
+    return reference_path(os.path.join("caffe", rel))
+
 
 # train/test net prototxts: path -> (channels, height, width) fed after the
 # data-layer swap.  Geometry is what the reference apps feed each model
@@ -127,7 +131,7 @@ BUILD_ONLY = {
 
 
 def _read(rel):
-    with open(os.path.join(REF, rel)) as f:
+    with open(_ref(rel)) as f:
         return f.read()
 
 
@@ -138,9 +142,9 @@ def test_zoo_inventory_complete():
              | set(SOLVERS))
     found = set()
     for root in ("models", "examples"):
-        for p in glob.glob(os.path.join(REF, root, "**", "*.prototxt"),
+        for p in glob.glob(os.path.join(_ref(root), "**", "*.prototxt"),
                            recursive=True):
-            found.add(os.path.relpath(p, REF))
+            found.add(os.path.relpath(p, _ref()))
     missing = found - known
     assert not missing, f"unclassified zoo prototxts: {sorted(missing)}"
 
@@ -196,7 +200,7 @@ def test_python_layer_net_runs(rel):
 
     from sparknet_tpu import pycaffe_compat
     pycaffe_compat.install()
-    layers_dir = os.path.join(REF, "examples/pycaffe/layers")
+    layers_dir = _ref("examples/pycaffe/layers")
     if layers_dir not in sys.path:
         sys.path.insert(0, layers_dir)
     netp = load_net_prototxt(_read(rel))
@@ -230,7 +234,7 @@ def test_zoo_serialize_roundtrip(rel):
     including V0/V1-format files which round-trip as upgraded V2."""
     from sparknet_tpu.proto import save_net_prototxt
 
-    net = load_net_prototxt(os.path.join(REF, rel))
+    net = load_net_prototxt(_ref(rel))
     back = load_net_prototxt(save_net_prototxt(net))
     assert [l.name for l in back.layer] == [l.name for l in net.layer]
     assert [l.type for l in back.layer] == [l.type for l in net.layer]

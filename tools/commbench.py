@@ -204,6 +204,7 @@ def main(argv=None) -> int:
         "commbench": True,   # ingest sniff key (perfledger.entries_from_any)
         "ok": not failures,
         "failures": failures,
+        "backend": jax.default_backend(),
         "rounds": args.rounds,
         "tau": tau,
         "batch": args.batch,
@@ -242,10 +243,10 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # standalone: force the CPU backend with a virtual mesh BEFORE jax
-    # initializes (the same rig contract as tests/conftest.py)
+    # standalone: a CPU parity gate — hold the process to the CPU with a
+    # virtual mesh BEFORE jax initializes (as tests/conftest.py does); the
+    # result names the backend it ran on
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

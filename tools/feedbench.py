@@ -222,6 +222,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help="also write the JSON here")
     args = ap.parse_args(argv)
 
+    # a host-side parity gate: whatever touches JAX here stays on the CPU
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.corrupt:
         os.environ["SPARKNET_FAULT"] = "corrupt_record:0.1"
@@ -318,6 +319,7 @@ def main(argv=None) -> int:
             }
     verdict = {
         "metric": "feed_parity",
+        "backend": os.environ["JAX_PLATFORMS"],
         "ok": not errs,
         "errors": errs,
         "batches": len(serial["batches"]),

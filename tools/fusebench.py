@@ -271,5 +271,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # a CPU parity gate unless told otherwise; the result names the backend
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     raise SystemExit(main())

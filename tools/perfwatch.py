@@ -6,7 +6,7 @@ gate-able trajectory over ``perf/LEDGER.jsonl``
 (``sparknet_tpu.utils.perfledger``):
 
   ingest      append captures to the ledger; ``--backfill`` walks the
-              committed BENCH_r0*.json / BENCH_serving_r07.json /
+              committed BENCH_serving_r07.json /
               RESULTS_bench_*.json / profiles/*/op_table.json set so
               the trajectory is populated from PR 1 onward.
   regress     the statistical regression sentinel: compare a fresh
@@ -68,11 +68,6 @@ def _log(msg: str) -> None:
 # artifacts that predate provenance stamping and carry no device field;
 # BENCH_serving_r07 is the CPU capture ROADMAP item 1 records).
 _BACKFILL = [
-    ("BENCH_r01.json", None),
-    ("BENCH_r02.json", None),
-    ("BENCH_r03.json", None),
-    ("BENCH_r04.json", None),
-    ("BENCH_r05.json", None),
     ("BENCH_serving_r07.json", "cpu/cpu"),
     ("RESULTS_bench_tpu.json", None),
     ("RESULTS_bench_googlenet.json", None),
@@ -623,8 +618,7 @@ _SMOKE_ENV = {
     "BENCH_PLATFORM": "cpu", "BENCH_MODEL": "lenet", "BENCH_BATCH": "8",
     "BENCH_ITERS": "2", "BENCH_REPS": "2", "BENCH_WINDOWS": "1",
     "BENCH_DTYPE": "f32", "BENCH_FEED_BATCH": "8", "BENCH_FEED_ITERS": "4",
-    "BENCH_ROUND": "0", "BENCH_SERVING": "0", "BENCH_ATTEMPTS": "1",
-    "BENCH_TIMEOUT_S": "240",
+    "BENCH_ROUND": "0", "BENCH_SERVING": "0",
 }
 
 

@@ -485,6 +485,8 @@ def main(argv=None) -> int:
     if args.fleet:
         return fleet_main(args, cfg, stop)
 
+    from sparknet_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     house = ModelHouse(cfg)
     declared_p99: list[float] = []
     for name, weights in parse_models(args.models):

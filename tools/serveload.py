@@ -402,6 +402,13 @@ def run_fleet_report(model: str = "lenet", replicas: int = 3,
         "workdir": workdir,
     }
 
+    # first, so that a backend the fleet refuses (tpu: one process per
+    # chip) costs no warm-up
+    fleet = ServingFleet(
+        workdir, devices, serve_env=serve_env,
+        router_cfg=RouterConfig(spill_depth=max(cfg.batch_shapes)),
+        replica_timeout_s=20.0, preempt_grace_s=15.0)
+
     # in-process references: same config + seed as every replica, so the
     # remote fleet must be bit-identical to this house's solo rows
     _log(f"building local reference model + solo references for "
@@ -412,10 +419,6 @@ def run_fleet_report(model: str = "lenet", replicas: int = 3,
               for _ in range(inputs_n)]
     refs = solo_references(ref_lm, inputs)
 
-    fleet = ServingFleet(
-        workdir, devices, serve_env=serve_env,
-        router_cfg=RouterConfig(spill_depth=max(cfg.batch_shapes)),
-        replica_timeout_s=20.0, preempt_grace_s=15.0)
     autoscaler = Autoscaler(
         fleet_stats_fn(fleet), fleet.scale_up, fleet.scale_down,
         cfg=AutoscaleConfig(max_replicas=max(replicas + 1, 2),

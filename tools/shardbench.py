@@ -228,6 +228,11 @@ def main(argv=None) -> int:
             audit_msgs.append("audit still failing after rollback")
         audit_ok = not audit_msgs
         failures += [f"[audit] {m2}" for m2 in audit_msgs]
+        # a round that was NOT rejected has scheduled an asynchronous
+        # checkpoint into ck: settle it before the directory goes, so a
+        # missed audit is reported as the failure it is and not as a
+        # clean-up race
+        tr.drain()
 
     # -- 6. analytic boundary/exchange bytes ------------------------------
     probe = legs["local_sgd"]["sharded"]["trainer"]
@@ -261,6 +266,7 @@ def main(argv=None) -> int:
         "shardbench": True,  # ingest sniff key (perfledger.entries_from_any)
         "ok": not failures,
         "failures": failures,
+        "backend": jax.default_backend(),
         "model": "lenet",
         "rounds": args.rounds,
         "tau": tau,
@@ -303,10 +309,10 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # standalone: force the CPU backend with a virtual mesh BEFORE jax
-    # initializes (the same rig contract as tests/conftest.py)
+    # standalone: a CPU parity gate — hold the process to the CPU with a
+    # virtual mesh BEFORE jax initializes (as tests/conftest.py does); the
+    # result names the backend it ran on
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -228,7 +228,6 @@ def _net_knobs(workdir: str) -> None:
     # the ssh-spawned workers inherit this process's env through the
     # shim (the remote branch applies no platform/device carving)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 
 
 def _wire_driver(out, rounds, *, host, ckpt=None, extra_env=None,
@@ -445,6 +444,7 @@ def net_soak(args) -> int:
                              net_slice=args.net_slice)
     passed = sum(1 for e in episodes if e["ok"])
     report = {"mode": "net", "seed": args.seed,
+              "backend": os.environ["JAX_PLATFORMS"],
               "slice": bool(args.net_slice), "rounds": rounds,
               "lease_window_s": lease_window_s(), "episodes": episodes,
               "passed": passed, "failed": len(episodes) - passed,
@@ -1034,6 +1034,7 @@ def pod_soak(args) -> int:
 
     passed = sum(1 for e in episodes if e["ok"])
     report = {"mode": "pod", "seed": args.seed, "pod_hosts": args.pod,
+              "backend": os.environ["JAX_PLATFORMS"],
               "devices_per_host": args.pod_devices,
               "transport": "ssh",
               "slice": bool(args.pod_slice), "episodes": episodes,

@@ -526,5 +526,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     raise SystemExit(main())
