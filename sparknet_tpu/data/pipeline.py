@@ -90,14 +90,17 @@ class FeedStats:
 
     Stage seconds are summed across whichever threads ran the stage, so
     with a parallel pool ``decode_s`` is cpu-seconds (it can exceed wall
-    time — that is the point of the pool).  ``snapshot()`` returns totals;
-    ``per_batch()`` divides by delivered batches for the bench JSON."""
+    time — that is the point of the pool).  ``wait_s`` is the consumer's
+    side: the seconds ``DeviceFeed.__next__`` was blocked for a staged
+    batch.  ``snapshot()`` returns totals; ``per_batch()`` divides by
+    delivered batches for the bench JSON."""
 
-    STAGES = ("read", "decode", "transform", "device_put")
+    STAGES = ("read", "decode", "transform", "device_put", "wait")
 
     def __init__(self):
         self._lock = threading.Lock()
         self._s = {k: 0.0 for k in self.STAGES}
+        self._delivery = "host"
         self.batches = 0
         self.records = 0
         self.cache_hits = 0        # RAM-tier hits (back-compat meaning)
@@ -109,19 +112,29 @@ class FeedStats:
             self._s[stage] = self._s.get(stage, 0.0) + seconds
             self.records += records
         # telemetry plane: the stage timing the pipeline already measured
-        # becomes a histogram sample and (when tracing) a retroactive
-        # span — DecodePool / transforms / DeviceFeed all route through
-        # here, so one hook instruments every feed stage
+        # becomes a histogram sample — DecodePool / transforms /
+        # DeviceFeed all route through here, so one hook covers every
+        # feed stage (the spans are per batch, where the work happens)
         telemetry.get_registry().histogram(
             "feed_stage_seconds",
             "host feed pipeline stage latency").observe(seconds,
                                                         stage=stage)
-        telemetry.note_span(f"feed.{stage}", seconds, cat="feed")
 
-    def count_batch(self, records: int = 0) -> None:
+    def delivered_by(self, stage: str) -> None:
+        """Name the stage whose batches reach the consumer.  ``batches``
+        counts delivered batches: the host stage's (``records_feed``,
+        ``db_feed``) while it is the only one, the ``DeviceFeed``'s
+        (``"device"``) once one is built over this object, so that two
+        stages sharing one ``FeedStats`` count each batch once."""
         with self._lock:
-            self.batches += 1
+            self._delivery = stage
+
+    def count_batch(self, records: int = 0, stage: str = "host") -> None:
+        with self._lock:
             self.records += records
+            if stage != self._delivery:
+                return
+            self.batches += 1
         telemetry.get_registry().counter(
             "feed_batches_total", "batches delivered to the consumer"
         ).inc()

@@ -286,15 +286,26 @@ class DeviceFeed:
         if putters is None:
             putters = max(1, knobs.get_int("SPARKNET_FEED_PUTTERS", 2))
         self.stats = stats
+        if stats is not None:
+            # this stage hands the batches to the consumer: where a
+            # FeedStats is shared with the host stage, they count here
+            stats.delivered_by("device")
+        self._delivered = 0
         self._sharding = sharding
         self._cast = dict(device_cast) if device_cast else None
         self._pf = PrefetchIterator(batches, depth=depth,
                                     stall_timeout=stall_timeout,
                                     restarts=restarts)
-        self._pool = DecodePool(self._put, workers=putters,
+        self._pool = DecodePool(self._put_one, workers=putters,
                                 window=putters + 1, name="device_put",
                                 stats=stats, stage="device_put")
-        self._it = self._pool.imap(self._pf)
+        self._it = self._pool.imap(enumerate(self._pf))
+
+    def _put_one(self, item: tuple[int, Mapping[str, Any]]
+                 ) -> dict[str, jax.Array]:
+        ordinal, batch = item
+        with telemetry.span("feed.device_put", cat="feed", batch=ordinal):
+            return self._put(batch)
 
     def _put(self, batch: Mapping[str, Any]) -> dict[str, jax.Array]:
         out: dict[str, jax.Array] = {}
@@ -310,8 +321,8 @@ class DeviceFeed:
             out[k] = a
         # settle the transfer on the putter thread, not in the consumer's
         # step — staged batches are fully HBM-resident when yielded (and
-        # the stats' device_put_s measures the real transfer, not the
-        # async dispatch)
+        # the span and the stats' device_put_s measure the real transfer,
+        # not the async dispatch)
         if out:
             jax.block_until_ready(list(out.values()))
         return out
@@ -320,9 +331,13 @@ class DeviceFeed:
         return self
 
     def __next__(self) -> dict[str, jax.Array]:
-        batch = next(self._it)
+        t0 = time.perf_counter()
+        with telemetry.span("feed.wait", cat="feed", batch=self._delivered):
+            batch = next(self._it)
+        self._delivered += 1
         if self.stats is not None:
-            self.stats.count_batch()
+            self.stats.note("wait", time.perf_counter() - t0)
+            self.stats.count_batch(stage="device")
         return batch
 
     def close(self) -> None:

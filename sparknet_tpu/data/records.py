@@ -45,6 +45,7 @@ shard size target).
 
 from __future__ import annotations
 
+import itertools
 import os
 import struct
 import time
@@ -52,7 +53,7 @@ from typing import Any, Iterable, Iterator
 
 import numpy as np
 
-from ..utils import faults, knobs
+from ..utils import faults, knobs, telemetry
 from .integrity import DataCorruptionError, Quarantine, QuarantinePolicy, crc32
 from .objectstore import ObjectStore, VerifyingStore, get_store
 
@@ -539,9 +540,11 @@ def records_feed(lp, phase, tops: list[str] | None = None, seed: int = 0,
     pool = DecodePool(fetch_one, workers=workers,
                       name=f"records:{source}", window=batch + 2)
 
-    def emit(imgs_l: list, labels_l: list) -> dict[str, np.ndarray]:
+    def emit(imgs_l: list, labels_l: list, ordinal: int
+             ) -> dict[str, np.ndarray]:
         n = len(imgs_l)
-        stacked = np.stack(imgs_l)          # uint8 [n, c, h, w]
+        with telemetry.span("feed.stack", cat="feed", batch=ordinal):
+            stacked = np.stack(imgs_l)      # uint8 [n, c, h, w]
         if tf is None:
             data = stacked
             if stats is not None:
@@ -568,18 +571,26 @@ def records_feed(lp, phase, tops: list[str] | None = None, seed: int = 0,
         imgs_l.append(img)
         labels_l.append(label)
 
+    # one span a host batch, closed before the yield: what this thread
+    # spends between two of them is back-pressure from the queue below
     try:
-        while True:
-            for _ in range(batch):
-                pool.submit(pull())
-            imgs_l: list[np.ndarray] = []
-            labels_l: list[int] = []
-            for _ in range(batch):
-                collect_one(imgs_l, labels_l)
-            while len(imgs_l) < batch:     # replace quarantined records
-                pool.submit(pull())
-                collect_one(imgs_l, labels_l)
-            yield emit(imgs_l, labels_l)
+        for ordinal in itertools.count():
+            with telemetry.span("feed.assemble", cat="feed", batch=ordinal):
+                with telemetry.span("feed.submit", cat="feed",
+                                    batch=ordinal):
+                    for _ in range(batch):
+                        pool.submit(pull())
+                imgs_l: list[np.ndarray] = []
+                labels_l: list[int] = []
+                with telemetry.span("feed.collect", cat="feed",
+                                    batch=ordinal):
+                    for _ in range(batch):
+                        collect_one(imgs_l, labels_l)
+                    while len(imgs_l) < batch:   # replace quarantined ones
+                        pool.submit(pull())
+                        collect_one(imgs_l, labels_l)
+                out = emit(imgs_l, labels_l, ordinal)
+            yield out
     finally:
         pool.close()
         shards.close()

@@ -128,6 +128,12 @@ def apply(imgs, ys, xs, flips, spec: AugmentSpec):
     return x
 
 
+# the scope graph/net.py gives every layer, so that a device trace names
+# the augmentation's operations like a layer's
+SCOPE = "L[augment]"
+
+
+@jax.named_scope(SCOPE)
 def augment_batch(imgs, key, spec: AugmentSpec):
     """Draw + apply in one call — the train step's entry point."""
     n, _c, h, w = imgs.shape

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..graph.net import Net, WeightCollection
 from ..proto.caffe_pb import NetParameter, NetState, Phase, SolverParameter
+from ..utils import telemetry
 from ..utils.glog import log_line
 from .lr_policies import learning_rate
 from .update_rules import make_update_rule
@@ -239,8 +240,10 @@ class Solver:
             # copy: the jitted step donates param buffers
             params_before = jax.tree_util.tree_map(
                 jnp.copy, self.params) if debug else None
-            self.params, self.state, loss_dev = self._step(
-                self.params, self.state, self.iter, stacked, rng)
+            with telemetry.span("step.dispatch", cat="step",
+                                iter=self.iter):
+                self.params, self.state, loss_dev = self._step(
+                    self.params, self.state, self.iter, stacked, rng)
             # the loss stays a DEVICE scalar here — fetching it every
             # iteration would serialize the host loop on each compiled
             # step (the reference pattern carried over from per-iter
@@ -379,9 +382,11 @@ class Solver:
                       f"data: {asum(a):.6g}; diff: {asum(a - b):.6g}")
 
     def _next_batches(self):
-        batches = [dict(next(self._train_iter)) for _ in range(self.sp.iter_size)]
-        return jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *batches)
+        with telemetry.span("step.next_batch", cat="step", iter=self.iter):
+            batches = [dict(next(self._train_iter))
+                       for _ in range(self.sp.iter_size)]
+            return jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *batches)
 
     def smoothed_loss(self) -> float:
         """Average of the trailing ``average_loss`` window
@@ -391,8 +396,9 @@ class Solver:
         ends."""
         if not self._smoothed:
             return 0.0
-        return float(sum(float(v) for v in self._smoothed)
-                     / len(self._smoothed))
+        with telemetry.span("step.loss_fetch", cat="step", iter=self.iter):
+            return float(sum(float(v) for v in self._smoothed)
+                         / len(self._smoothed))
 
     # -- test pass (Solver::TestAndStoreResult; reference:
     #    solver.cpp:413-445 + ccaffe.cpp:179-187) -------------------------

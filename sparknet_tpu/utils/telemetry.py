@@ -35,13 +35,24 @@ decisions, serving batches) publish into:
   something went wrong (the crash "black box"), and the fleet appends
   the tail into quarantine postmortems.
 
+**One span, two clocks.**  ``span()`` is the only span primitive the
+program's modules call.  It always enters a
+``jax.profiler.TraceAnnotation("sparknet.<name>", **args)``: with no
+profiler session running that is a flag test in C++ ("tracing off");
+under a session (``jax.profiler.start_trace``, the benchmark's
+``--trace 1``) the span lands in the ``.xplane.pb`` on the profiler's
+clock, where it can be laid over the device's operations.  With
+``SPARKNET_TRACE_DIR`` set the same span is also written to the JSONL
+shard above, on the epoch clock.
+
 **Off switch:** ``SPARKNET_TELEMETRY=0`` makes the whole plane a no-op:
 ``get_registry()`` returns a null registry whose metrics are shared
 singletons with pass methods, ``span()`` returns a shared null context
 manager, and the recorder drops events — nothing is allocated per
-round and no file is ever written.  Tracing additionally requires
-``SPARKNET_TRACE_DIR`` even when telemetry is on, so the default
-steady-state cost is a few counter increments per round.
+round and no file is ever written.  The JSONL shard additionally
+requires ``SPARKNET_TRACE_DIR`` even when telemetry is on, so the
+default steady-state cost is a few counter increments per round and an
+annotation no session reads.
 
 Env knobs:
   SPARKNET_TELEMETRY      — "0" disables the whole plane (default on).
@@ -542,19 +553,23 @@ class Tracer:
 
 
 class _Span:
-    """Live tracing span: wall-clock anchored, perf_counter-measured."""
+    """Live tracing span for the JSONL shard: wall-clock anchored,
+    perf_counter-measured, around the same span's profiler annotation."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_p0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_ann", "_t0", "_p0")
 
-    def __init__(self, tr: Tracer, name: str, cat: str, args: dict):
+    def __init__(self, tr: Tracer, name: str, cat: str, args: dict, ann):
         self._tr, self._name, self._cat, self._args = tr, name, cat, args
+        self._ann = ann
 
     def __enter__(self):
         self._t0 = time.time()
         self._p0 = time.perf_counter()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         dur = time.perf_counter() - self._p0
         self._tr.complete(self._name, self._cat, self._t0 * 1e6,
                           dur * 1e6, self._args)
@@ -643,7 +658,8 @@ class _NullRecorder:
 _NULL_REGISTRY = _NullRegistry()
 _NULL_RECORDER = _NullRecorder()
 _state: dict[str, Any] = {"registry": None, "tracer": None,
-                          "tracer_off": False, "recorder": None}
+                          "tracer_off": False, "recorder": None,
+                          "annotation": None}
 _state_lock = threading.Lock()
 
 
@@ -692,13 +708,34 @@ def tracing() -> bool:
     return get_tracer() is not None
 
 
+SPAN_PREFIX = "sparknet."
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, or False while the plane is off;
+    latched on first use (and imported only then: the tools that fold
+    snapshots or merge shards use this module without JAX)."""
+    ann = _state["annotation"]
+    if ann is None:
+        ann = False
+        if enabled():
+            from jax.profiler import TraceAnnotation as ann
+        _state["annotation"] = ann
+    return ann
+
+
 def span(name: str, cat: str = "app", **args):
-    """Context manager tracing one span; the shared no-op when tracing
-    is off — safe (and free) to leave on hot paths."""
-    tr = get_tracer()
-    if tr is None:
+    """Context manager tracing one span: a profiler annotation
+    ``sparknet.<name>`` carrying ``args`` (a flag test unless a profiler
+    session is running), inside a JSONL-shard span when
+    ``SPARKNET_TRACE_DIR`` is set; the shared no-op under
+    ``SPARKNET_TELEMETRY=0`` — safe to leave on hot paths."""
+    ann = _annotation()
+    if not ann:
         return NULL_SPAN
-    return _Span(tr, name, cat, args)
+    tr = get_tracer()
+    here = ann(SPAN_PREFIX + name, **args)
+    return here if tr is None else _Span(tr, name, cat, args, here)
 
 
 def note_span(name: str, seconds: float, cat: str = "app", **args) -> None:
@@ -728,7 +765,7 @@ def reset() -> None:
         if tr is not None:
             tr.flush()
         _state.update(registry=None, tracer=None, tracer_off=False,
-                      recorder=None)
+                      recorder=None, annotation=None)
         _DERIVED_RUN = None
 
 
