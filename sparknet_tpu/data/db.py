@@ -307,7 +307,11 @@ def db_feed(lp, phase: Phase, tops: list[str] | None = None,
     decode/transform seconds.  ``buffers``: > 0 rotates the batch output
     through that many preallocated buffers (``pipeline.BufferRing``) —
     opt-in, because a consumer that holds more than ``buffers - 1``
-    batches concurrently would see them overwritten.
+    batches concurrently would see them overwritten (a RECORDS source
+    reads its next batch while this one is held: one buffer more).  At
+    0, the default, a batch that anything still holds is never written
+    again (a RECORDS source reads into its earlier arrays only once
+    every reference to them is gone).
 
     A pre-decoded record-shard source (``backend: "RECORDS"``, a
     ``*.rec`` path, or a directory of them — written once by

@@ -46,6 +46,26 @@ class ObjectStore:
             f.seek(offset)
             return f.read(length)
 
+    #: True where ``read_into`` lands the bytes in the caller's buffers
+    #: with no copy between (the record feed counts its reads by this).
+    reads_in_place = False
+
+    def read_into(self, key: str, offset: int, buffers) -> int:
+        """Ranged read INTO the caller's writable, contiguous buffers,
+        filled in order from ``offset``; returns the bytes delivered
+        (fewer than the buffers hold only where the object ends).
+        Default: ``open_range`` and a copy, so a store with no override
+        keeps everything its ``open_range`` does (a ranged GET, the
+        ``VerifyingStore``'s retry and checksum)."""
+        views = [memoryview(b).cast("B") for b in buffers]
+        raw = self.open_range(key, offset, sum(len(v) for v in views))
+        at = 0
+        for v in views:
+            part = raw[at:at + len(v)]
+            v[:len(part)] = part
+            at += len(part)
+        return at
+
     def close(self) -> None:
         """Release any pooled resources (no-op by default)."""
 
@@ -65,17 +85,18 @@ class _PooledFd:
 class LocalStore(ObjectStore):
     """Filesystem-backed store; keys are paths relative to ``root``.
 
-    ``open_range`` (the lazy-partition / record-shard hot path — one
-    call per record, fanned out over the parallel ranged-read pool) is
-    fully thread-safe: reads use per-call ``os.pread`` (positioned read,
-    no shared seek cursor to race on) against a small LRU pool of raw
-    descriptors.  Only pool bookkeeping happens under the lock; the
-    actual IO runs outside it, so N pool workers genuinely read in
-    parallel.  A descriptor evicted (or ``close()``d) while readers are
+    ``open_range`` (the lazy-partition path, one call per record) and
+    ``read_into`` (the record feed's: one ``os.preadv`` lands a run of
+    records in their rows of the batch, no ``bytes`` between) are fully
+    thread-safe: both are positioned reads (no shared seek cursor to race
+    on) against a small LRU pool of raw descriptors.  Only pool
+    bookkeeping happens under the lock; the actual IO runs outside it,
+    so N pool workers genuinely read in parallel.  A descriptor evicted (or ``close()``d) while readers are
     mid-``pread`` stays open until the last of them releases it —
     eviction can never invalidate a concurrent read."""
 
     _MAX_HANDLES = 8
+    reads_in_place = True
 
     def __init__(self, root: str):
         self.root = root
@@ -128,6 +149,13 @@ class LocalStore(ObjectStore):
         h = self._acquire(key)
         try:
             return os.pread(h.fd, length, offset)
+        finally:
+            self._release(h)
+
+    def read_into(self, key: str, offset: int, buffers) -> int:
+        h = self._acquire(key)
+        try:
+            return os.preadv(h.fd, buffers, offset)
         finally:
             self._release(h)
 

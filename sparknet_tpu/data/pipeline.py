@@ -92,8 +92,13 @@ class FeedStats:
     with a parallel pool ``decode_s`` is cpu-seconds (it can exceed wall
     time — that is the point of the pool).  ``wait_s`` is the consumer's
     side: the seconds ``DeviceFeed.__next__`` was blocked for a staged
-    batch.  ``snapshot()`` returns totals; ``per_batch()`` divides by
-    delivered batches for the bench JSON."""
+    batch.  A stage notes as often as suits it: ``records_feed`` sums
+    its readers' ``read`` and ``decode`` seconds over a batch's records
+    and notes each once a batch (same totals, two locked calls a batch
+    in place of two a record), and counts there how the records' bytes
+    arrived (``read_in_place``, ``read_copied``).  ``snapshot()``
+    returns totals; ``per_batch()`` divides by delivered batches for
+    the bench JSON."""
 
     STAGES = ("read", "decode", "transform", "device_put", "wait")
 
@@ -103,6 +108,8 @@ class FeedStats:
         self._delivery = "host"
         self.batches = 0
         self.records = 0
+        self.read_in_place = 0     # records read straight into their row
+        self.read_copied = 0       # ... and through a copy of their bytes
         self.cache_hits = 0        # RAM-tier hits (back-compat meaning)
         self.cache_disk_hits = 0   # served from the local-disk spill tier
         self.cache_misses = 0      # every tier missed: origin materialize
@@ -138,6 +145,15 @@ class FeedStats:
         telemetry.get_registry().counter(
             "feed_batches_total", "batches delivered to the consumer"
         ).inc()
+
+    def count_reads(self, in_place: int, copied: int) -> None:
+        """How a batch's records reached their rows (``records_feed``):
+        read in place by the store, or copied out of ``bytes`` (the
+        cached blob, a store with no ``read_into`` of its own, an
+        injected fault)."""
+        with self._lock:
+            self.read_in_place += in_place
+            self.read_copied += copied
 
     def note_cache(self, hit: bool, tier: str = "ram") -> None:
         """Record one shard-cache lookup outcome.  ``tier`` labels WHICH
@@ -177,6 +193,8 @@ class FeedStats:
         with self._lock:
             out = {f"{k}_s": round(v, 6) for k, v in self._s.items()}
             out.update(batches=self.batches, records=self.records,
+                       read_in_place=self.read_in_place,
+                       read_copied=self.read_copied,
                        cache_hits=self.cache_hits,
                        cache_disk_hits=self.cache_disk_hits,
                        cache_misses=self.cache_misses)
@@ -413,8 +431,10 @@ class BufferRing:
     again after ``size`` further ``take()`` calls, so every downstream
     stage that holds batches concurrently (prefetch queue depth + staging
     window + the consumer's working batch) must together hold FEWER than
-    ``size`` — size it ``depth + window + 2``.  ``db_feed`` only rotates
-    buffers when explicitly asked (``buffers=N``)."""
+    ``size`` — size it ``depth + window + 2``, and one more under a raw
+    ``records_feed``, which takes the next batch's buffer while this
+    batch is still being read.  ``db_feed`` only rotates buffers when
+    explicitly asked (``buffers=N``)."""
 
     def __init__(self, size: int):
         if size < 2:

@@ -234,9 +234,13 @@ class PrefetchIterator:
             return item
 
     def close(self) -> None:
-        """Stop the producer (every generation of it) and release staged
-        items.  Safe to call concurrently with a watchdog restart: the
-        stop event gates both the old and the freshly-spawned feeder."""
+        """Stop the producer (every generation of it), release staged
+        items, and close the source where it can be closed (a generator:
+        ``records_feed`` keeps a batch's reads in flight ahead of the one
+        it yielded, and its ``finally`` is what stops those readers —
+        now, not whenever the interpreter collects it).  Safe to call
+        concurrently with a watchdog restart: the stop event gates both
+        the old and the freshly-spawned feeder."""
         self._stop.set()
         self._done = True
         while True:
@@ -246,6 +250,11 @@ class PrefetchIterator:
                 break
         for t in self._threads:
             t.join(timeout=5.0)
+        close_source = getattr(self._source, "close", None)
+        # a feeder that outlived its join is still inside the source
+        if close_source is not None and not any(
+                t.is_alive() for t in self._threads):
+            close_source()
 
     def __enter__(self) -> "PrefetchIterator":
         return self

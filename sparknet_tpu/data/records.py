@@ -32,27 +32,37 @@ once half of that lesson:
   ``PartitionedDataset`` partition and composes with the tiered
   ``pipeline.ShardCache`` (RAM → local-disk spill → origin store).
 - :func:`records_feed` — the ``db_feed``-shaped batch stream that skips
-  decode entirely: serial pulls keep the fault-injection coin flips and
-  quarantine epoch accounting bit-identical to the LMDB path, ranged
-  reads fan out over a bounded ``DecodePool`` (order-preserving, typed
-  errors), and ``raw=True`` ships untransformed uint8 for the
+  decode entirely and assembles each batch IN PLACE: serial pulls keep
+  the fault-injection coin flips and quarantine epoch accounting
+  bit-identical to the LMDB path; the batch's uint8 array exists before
+  its reads are submitted, and runs of consecutive records fan out over
+  a bounded ``DecodePool`` (order-preserving, typed errors) whose
+  readers land each record's pixels straight in its row
+  (``ObjectStore.read_into``: one copy, page cache to batch) and check
+  its crc there (``native.crc32_rows``: a run a call, off the
+  interpreter lock); the next batch's reads are in flight while this
+  one is finished.  ``raw=True`` ships the array untransformed for the
   device-side augmentation path (``ops.augment``).
 
-Knobs: ``SPARKNET_RECORD_READERS`` (ranged-read pool width, default
-``SPARKNET_FEED_WORKERS``), ``SPARKNET_RECORD_SHARD_MB`` (converter
-shard size target).
+Knobs: ``SPARKNET_RECORD_READERS`` (reader threads, each reading runs of
+records into their rows; default ``SPARKNET_FEED_WORKERS``),
+``SPARKNET_RECORD_SHARD_MB`` (converter shard size target).
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
 import os
 import struct
+import sys
 import time
+import zlib
 from typing import Any, Iterable, Iterator
 
 import numpy as np
 
+from .. import native
 from ..utils import faults, knobs, telemetry
 from .integrity import DataCorruptionError, Quarantine, QuarantinePolicy, crc32
 from .objectstore import ObjectStore, VerifyingStore, get_store
@@ -68,11 +78,18 @@ SHARD_SUFFIX = ".rec"
 # covering everything before it; the tail pads to HEADER_SIZE
 _HEADER = struct.Struct("<8sIIQIIIIQI")
 _LABEL = struct.Struct("<q")
+_LABEL_DTYPE = np.dtype("<i8")      # a batch's label column, as stored
+
+# Records one reader takes at a time: a run of consecutive records of one
+# shard, bound for consecutive rows of one batch, is one ``read_into``
+# (2 buffers a record) and one hand-over through the pool.
+MAX_RUN = 32
 
 
 def record_readers(default: int | None = None) -> int:
-    """Ranged-read pool width: ``SPARKNET_RECORD_READERS``, else the
-    decode-pool default (``SPARKNET_FEED_WORKERS``).  0 = serial."""
+    """Reader threads of ``records_feed``: ``SPARKNET_RECORD_READERS``,
+    else the decode-pool default (``SPARKNET_FEED_WORKERS``).  0 = serial:
+    the same reads into the same rows, on the feed's own thread."""
     raw = knobs.raw("SPARKNET_RECORD_READERS", "")
     if not raw:
         from .pipeline import feed_workers
@@ -248,10 +265,12 @@ class RecordShard:
 
     The header and crc table are read once at construction (two small
     ranged reads); after that ``read(i)`` is exactly one ranged read of
-    ``stride`` bytes, crc-validated against the table.  Thread-safe for
-    concurrent readers (the parallel ranged-read pool) as long as the
-    backing store's ``open_range`` is — ``LocalStore`` uses per-call
-    ``os.pread`` on a refcounted fd pool for exactly this.
+    ``stride`` bytes, crc-validated against the table, and
+    ``read_rows``/``check_rows`` do the same for a run of records
+    straight into rows of a caller's batch.  Thread-safe for concurrent
+    readers (the parallel ranged-read pool) as long as the backing
+    store's ``open_range``/``read_into`` are — ``LocalStore`` uses
+    positioned reads on a refcounted fd pool for exactly this.
 
     Satisfies ``__len__``/``__getitem__``, so a shard can stand directly
     as a ``PartitionedDataset`` partition (decode-free lazy records) and
@@ -325,10 +344,77 @@ class RecordShard:
         return store.open_range(self.key, self.data_off,
                                 self.count * self.stride)
 
+    def _corrupt(self, i: int, nbytes: int) -> DataCorruptionError:
+        return DataCorruptionError(
+            f"record block checksum mismatch "
+            f"({nbytes}/{self.stride} bytes)",
+            source=self.source, key=i, offset=self.offset(i))
+
+    @property
+    def max_run(self) -> int:
+        """Records one :meth:`read_rows` may cover: a run where the bytes
+        come from the cached blob or the store reads in place; one
+        through any other store, whose ranged read (a GET, a
+        ``VerifyingStore``'s checksum and torn-read retry) is a
+        record's."""
+        if self._cache is not None or self.store.reads_in_place:
+            return MAX_RUN
+        return 1
+
+    def read_rows(self, i: int, imgs: np.ndarray,
+                  labels: np.ndarray) -> tuple[int, bool]:
+        """Records ``i .. i+len(imgs)-1`` (at most :attr:`max_run`) into
+        the rows of ``imgs`` (uint8 ``[m, c, h, w]``) and ``labels``
+        (``<i8 [m]``), both contiguous — the feed's read: no ``bytes``
+        a record.  NOT yet crc-validated; pair with :meth:`check_rows`.
+        Returns (records whose bytes arrived whole, whether they were
+        read in place): the store's ``read_into`` lands them there; the
+        cached blob's slice, and a store with no override, copy."""
+        m = len(imgs)
+        if not 0 <= i <= self.count - m:
+            raise IndexError(
+                f"records [{i}, {i + m}) out of range [0, {self.count})")
+        if self._cache is not None:
+            blob = self._cache.get(self._cache_key, self._load_blob)
+            got = max(0, min(m, len(blob) // self.stride - i))
+            if got:
+                rec = np.frombuffer(
+                    blob, np.uint8, got * self.stride,
+                    i * self.stride).reshape(got, self.stride)
+                imgs[:got].reshape(got, -1)[...] = rec[:, :-LABEL_BYTES]
+                labels[:got].view(np.uint8).reshape(
+                    got, LABEL_BYTES)[...] = rec[:, -LABEL_BYTES:]
+            return got, False
+        got = self.store.read_into(
+            self.key, self.offset(i),
+            [b for r in range(m) for b in (imgs[r], labels[r:r + 1])])
+        return got // self.stride, self.store.reads_in_place
+
+    def check_rows(self, i: int, imgs: np.ndarray, labels: np.ndarray,
+                   arrived: int) -> list[tuple[int, DataCorruptionError]]:
+        """Validate rows filled by :meth:`read_rows` where they lie: each
+        record's crc (the label's chained over the pixels') against the
+        table.  Returns ``(row, error)`` for every record that fails or
+        did not arrive whole: the typed error :meth:`unpack` raises.
+        Thread-safe, and meant for several pool threads at once."""
+        m = len(imgs)
+        want = self.crcs[i:i + m]
+        # one native call a run, the interpreter lock released once;
+        # with no toolchain, zlib.crc32 a record, which takes it back a
+        # record (8 threads then check no faster than 4)
+        failed = native.crc32_rows(imgs, labels, want)
+        if failed is None:
+            failed = [zlib.crc32(labels[r:r + 1], zlib.crc32(imgs[r]))
+                      & 0xFFFFFFFF != want[r] for r in range(m)]
+        return [(r, self._corrupt(i + r, self.stride if r < arrived else 0))
+                for r in range(m) if r >= arrived or failed[r]]
+
     def read_raw(self, i: int) -> bytes:
-        """Record ``i``'s block bytes — one ranged read (or a slice of
-        the cached whole-shard blob), NOT yet crc-validated; pair with
-        :meth:`unpack`."""
+        """Record ``i``'s block as fresh ``bytes`` — one ranged read (or
+        a slice of the cached whole-shard blob), NOT yet crc-validated;
+        pair with :meth:`unpack`.  The one-record path (``read``, lazy
+        partitions, an injected fault); the feed reads through
+        :meth:`read_rows`."""
         if not 0 <= i < self.count:
             raise IndexError(f"record {i} out of range [0, {self.count})")
         if self._cache is not None:
@@ -339,14 +425,12 @@ class RecordShard:
 
     def unpack(self, raw: bytes, i: int) -> tuple[np.ndarray, int]:
         """Validate + unpack one record block: crc against the table,
-        then a zero-decode frombuffer view copy.  Corruption raises
-        :class:`DataCorruptionError` with source/key/offset attribution
-        (the quarantine layer's admission unit)."""
+        then a zero-decode frombuffer VIEW of ``raw`` (no copy).
+        Corruption raises :class:`DataCorruptionError` with
+        source/key/offset attribution (the quarantine layer's admission
+        unit)."""
         if len(raw) != self.stride or crc32(raw) != int(self.crcs[i]):
-            raise DataCorruptionError(
-                f"record block checksum mismatch "
-                f"({len(raw)}/{self.stride} bytes)",
-                source=self.source, key=i, offset=self.offset(i))
+            raise self._corrupt(i, len(raw))
         img = np.frombuffer(raw, np.uint8,
                             count=self.stride - LABEL_BYTES).reshape(
                                 self.c, self.h, self.w)
@@ -456,29 +540,84 @@ def is_records_source(source: str) -> bool:
         return False
 
 
+# Arrays a feed keeps an eye on for reuse (``_unheld``): more than any
+# pipeline below it holds at once, so none is forgotten before it is let go.
+_SPARE_MAX = 64
+
+
+def _unheld(spare: list) -> np.ndarray | None:
+    """Take from ``spare`` an array that nothing but ``spare`` refers to
+    any more: every consumer, queue, view and ``device_put`` that aliases
+    it has let it go, so writing it again can be seen by no one.  (A
+    reference count of 2: the list's, and the call's own argument.)"""
+    for i in range(len(spare)):
+        if sys.getrefcount(spare[i]) == 2:
+            return spare.pop(i)
+    return None
+
+
+class _Batch:
+    """One batch under assembly: its arrays, the rows no pull has been
+    given yet, and the rows that hold good records, in pull order."""
+
+    __slots__ = ("imgs", "labels", "free", "rows")
+
+    def __init__(self, imgs: np.ndarray):
+        self.imgs = imgs
+        self.labels = np.empty(len(imgs), _LABEL_DTYPE)
+        self.free = collections.deque(range(len(imgs)))
+        self.rows: list[int] = []
+
+
 def records_feed(lp, phase, tops: list[str] | None = None, seed: int = 0,
                  quarantine: Quarantine | None = None,
                  workers: int | None = None, stats=None, buffers: int = 0,
                  raw: bool = False, verify: bool | None = None,
                  cache=None) -> Iterator[dict[str, np.ndarray]]:
     """Batch stream for a records-backed ``Data`` layer — ``db_feed``'s
-    contract without the decode stage.
+    contract without the decode stage, each batch assembled in place.
 
     Determinism mirrors ``db_feed`` exactly: records are PULLED serially
     on the consumer thread (sequential ordinal, the fault injector's
-    per-seq ``corrupt_record`` coin, quarantine epoch accounting), while
+    per-seq ``corrupt_record`` coin, quarantine epoch accounting) and a
+    batch is the next ``batch_size`` GOOD records of that order, while
     the ranged READS fan out over an order-preserving ``DecodePool`` —
     so for a fixed seed the parallel records stream is bit-identical to
     the serial one AND to the serial LMDB decode path the shards were
     converted from (same pixels, same labels, same quarantine
-    admissions, same replacement pulls).  IO seconds book to the feed's
-    ``read`` stage, crc-check/unpack to ``decode`` — perfwatch can tell
-    a slow store from a slow host.
+    admissions, same replacement pulls).
 
-    ``raw=True`` skips the host transform and ships uint8 pixels
+    In place: a batch's ``uint8 [n, c, h, w]`` array and its label
+    column exist before its reads are submitted, a pull is given the
+    batch's next row, and a reader lands its run of records in their
+    rows (``RecordShard.read_rows``: ``os.preadv`` through a
+    ``LocalStore``, a slice of the cached blob with a ``ShardCache``, a
+    copy of ``open_range``'s bytes through any other store) and checks
+    each crc there — every record, before its batch is yielded.  The
+    pulls of batch k+1 are submitted before batch k is collected, so the
+    readers never drain at a batch's edge, also while this generator is
+    suspended in ``yield``; their epoch rolls wait until the thread
+    turns to that batch, so a quarantine admission falls in the budget
+    it always did.  A corrupt record is rare and its path may be slow:
+    its row is given to a later pull, what batch k lacks is copied out
+    of batch k+1's rows (they are next in pull order), and a batch
+    whose rows are out of order is gathered once when it is emitted.
+    Read seconds book to the feed's ``read`` stage and the crc to
+    ``decode``, summed over a batch's records and noted once a batch —
+    perfwatch can tell a slow store from a slow host; ``read_in_place``
+    / ``read_copied`` count the records by how their bytes arrived.
+
+    ``raw=True`` skips the host transform and ships the uint8 array
     untouched (plus f32 labels) — the device-side augmentation path:
     pair with ``Solver.set_augment`` so crop/mirror/mean/scale run
-    inside the compiled step.  ``verify=True`` (or data_param
+    inside the compiled step.  A yielded batch is never written again
+    while anything refers to it: its array is a fresh one, or one this
+    feed made earlier that every holder has since let go (the consumer,
+    a queue, a view, a ``device_put`` that aliases it: ``_unheld``), so
+    steady state reads into memory whose pages are mapped already.
+    ``buffers=N`` instead rotates the yielded array (the raw one, or the
+    transform's output) through a ``BufferRing`` under that parameter's
+    aliasing contract, the feed's own batch ahead counted in.  ``verify=True`` (or data_param
     ``verify``) routes reads through a :class:`VerifyingStore`.
     ``cache``: a tiered ``pipeline.ShardCache`` for whole-shard blobs
     (cold = one streaming read, warm = host RAM, evicted = local-disk
@@ -502,98 +641,187 @@ def records_feed(lp, phase, tops: list[str] | None = None, seed: int = 0,
         quarantine = Quarantine(QuarantinePolicy.from_env(),
                                 epoch_size=epoch_size, source=source)
     injector = faults.get_injector()
-    state = {"seq": 0}
     ring = BufferRing(buffers) if buffers else None
-
-    def pull() -> tuple[RecordShard, int, int, bool]:
-        """Serial ordinal advance: epoch budget roll + fault coin happen
-        here, on the consumer thread, in pull order — exactly where
-        ``db_feed`` flips them."""
-        seq = state["seq"]
-        state["seq"] += 1
-        if seq and seq % epoch_size == 0:
-            quarantine.start_epoch()
-        shard, local = shards.locate(seq)
-        return shard, local, seq, injector.corrupt_record(seq)
-
-    def fetch_one(item) -> tuple[np.ndarray, int]:
-        """Ranged read + crc validate + unpack (runs on pool workers).
-        The injected fault corrupts the payload AFTER the read — rotting
-        bytes on the medium, which the crc check must catch and the
-        quarantine must attribute."""
-        shard, local, seq, inject = item
-        t0 = time.perf_counter()
-        raw_block = shard.read_raw(local)
-        if stats is not None:
-            stats.note("read", time.perf_counter() - t0)
-        if inject:
-            raw_block = faults.corrupt_bytes(raw_block, seq)
-        t0 = time.perf_counter()
-        try:
-            return shard.unpack(raw_block, local)
-        finally:
-            if stats is not None:
-                stats.note("decode", time.perf_counter() - t0)
-
     if workers is None:
         workers = record_readers()
-    pool = DecodePool(fetch_one, workers=workers,
-                      name=f"records:{source}", window=batch + 2)
+    run_len = max(1, min(MAX_RUN, batch // max(workers, 1)))
+    in_order = list(range(batch))
 
-    def emit(imgs_l: list, labels_l: list, ordinal: int
-             ) -> dict[str, np.ndarray]:
-        n = len(imgs_l)
+    seq = 0                 # records pulled
+    ahead = 0               # ... and not yet consumed
+    rolls: set[int] = set()     # pulled seqs whose epoch roll is still due
+    runs: collections.deque = collections.deque()   # submitted, in order
+    ready: collections.deque = collections.deque()  # arrived, a record each
+    tally = {"read": 0.0, "decode": 0.0, "in_place": 0, "copied": 0}
+
+    def fetch_run(run) -> tuple[list, bool, float, float]:
+        """Read a run into its rows and check each crc there (runs on
+        pool workers).  A corrupt record is a value here, not a raise:
+        its typed error reaches the quarantine at its place in the pull
+        order, on the feed's thread.  The injected fault corrupts the
+        payload AFTER the read — rotting bytes on the medium — so that
+        one record goes through ``read_raw``'s copy."""
+        shard, local, m, b, row, rseq, inject = run
+        imgs, labels = b.imgs[row:row + m], b.labels[row:row + m]
+        t0 = time.perf_counter()
+        try:
+            if inject:
+                block = faults.corrupt_bytes(shard.read_raw(local), rseq)
+                t1 = time.perf_counter()
+                imgs[0], labels[0] = shard.unpack(block, local)
+                return [], False, t1 - t0, time.perf_counter() - t1
+            arrived, in_place = shard.read_rows(local, imgs, labels)
+        except DataCorruptionError as e:
+            # unpack's verdict, or a VerifyingStore's on the one record
+            # it reads at a time (RecordShard.max_run)
+            return [(0, e)], False, time.perf_counter() - t0, 0.0
+        t1 = time.perf_counter()
+        bad = shard.check_rows(local, imgs, labels, arrived)
+        return bad, in_place, t1 - t0, time.perf_counter() - t1
+
+    pool = DecodePool(fetch_run, workers=workers,
+                      name=f"records:{source}", window=2 * batch + 2)
+
+    spare: list[np.ndarray] = []    # arrays handed out, oldest first
+
+    def new_batch() -> _Batch:
+        """An array no one else holds: the ring's next under ``buffers``,
+        else one of this feed's own that every holder has let go (its
+        pages are mapped: a fresh 201 MB array is touched for the first
+        time at a ninth of the speed), else a fresh one."""
+        shape = (batch, c, h, w)
+        if ring and tf is None:
+            return _Batch(ring.take(shape, np.uint8))
+        imgs = _unheld(spare)
+        return _Batch(np.empty(shape, np.uint8) if imgs is None else imgs)
+
+    def submit_pulls(b: _Batch, count: int) -> None:
+        """``count`` serial pulls bound for ``b``'s next free rows:
+        ordinal advance and fault coin happen here, on the consumer
+        thread, in pull order — exactly where ``db_feed`` flips them.
+        Consecutive records of one shard bound for consecutive rows go
+        to the pool as one run."""
+        nonlocal seq, ahead
+
+        def send(run: list) -> None:
+            runs.append(tuple(run))
+            pool.submit(runs[-1])
+
+        run = None
+        for _ in range(count):
+            shard, local = shards.locate(seq)
+            inject = injector.corrupt_record(seq)
+            row = b.free.popleft()
+            if seq and seq % epoch_size == 0:
+                rolls.add(seq)
+            if (run is not None and run[0] is shard and run[2] < limit
+                    and not inject and local == run[1] + run[2]
+                    and row == run[4] + run[2]):
+                run[2] += 1
+            else:
+                if run is not None:
+                    send(run)
+                run = [shard, local, 1, b, row, seq, inject]
+                limit = 1 if inject else min(run_len, shard.max_run)
+            seq += 1
+        if run is not None:
+            send(run)
+        ahead += count
+
+    def arrive() -> None:
+        """The next run's records, in pull order, onto ``ready``."""
+        bad, in_place, read_s, crc_s = pool.result()
+        _, _, m, b, row, rseq, _ = runs.popleft()
+        tally["read"] += read_s
+        tally["decode"] += crc_s
+        tally["in_place" if in_place else "copied"] += m
+        bad = dict(bad)
+        ready.extend((b, row + r, rseq + r, bad.get(r)) for r in range(m))
+
+    def collect(cur: _Batch) -> None:
+        """Consume records in pull order until ``cur`` holds ``batch``
+        good ones."""
+        nonlocal ahead
+        while len(cur.rows) < batch:
+            if not ahead:           # nothing pulled ahead is left to take
+                submit_pulls(cur, batch - len(cur.rows))
+            if not ready:
+                arrive()
+            b, row, rseq, err = ready.popleft()
+            ahead -= 1
+            if rolls and rseq in rolls:     # pulled ahead, consumed as a
+                rolls.remove(rseq)          # replacement: rolls where the
+                quarantine.start_epoch()    # serial pull did, before it
+            if err is not None:
+                b.free.append(row)
+                quarantine.admit(err)   # raises QuarantineExceeded past budget
+                continue
+            if b is not cur:        # read into the next batch's row
+                to = cur.free.popleft()
+                cur.imgs[to], cur.labels[to] = b.imgs[row], b.labels[row]
+                b.free.append(row)
+                row = to
+            cur.rows.append(row)
+
+    def emit(b: _Batch, ordinal: int) -> dict[str, np.ndarray]:
         with telemetry.span("feed.stack", cat="feed", batch=ordinal):
-            stacked = np.stack(imgs_l)      # uint8 [n, c, h, w]
-        if tf is None:
-            data = stacked
-            if stats is not None:
-                stats.count_batch(n)
-        else:
-            t0 = time.perf_counter() if stats is not None else 0.0
-            shape = ((n, c, tf.crop, tf.crop) if tf.crop
-                     else (n, c, h, w))
-            data = tf.batch(stacked, out=ring.take(shape) if ring else None)
+            imgs, labels = b.imgs, b.labels
+            if b.rows != in_order:      # a hole was filled out of order
+                imgs, labels = imgs[b.rows], labels[b.rows]
+            labels = labels.astype(np.float32)
+        if not (ring and tf is None):
+            spare.append(b.imgs)
+            del spare[:-_SPARE_MAX]
+        data = imgs
+        if tf is not None:
+            t0 = time.perf_counter()
+            shape = ((batch, c, tf.crop, tf.crop) if tf.crop
+                     else (batch, c, h, w))
+            data = tf.batch(imgs, out=ring.take(shape) if ring else None)
             if stats is not None:
                 stats.note("transform", time.perf_counter() - t0)
-                stats.count_batch(n)
+        if stats is not None:
+            stats.note("read", tally["read"])
+            stats.note("decode", tally["decode"])
+            stats.count_reads(tally["in_place"], tally["copied"])
+            tally.update(read=0.0, decode=0.0, in_place=0, copied=0)
+            stats.count_batch(batch)
         out = {tops[0]: data}
         if len(tops) > 1:
-            out[tops[1]] = np.asarray(labels_l, np.float32)
+            out[tops[1]] = labels
         return out
-
-    def collect_one(imgs_l: list, labels_l: list) -> None:
-        try:
-            img, label = pool.result()
-        except DataCorruptionError as e:
-            quarantine.admit(e)     # raises QuarantineExceeded past budget
-            return
-        imgs_l.append(img)
-        labels_l.append(label)
 
     # one span a host batch, closed before the yield: what this thread
     # spends between two of them is back-pressure from the queue below
+    nxt = new_batch()
     try:
         for ordinal in itertools.count():
             with telemetry.span("feed.assemble", cat="feed", batch=ordinal):
                 with telemetry.span("feed.submit", cat="feed",
                                     batch=ordinal):
-                    for _ in range(batch):
-                        pool.submit(pull())
-                imgs_l: list[np.ndarray] = []
-                labels_l: list[int] = []
+                    cur = nxt
+                    # what the batch before took of this one's pulls
+                    submit_pulls(cur, batch - ahead)
+                    # these are this batch's first pulls: their epoch
+                    # rolls fall before any of its admissions
+                    for _ in rolls:
+                        quarantine.start_epoch()
+                    rolls.clear()
+                    nxt = new_batch()
+                    submit_pulls(nxt, batch)
                 with telemetry.span("feed.collect", cat="feed",
                                     batch=ordinal):
-                    for _ in range(batch):
-                        collect_one(imgs_l, labels_l)
-                    while len(imgs_l) < batch:   # replace quarantined ones
-                        pool.submit(pull())
-                        collect_one(imgs_l, labels_l)
-                out = emit(imgs_l, labels_l, ordinal)
+                    collect(cur)
+                out = emit(cur, ordinal)
             yield out
     finally:
-        pool.close()
-        shards.close()
+        # Collected by a finalizing interpreter (a consumer that never
+        # closed it), the readers are daemon threads already stopped
+        # wherever they stood, reading ahead, and one may stand inside
+        # the store's or the pool's lock: waiting for it would never end.
+        if not sys.is_finalizing():
+            pool.close()
+            shards.close()
 
 
 # ---------------------------------------------------------------------------

@@ -35,7 +35,7 @@ def _build() -> str | None:
             and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC)):
         return None
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-ljpeg", "-o", _LIB_PATH]
+           "-ljpeg", "-lz", "-o", _LIB_PATH]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
     except (OSError, subprocess.TimeoutExpired) as e:
@@ -82,6 +82,9 @@ def get_lib() -> ctypes.CDLL | None:
             u8p, i64p, i64p, i64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
             f32p, i32p]
         lib.sn_parse_datum_batch.restype = ctypes.c_int
+        u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
+        lib.sn_crc32_rows.argtypes = [u8p, i64, u8p, i64, u32p, i64, u8p]
+        lib.sn_crc32_rows.restype = i64
         _lib = lib
         return _lib
 
@@ -214,3 +217,27 @@ def parse_datum_batch(records: list[bytes], c: int, h: int, w: int,
     if rc != 0:
         return None
     return out, labels
+
+
+def crc32_rows(rows: np.ndarray, tails: np.ndarray,
+               want: np.ndarray) -> np.ndarray | None:
+    """Which rows fail their checksum: zlib's crc32 of ``rows[i]``'s
+    bytes chained over ``tails[i]``'s, against ``want[i]`` (uint32), for
+    the ``n`` rows of two C-contiguous arrays — bool ``[n]``, in ONE call
+    with the interpreter lock released, so pool threads check their runs
+    of records side by side (``zlib.crc32`` a row takes the lock back a
+    row).  None when the native library is unavailable: the caller loops
+    over ``zlib.crc32``."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    n = len(rows)
+    if len(tails) != n or len(want) != n:
+        raise ValueError(f"crc32_rows: {n} rows, {len(tails)} tails, "
+                         f"{len(want)} checksums")
+    bad = np.zeros(n, np.uint8)
+    if n:
+        lib.sn_crc32_rows(rows.view(np.uint8), rows.nbytes // n,
+                          tails.view(np.uint8), tails.nbytes // n,
+                          want, n, bad)
+    return bad.view(np.bool_)

@@ -21,6 +21,7 @@
 #include <csetjmp>
 
 #include <jpeglib.h>
+#include <zlib.h>
 
 extern "C" {
 
@@ -271,6 +272,25 @@ int sn_decode_jpeg_resize(const uint8_t* buf, int64_t len,
     }
     delete[] pixels;
     return 0;
+}
+
+// Record-shard integrity, a run of rows a call: zlib's crc32 of each
+// row's bytes, chained over its tail's (a record's pixels, then its
+// label), against the shard's table.  bad[i] = 1 where they differ;
+// returns how many did.  One call from Python, so the readers of
+// records_feed check their runs side by side: zlib.crc32 a row would
+// take the interpreter lock back 1,024 times a batch.
+int64_t sn_crc32_rows(const uint8_t* rows, int64_t row_bytes,
+                      const uint8_t* tails, int64_t tail_bytes,
+                      const uint32_t* want, int64_t n, uint8_t* bad) {
+    int64_t n_bad = 0;
+    for (int64_t i = 0; i < n; ++i) {
+        uLong c = crc32_z(0L, rows + i * row_bytes, (z_size_t)row_bytes);
+        c = crc32_z(c, tails + i * tail_bytes, (z_size_t)tail_bytes);
+        bad[i] = (uint32_t)c != want[i];
+        n_bad += bad[i];
+    }
+    return n_bad;
 }
 
 }  // extern "C"

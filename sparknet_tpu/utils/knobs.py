@@ -351,7 +351,8 @@ _register(
          "Feeder stall detector timeout in seconds; unset disables.",
          "sparknet_tpu/data/prefetch.py"),
     Knob("SPARKNET_RECORD_READERS", "int", "",
-         "Ranged-read pool width for record-shard feeds; 0 = serial "
+         "Reader threads of a record-shard feed, each reading runs of "
+         "records straight into their rows of the batch; 0 = serial "
          "reference path; unset = SPARKNET_FEED_WORKERS.",
          "sparknet_tpu/data/records.py"),
     Knob("SPARKNET_RECORD_SHARD_MB", "int", "64",

@@ -37,6 +37,36 @@ def test_crop_batch_matches_numpy(np_rng):
         np.testing.assert_allclose(out[i], ref - mean, rtol=1e-6)
 
 
+@pytest.mark.parametrize("shape", [(7, 3, 5, 4), (1, 1, 1, 1), (0, 2, 2, 2),
+                                   (33, 1, 64, 96)])
+def test_crc32_rows_matches_zlib(np_rng, shape):
+    """One native call over a run of rows == ``zlib.crc32`` a row, the
+    tail's chained over the row's: a flipped bit in either, or in the
+    table, flags that row and no other."""
+    import zlib
+    n = shape[0]
+    rows = np_rng.integers(0, 256, size=shape).astype(np.uint8)
+    tails = np_rng.integers(-5, 1000, size=n).astype("<i8")
+    want = np.asarray(
+        [zlib.crc32(tails[i:i + 1], zlib.crc32(rows[i])) & 0xFFFFFFFF
+         for i in range(n)], np.uint32)
+    assert not native.crc32_rows(rows, tails, want).any()
+    if n < 3:
+        return
+    rows[1, 0, -1, -1] ^= 0x10
+    tails[n - 1] += 1
+    want[n // 2] ^= 1
+    flagged = native.crc32_rows(rows, tails, want)
+    assert flagged.dtype == np.bool_
+    assert set(np.flatnonzero(flagged)) == {1, n // 2, n - 1}
+    # a view into a larger array, as the feed passes its runs
+    assert np.array_equal(
+        native.crc32_rows(rows[2:n - 1], tails[2:n - 1], want[2:n - 1]),
+        flagged[2:n - 1])
+    with pytest.raises(ValueError):
+        native.crc32_rows(rows, tails[1:], want)
+
+
 def test_crop_batch_scalar_mean(np_rng):
     batch = np.ones((2, 1, 4, 4), np.float32) * 10
     out = native.crop_batch(batch, 2, np.zeros(2, np.int32),

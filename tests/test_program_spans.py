@@ -206,6 +206,31 @@ def test_spans_reach_the_profilers_trace(flow, plane, tmp_path):
                                             ["profile", "shards"])
 
 
+def test_a_fed_batch_is_one_assemble_with_its_three_children(
+        plane, tmp_path):
+    """The feed's thread as it is since the batch is assembled in place:
+    one ``feed.assemble`` a host batch, ordinals rising from 0, and
+    inside each one ``feed.submit`` (the pulls of the batch after),
+    ``feed.collect`` and ``feed.stack`` (what is left of the stacking:
+    the labels' cast), in that order and with the batch's ordinal;
+    nothing a record."""
+    _, events = profiled(run_feed, tmp_path)
+    assembles = named(events, "feed.assemble")
+    assert [e[3]["batch"] for e in assembles] == list(range(len(assembles)))
+    assert len(assembles) >= 4              # two calls of two steps
+    children = [e for e in events
+                if e[0] in ("feed.submit", "feed.collect", "feed.stack")]
+    for a in assembles:
+        mine = [c for c in children if a[1] <= c[1] and c[2] <= a[2]]
+        assert [c[0] for c in mine] == [
+            "feed.submit", "feed.collect", "feed.stack"]
+        assert {c[3]["batch"] for c in mine} == {a[3]["batch"]}
+    assert len(children) == 3 * len(assembles)
+    feed_names = {e[0] for e in events if e[0].startswith("feed.")}
+    assert feed_names == {"feed.assemble", "feed.submit", "feed.collect",
+                          "feed.stack", "feed.device_put", "feed.wait"}
+
+
 def test_step_ordinals_rise_and_the_fetch_is_once_a_call(plane, tmp_path):
     def three_calls(_):
         solver = tiny_solver(itertools.cycle(raw_batches()))
@@ -303,7 +328,8 @@ def test_feed_stats_count_a_delivered_batch_once_and_the_wait(
     """One ``FeedStats`` under both stages counts what the ``DeviceFeed``
     delivered, not that and the host stage's batches again; one a stage
     counts each stage's own.  The consumer's wait is a stage of the
-    ``DeviceFeed``'s object."""
+    ``DeviceFeed``'s object; how the records' bytes reached their rows
+    (``read_in_place``, ``read_copied``) is the host stage's."""
     device = FeedStats()
     host = device if shared else FeedStats()
     with fed(shard_dir(tmp_path), stats=device, host_stats=host) as feed:
@@ -316,13 +342,17 @@ def test_feed_stats_count_a_delivered_batch_once_and_the_wait(
     assert per["wait_s"] == pytest.approx(snap["wait_s"] / 4, abs=1e-6)
     assert per["device_put_s"] == pytest.approx(
         snap["device_put_s"] / 4, abs=1e-6)
+    made = host.snapshot()
+    # a LocalStore and no fault: every record was read into its row
+    assert made["read_in_place"] == made["records"] >= 4 * BATCH
+    assert made["read_copied"] == 0
     if shared:
-        assert snap["read_s"] > 0 and snap["records"] >= 4 * BATCH
+        assert snap["read_s"] > 0
     else:
         # the host stage runs ahead of the consumer by the queues
-        assert host.snapshot()["batches"] >= 4
-        assert host.snapshot()["wait_s"] == 0.0
+        assert made["batches"] >= 4 and made["wait_s"] == 0.0
         assert snap["read_s"] == 0.0
+        assert snap["read_in_place"] == snap["read_copied"] == 0
 
 
 _KEYED_BY_SCOPE = """

@@ -2,13 +2,17 @@
 write/read round trip and typed corruption, the converter, streaming
 ingestion through a VerifyingStore, the tiered ShardCache (RAM + disk
 spill), records_feed bit-parity against the serial LMDB decode path
-(clean AND under corrupt_record faults), thread-safe LocalStore ranged
-reads under a concurrent pool, and device-vs-host augmentation
-bit-identity at a shared RNG seed."""
+(clean AND under corrupt_record faults), its in-place assembly (the
+stream against the shards themselves and against ``workers=0``, a held
+batch never rewritten, the copying default counted, the next batch's
+reads in flight at a yield), thread-safe LocalStore ranged reads under a
+concurrent pool, and device-vs-host augmentation bit-identity at a
+shared RNG seed."""
 
 import itertools
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -321,27 +325,374 @@ def test_db_feed_dispatches_records_backend(tmp_path):
         assert np.array_equal(x["data"], y["data"])
 
 
-def test_records_feed_from_verifying_store_with_tiered_cache(tmp_path):
+@pytest.mark.parametrize("path", ["verify", "cache", "verify+cache"])
+def test_records_feed_from_verifying_store_with_tiered_cache(tmp_path, path):
+    """No ``read_into`` of the store's own on these paths (a
+    ``VerifyingStore`` reads a record at a time through its checksum and
+    retry; a ``ShardCache`` serves slices of a blob): the same bytes
+    arrive, and the feed books every record as copied."""
     recs = _records(24, seed=5)
     shards = str(tmp_path / "s")
     convert_to_shards(iter(recs), shards, shard_bytes=8 * (3 * 8 * 8 + 8))
     faults.reset_injector()
+    ref_stats = FeedStats()
     ref = _pull_batches(records_feed(_data_layer(shards, 8, "RECORDS"),
-                                     Phase.TRAIN, seed=0, workers=0), 6)
+                                     Phase.TRAIN, seed=0, workers=0,
+                                     stats=ref_stats), 6)
+    assert ref_stats.snapshot()["read_in_place"] == 6 * 8
+    assert ref_stats.snapshot()["read_copied"] == 0
     stats = FeedStats()
     cache = ShardCache(max_shards=1, stats=stats,
-                       spill_dir=str(tmp_path / "spill"), max_spill=8)
+                       spill_dir=str(tmp_path / "spill"),
+                       max_spill=8) if "cache" in path else None
     faults.reset_injector()
     got = _pull_batches(records_feed(_data_layer(shards, 8, "RECORDS"),
                                      Phase.TRAIN, seed=0, workers=2,
-                                     verify=True, cache=cache), 6)
+                                     verify="verify" in path, cache=cache,
+                                     stats=stats), 6)
     for a, b in zip(ref, got):
         assert np.array_equal(a["data"], b["data"])
         assert np.array_equal(a["label"], b["label"])
     snap = stats.snapshot()
-    assert snap["cache_misses"] >= 3          # one cold miss per shard
-    assert snap["cache_hits"] > 0             # within-shard locality
-    assert snap["cache_disk_hits"] > 0        # epoch 2 rereads spilled
+    assert snap["read_in_place"] == 0 and snap["read_copied"] == 6 * 8
+    if cache is not None:
+        assert snap["cache_misses"] >= 3          # one cold miss per shard
+        assert snap["cache_hits"] > 0             # within-shard locality
+        assert snap["cache_disk_hits"] > 0        # epoch 2 rereads spilled
+
+
+# ---------------------------------------------------------------------------
+# In-place assembly
+# ---------------------------------------------------------------------------
+
+def _raw_layer(source, batch):
+    return layer("d", "Data", [], ["data", "label"],
+                 data_param={"source": source, "batch_size": batch,
+                             "backend": "RECORDS"})
+
+
+def _expected_stream(recs, batch, batches):
+    """The stream from the records themselves: a batch is the next
+    ``batch`` records of the cyclic order whose fault coin stays down."""
+    injector = faults.get_injector()
+    good = (seq for seq in itertools.count()
+            if not injector.corrupt_record(seq))
+    out = []
+    for _ in range(batches):
+        picked = [recs[seq % len(recs)]
+                  for seq in itertools.islice(good, batch)]
+        out.append({"data": np.stack([img for img, _ in picked]),
+                    "label": np.asarray([lab for _, lab in picked],
+                                        np.float32)})
+    return out
+
+
+@pytest.mark.parametrize("workers", [1, 3, 16])
+@pytest.mark.parametrize("corrupt", [False, True])
+def test_in_place_stream_is_the_serial_one(tmp_path, monkeypatch, corrupt,
+                                           workers):
+    """Batch edges inside a shard (20 records a shard, 16 a batch) and
+    across an epoch's end (50 records): the raw in-place stream equals
+    the shards' own records in pull order and the ``workers=0`` stream
+    bit for bit, with equal quarantine reports, and the counters say how
+    each record's bytes arrived."""
+    if corrupt:
+        monkeypatch.setenv("SPARKNET_FAULT", "corrupt_record:0.1")
+        monkeypatch.setenv("SPARKNET_FAULT_ATTEMPT", "0")
+    n, batch, batches = 50, 16, 8
+    recs = _records(n, seed=3)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards,
+                      shard_bytes=20 * (3 * 8 * 8 + 8))
+    assert len(os.listdir(shards)) == 3
+
+    def stream(w):
+        faults.reset_injector()
+        q = Quarantine(QuarantinePolicy(max_fraction=0.5), epoch_size=n)
+        stats = FeedStats()
+        got = _pull_batches(records_feed(
+            _raw_layer(shards, batch), Phase.TRAIN, raw=True, quarantine=q,
+            workers=w, stats=stats), batches)
+        return got, q.report(), stats.snapshot()
+
+    ref, ref_report, _ = stream(0)
+    got, report, snap = stream(workers)
+    want = _expected_stream(recs, batch, batches)
+    for a, b, c in zip(want, ref, got):
+        assert c["data"].dtype == np.uint8 and c["label"].dtype == np.float32
+        for key in ("data", "label"):
+            assert np.array_equal(a[key], b[key])
+            assert np.array_equal(a[key], c[key])
+    assert report == ref_report
+    assert snap["batches"] == batches and snap["read_s"] > 0
+    consumed = batches * batch + report["total_bad"]
+    if corrupt:
+        assert report["total_bad"] > 0 and report["epochs_completed"] >= 2
+        # an injected fault rots a copy of the record's bytes
+        assert snap["read_copied"] == report["total_bad"]
+    assert snap["read_in_place"] + snap["read_copied"] == consumed
+    assert snap["read_in_place"] == consumed - report["total_bad"]
+
+
+@pytest.mark.parametrize("corrupt", [False, True])
+def test_a_held_batch_is_never_written_again(tmp_path, monkeypatch, corrupt):
+    """``buffers=0``: a consumer that keeps every batch it was handed
+    finds each as it was yielded, whatever the feed read since, and no
+    two share memory."""
+    if corrupt:
+        monkeypatch.setenv("SPARKNET_FAULT", "corrupt_record:0.1")
+        monkeypatch.setenv("SPARKNET_FAULT_ATTEMPT", "0")
+    recs = _records(40, seed=9)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards, shard_bytes=16 * (3 * 8 * 8 + 8))
+    faults.reset_injector()
+    feed = records_feed(_raw_layer(shards, 8), Phase.TRAIN, raw=True,
+                        workers=4,
+                        quarantine=Quarantine(
+                            QuarantinePolicy(max_fraction=0.5),
+                            epoch_size=40))
+    held = list(itertools.islice(feed, 4))
+    for _ in range(3):          # the feed reads on, two batches ahead
+        next(feed)
+    feed.close()
+    for want, got in zip(_expected_stream(recs, 8, 4), held):
+        assert np.array_equal(want["data"], got["data"])
+        assert np.array_equal(want["label"], got["label"])
+    for i, a in enumerate(held):
+        for b in held[i + 1:]:
+            assert not np.shares_memory(a["data"], b["data"])
+
+
+def test_only_arrays_nobody_holds_are_read_into_again(tmp_path):
+    """``buffers=0``: the feed reads into one of its earlier arrays once
+    every reference to it is gone (its pages are mapped; a fresh array's
+    are not), and into no array that anything still holds: a name, a
+    view, a dict."""
+    from sparknet_tpu.data.records import _unheld
+    a = np.zeros(4, np.uint8)
+    spare = [a]
+    assert _unheld(spare) is None           # ``a`` holds it
+    view = a[1:]
+    del a
+    assert _unheld(spare) is None           # a view holds it
+    del view
+    assert _unheld(spare) is not None and spare == []
+
+    recs = _records(40, seed=10)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards, shard_bytes=16 * (3 * 8 * 8 + 8))
+    faults.reset_injector()
+    feed = records_feed(_raw_layer(shards, 8), Phase.TRAIN, raw=True,
+                        workers=2)
+    want = _expected_stream(recs, 8, 14)
+
+    def address(arr):
+        return arr.__array_interface__["data"][0]
+
+    kept = next(feed)                       # held to the end
+    row = next(feed)["data"][3]             # a view of the second, held
+    seen = []
+    for k in range(2, 14):
+        b = next(feed)
+        assert np.array_equal(want[k]["data"], b["data"])
+        assert np.array_equal(want[k]["label"], b["label"])
+        seen.append(address(b["data"]))
+        del b
+    feed.close()
+    assert len(set(seen)) < len(seen)       # memory came round again
+    assert address(kept["data"]) not in seen
+    assert address(row.base) not in seen
+    assert np.array_equal(kept["data"], want[0]["data"])
+    assert np.array_equal(row, want[1]["data"][3])
+
+
+def test_device_batches_held_stay_as_delivered(tmp_path):
+    """On the CPU backend ``device_put`` may alias the host array: the
+    alias is a holder like any other, so device batches a consumer
+    keeps stay as delivered while the feed reads on behind them."""
+    from sparknet_tpu.data import device_feed
+    recs = _records(40, seed=14)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards, shard_bytes=16 * (3 * 8 * 8 + 8))
+    faults.reset_injector()
+    want = _expected_stream(recs, 8, 6)
+    with device_feed(records_feed(_raw_layer(shards, 8), Phase.TRAIN,
+                                  raw=True, workers=2), depth=2) as feed:
+        held = [next(feed) for _ in range(6)]
+        for _ in range(10):
+            next(feed)
+        for a, b in zip(want, held):
+            assert np.array_equal(a["data"], np.asarray(b["data"]))
+            assert np.array_equal(a["label"], np.asarray(b["label"]))
+
+
+def test_raw_buffers_rotate_under_the_rings_contract(tmp_path):
+    """``buffers=N`` on the raw path: the yielded array is the ring's,
+    right when it is yielded, and handed out again N batches later —
+    the aliasing the parameter documents, no more and no less."""
+    recs = _records(40, seed=8)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards, shard_bytes=16 * (3 * 8 * 8 + 8))
+    faults.reset_injector()
+    feed = records_feed(_raw_layer(shards, 8), Phase.TRAIN, raw=True,
+                        workers=2, buffers=4)
+    held = []
+    for want in _expected_stream(recs, 8, 6):
+        got = next(feed)
+        assert np.array_equal(want["data"], got["data"])
+        assert np.array_equal(want["label"], got["label"])
+        held.append(got["data"])
+    feed.close()
+    assert np.shares_memory(held[0], held[4])
+    assert np.shares_memory(held[1], held[5])
+    assert not any(np.shares_memory(held[0], h) for h in held[1:4])
+
+
+@pytest.mark.parametrize("workers,toolchain", [(0, True), (2, True),
+                                               (2, False)])
+def test_rot_on_the_medium_is_caught_in_the_row(tmp_path, monkeypatch,
+                                                workers, toolchain):
+    """A flipped byte and a shard cut short inside its last record: the
+    in-place crc check files both with the record's key and offset, the
+    stream goes on with the records that are whole, and nothing of the
+    bad ones is delivered — by the native check of a run, and by the
+    ``zlib.crc32`` loop that stands in where nothing compiles."""
+    from sparknet_tpu import native
+    if toolchain:
+        assert native.available()
+    else:
+        monkeypatch.setattr(native, "get_lib", lambda: None)
+    recs = _records(12, seed=6)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards)
+    (name,) = os.listdir(shards)
+    path = os.path.join(shards, name)
+    shard = RecordShard.open(path)
+    with open(path, "r+b") as f:
+        f.seek(shard.offset(3) + 5)
+        orig = f.read(1)[0]
+        f.seek(shard.offset(3) + 5)
+        f.write(bytes([orig ^ 0xFF]))
+        f.truncate(shard.offset(12) - 10)
+    faults.reset_injector()
+    q = Quarantine(QuarantinePolicy(max_fraction=0.5), epoch_size=12)
+    got = _pull_batches(records_feed(_raw_layer(shards, 4), Phase.TRAIN,
+                                     raw=True, quarantine=q,
+                                     workers=workers), 5)
+    whole = [r for i, r in enumerate(recs) if i not in (3, 11)]
+    for k, b in enumerate(got):
+        want = [whole[(4 * k + j) % 10] for j in range(4)]
+        assert np.array_equal(b["data"], np.stack([i for i, _ in want]))
+        assert np.array_equal(b["label"],
+                              np.asarray([l for _, l in want], np.float32))
+    report = q.report()
+    assert report["total_bad"] == 3     # 3, 11, and 3 again an epoch on
+    assert [(e["key"], e["offset"]) for e in report["examples"][:2]] == [
+        ("3", shard.offset(3)), ("11", shard.offset(11))]
+    assert all(path in e["source"] or shards in e["source"]
+               for e in report["examples"])
+
+
+def test_closing_the_device_feed_stops_the_readers(tmp_path):
+    """The feed reads a batch ahead of the one it yielded, so its readers
+    are busy whenever a consumer stops: ``DeviceFeed.close`` closes the
+    generator too, and no reader thread is left for the interpreter's
+    shutdown to find."""
+    from sparknet_tpu.data import device_feed
+    recs = _records(64, seed=12)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards)
+    faults.reset_injector()
+
+    def readers():
+        return [t for t in threading.enumerate()
+                if t.name.startswith(f"records:{shards}")]
+
+    feed = device_feed(records_feed(_raw_layer(shards, 8), Phase.TRAIN,
+                                    raw=True, workers=3), depth=2)
+    first = next(feed)
+    assert np.array_equal(np.asarray(first["data"]),
+                          np.stack([img for img, _ in recs[:8]]))
+    assert len(readers()) == 3
+    feed.close()
+    assert readers() == []
+
+
+def test_a_feed_collected_at_shutdown_waits_for_no_lock(tmp_path,
+                                                        monkeypatch):
+    """A consumer that never closed its feed leaves the generator to a
+    finalizing interpreter, whose daemon readers stopped wherever they
+    stood, reading ahead: with the store's lock held as such a reader
+    would hold it, the generator's ``finally`` still returns."""
+    import sys
+    recs = _records(32, seed=13)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards)
+    faults.reset_injector()
+    feed = records_feed(_raw_layer(shards, 8), Phase.TRAIN, raw=True,
+                        workers=2)
+    next(feed)
+    frame = feed.gi_frame.f_locals
+    pool, store = frame["pool"], frame["shards"].shards[0].store
+    monkeypatch.setattr(sys, "is_finalizing", lambda: True)
+    assert store._lock.acquire(timeout=5)
+    try:
+        closer = threading.Thread(target=feed.close)
+        closer.start()
+        closer.join(timeout=10)
+        assert not closer.is_alive()
+    finally:
+        store._lock.release()
+        monkeypatch.undo()
+        pool.close()
+        store.close()
+
+
+def test_next_batch_reads_are_in_flight_at_a_yield(tmp_path, monkeypatch):
+    """Through a store whose reads of the second batch block on an
+    event: the first batch is yielded all the same, and while the
+    generator is suspended there (so nothing can be submitted any more)
+    the readers are found inside reads of the second batch."""
+    from sparknet_tpu.data import records as records_mod
+
+    recs = _records(32, seed=4)
+    shards = str(tmp_path / "shards")
+    convert_to_shards(iter(recs), shards)
+    (name,) = os.listdir(shards)
+    batch, stride = 8, 3 * 8 * 8 + 8
+    second = RecordShard.open(os.path.join(shards, name)).offset(batch)
+    release, started = threading.Event(), []
+
+    class GatedStore(LocalStore):
+        def read_into(self, key, offset, buffers):
+            if offset >= second:
+                started.append(offset)
+                assert release.wait(timeout=30)
+            return super().read_into(key, offset, buffers)
+
+    monkeypatch.setattr(records_mod, "get_store",
+                        lambda url: (GatedStore(url), ""))
+    faults.reset_injector()
+    feed = records_feed(_raw_layer(shards, batch), Phase.TRAIN, raw=True,
+                        workers=2)
+    try:
+        first = next(feed)
+        assert np.array_equal(first["data"],
+                              np.stack([img for img, _ in recs[:batch]]))
+        for _ in range(100):            # the readers pick their runs up
+            if len(started) == 2:
+                break
+            time.sleep(0.05)
+        # both readers sit in runs of the second batch, and nothing of
+        # the third has been read into: its pulls come at the next turn
+        assert sorted(started) == [second, second + 4 * stride]
+        release.set()
+        nxt = next(feed)
+        assert np.array_equal(
+            nxt["data"], np.stack([img for img, _ in recs[batch:2 * batch]]))
+    finally:
+        release.set()
+        feed.close()
 
 
 # ---------------------------------------------------------------------------
