@@ -57,7 +57,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..graph.net import Net, WeightCollection
-from ..ops.augment import SCOPE as AUGMENT_SCOPE
+from ..ops import augment
 from ..proto.caffe_pb import NetState, Phase, SolverParameter
 from ..utils import telemetry
 from ..solvers.lr_policies import learning_rate
@@ -227,42 +227,24 @@ def device_crop_mirror_mean(crop: int, mirror: bool = True,
     data_transformer.cpp).  The host then ships raw full-size images and
     does no per-pixel work at all — the TPU-native resolution of the
     reference's measured feed bottleneck (java_data_layer.cpp:36-44)."""
-    mean_arr = jnp.asarray(mean, jnp.float32) if mean is not None else None
-    # a crop-sized mean (the pycaffe mean-file shape) is subtracted AFTER
-    # cropping; a full-size mean before (equivalent to subtracting at each
-    # window); anything else should fail clearly, not deep in jit tracing
-    mean_after = (mean_arr is not None and mean_arr.ndim >= 2
-                  and mean_arr.shape[-2:] == (crop, crop))
-
-    @jax.named_scope(AUGMENT_SCOPE)
+    @jax.named_scope(augment.SCOPE)
     def pre(micro, rng):
         data = micro[field]
         lead = data.shape[:-3]
         c, h, w = data.shape[-3:]
-        flat = data.reshape((-1, c, h, w)).astype(jnp.float32)
-        if mean_arr is not None and not mean_after:
-            if mean_arr.ndim >= 2 and mean_arr.shape[-2:] != (h, w):
-                raise ValueError(
-                    f"device mean shape {mean_arr.shape} matches neither "
-                    f"the full image ({h}, {w}) nor the crop "
-                    f"({crop}, {crop})")
-            flat = flat - mean_arr
+        flat = data.reshape((-1, c, h, w))
         n = flat.shape[0]
         ky, kx, kf = jax.random.split(rng, 3)
         ys = jax.random.randint(ky, (n,), 0, h - crop + 1)
         xs = jax.random.randint(kx, (n,), 0, w - crop + 1)
-        flips = (jax.random.bernoulli(kf, 0.5, (n,)) if mirror
-                 else jnp.zeros((n,), bool))
-
-        def one(img, y, x, f):
-            win = lax.dynamic_slice(img, (0, y, x), (c, crop, crop))
-            if mean_after:
-                # crop-sized mean subtracts at unmirrored coordinates
-                # (data_transformer.cpp mirrors the subtracted result)
-                win = win - mean_arr
-            return jnp.where(f, win[:, :, ::-1], win)
-
-        out = jax.vmap(one)(flat, ys, xs, flips)
+        flips = jax.random.bernoulli(kf, 0.5, (n,)) if mirror else None
+        out = augment.crop_mirror(flat, ys, xs, flips, crop)
+        if mean is not None:
+            # full-size: each sample's window; crop-sized (the pycaffe
+            # mean-file shape): mirrored with its sample, since
+            # data_transformer.cpp mirrors the subtracted result
+            out = out - augment.mean_window(mean, (c, h, w), ys, xs, flips,
+                                            crop)
         return {**micro, field: out.reshape(lead + (c, crop, crop))}
 
     return pre

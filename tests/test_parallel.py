@@ -374,6 +374,63 @@ def test_device_preprocess_crop_sized_mean(np_rng):
         bad({"data": x}, jax.random.PRNGKey(0))
 
 
+@pytest.mark.parametrize("mirror", [True, False])
+@pytest.mark.parametrize("mean", ["none", "channel", "planes", "image",
+                                  "crop"])
+@pytest.mark.parametrize("dtype,path", [("uint8", "select"),
+                                        ("float32", "gather")])
+def test_device_preprocess_is_the_numpy_oracle_bit_for_bit(
+        np_rng, monkeypatch, dtype, path, mean, mirror):
+    """``device_crop_mirror_mean`` against numpy on the trainer's own
+    draws: per-channel values, the same broadcast to an image, a
+    full-size mean image (subtracted at each sample's window) and a
+    crop-sized one (mirrored with its sample), for a raw uint8 feed (the
+    selection) and a float32 one (the gather); the choice is counted."""
+    from sparknet_tpu.parallel import device_crop_mirror_mean
+    from sparknet_tpu.utils import telemetry
+
+    crop, full, c = 28, 32, 3
+    chan = np.asarray([104.0, 117.0, 123.0], np.float32).reshape(c, 1, 1)
+    mean_arr = {
+        "none": None, "channel": chan,
+        "planes": np.broadcast_to(chan, (c, full, full)),
+        "image": np_rng.normal(110, 30, (c, full, full)).astype(np.float32),
+        "crop": np_rng.normal(110, 30, (c, crop, crop)).astype(np.float32),
+    }[mean]
+    x = np_rng.integers(0, 256, size=(2, 4, c, full, full)).astype(dtype)
+    rng = jax.random.PRNGKey(5)
+    for k in ("SPARKNET_TELEMETRY", "SPARKNET_TRACE_DIR",
+              "SPARKNET_METRICS_SNAP"):
+        monkeypatch.delenv(k, raising=False)
+    telemetry.reset()
+    try:
+        pre = device_crop_mirror_mean(crop, mirror=mirror, mean=mean_arr)
+        out = np.asarray(jax.jit(pre)({"data": x}, rng)["data"])
+        fam = telemetry.get_registry().snapshot()["augment_lowering_total"]
+        assert {s["labels"]["path"]: s["value"]
+                for s in fam["samples"]} == {path: 1.0}
+    finally:
+        telemetry.reset()
+
+    ky, kx, kf = jax.random.split(rng, 3)
+    ys = np.asarray(jax.random.randint(ky, (8,), 0, full - crop + 1))
+    xs = np.asarray(jax.random.randint(kx, (8,), 0, full - crop + 1))
+    flips = (np.asarray(jax.random.bernoulli(kf, 0.5, (8,))) if mirror
+             else np.zeros(8, bool))
+    flat = x.reshape(8, c, full, full).astype(np.float32)
+    if mean in ("channel", "planes", "image"):
+        flat = flat - mean_arr
+    want = np.empty((8, c, crop, crop), np.float32)
+    for i in range(8):
+        win = flat[i, :, ys[i]:ys[i] + crop, xs[i]:xs[i] + crop]
+        if mean == "crop":
+            win = win - mean_arr
+        want[i] = win[:, :, ::-1] if flips[i] else win
+    assert out.dtype == np.float32 and out.shape == (2, 4, c, crop, crop)
+    assert np.array_equal(out.reshape(want.shape), want)
+    assert not mirror or 0 < flips.sum() < 8
+
+
 def test_uneven_partition_eval_matches_per_worker_truth(np_rng):
     """Reference semantics for unequal partitions (each zipPartitions
     worker tests its OWN `len` batches — ImageNetApp.scala:108-141): the

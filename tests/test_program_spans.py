@@ -87,7 +87,9 @@ def fed(source, stats=None, host_stats=None):
     return device_feed(host, depth=2, stats=stats)
 
 
-def tiny_trainer():
+def tiny_trainer(raw=False):
+    """``raw``: the feed ships uint8 and the mean is per-channel values
+    broadcast to an image, as the benchmark's round has them."""
     crop, full = 6, 8
     net = net_param("devpre", [
         java_data_layer("input", ["data", "label"], None,
@@ -99,15 +101,18 @@ def tiny_trainer():
     ])
     sp = load_solver_prototxt_with_net(SOLVER_TXT, net)
     rng = np.random.default_rng(2)
+    mean = rng.normal(size=(1, full, full)).astype(np.float32)
+    data = rng.normal(size=(2, 8, 1, full, full)).astype(np.float32)
+    if raw:
+        mean = np.full((1, full, full), 16.0, np.float32)
+        data = rng.integers(0, 256, size=data.shape).astype(np.uint8)
     trainer = DistributedTrainer(
         sp, make_mesh(2), TrainerConfig(
             strategy="local_sgd", tau=2,
             device_preprocess=device_crop_mirror_mean(
-                crop, mirror=True,
-                mean=rng.normal(size=(1, full, full)).astype(np.float32))),
+                crop, mirror=True, mean=mean)),
         seed=0)
-    batches = {"data": rng.normal(size=(2, 8, 1, full, full)
-                                  ).astype(np.float32),
+    batches = {"data": data,
                "label": rng.integers(0, 4, size=(2, 8)).astype(np.float32)}
     return trainer, batches
 
@@ -300,16 +305,18 @@ def test_lowered_program_names_the_augment_scope(what):
         lowered = solver._step.lower(solver.params, solver.state,
                                      solver.iter, stacked, solver._rng)
     else:
-        trainer, batches = tiny_trainer()
+        trainer, batches = tiny_trainer(raw=True)
         lowered = trainer._round.lower(
             trainer.params, trainer.state, jnp.asarray(trainer.iter),
             {k: jnp.asarray(v) for k, v in batches.items()}, trainer._rng,
             jnp.asarray(trainer.lr_scale, jnp.float32))
-    text = lowered.as_text(debug_info=True)
-    # the crop (a dynamic slice under vmap: a gather) carries the scope
-    assert any("L[augment]" in line and ("gather" in line
-                                         or "dynamic_slice" in line)
-               for line in text.splitlines())
+    scoped = [line for line in lowered.as_text(debug_info=True).splitlines()
+              if "L[augment]" in line]
+    # a uint8 batch is cropped and mirrored by selection: the products
+    # carry the scope, and nothing under it gathers or slices by sample
+    assert any("dot_general" in line for line in scoped)
+    assert not any("gather" in line or "dynamic_slice" in line
+                   or "reverse" in line for line in scoped)
 
 
 @pytest.mark.parametrize("flow", ["step", "feed", "round"])

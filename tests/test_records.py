@@ -720,6 +720,98 @@ def test_device_and_host_augment_arrays_bit_identical():
     assert np.array_equal(dev_t, host_t)
 
 
+_MEANS = {
+    "none": lambda c, h, w, rng: None,
+    "channel": lambda c, h, w, rng: np.asarray(
+        [104.0, 117.0, 123.0][:c], np.float32).reshape(c, 1, 1),
+    "planes": lambda c, h, w, rng: np.ascontiguousarray(np.broadcast_to(
+        np.asarray([104.0, 117.0, 123.0][:c], np.float32).reshape(c, 1, 1),
+        (c, h, w))),
+    "image": lambda c, h, w, rng: rng.normal(
+        110.0, 30.0, size=(c, h, w)).astype(np.float32),
+}
+
+
+@pytest.mark.parametrize("mean", sorted(_MEANS))
+@pytest.mark.parametrize("offsets,flips", [
+    ("drawn", "drawn"), ("zero", "all"), ("far", "none"), ("far", "all")])
+@pytest.mark.parametrize("shape,crop", [
+    ((2, 3, 256, 256), 227), ((2, 3, 256, 256), 224), ((5, 1, 32, 32), 28),
+    ((3, 3, 12, 12), 0)])
+@pytest.mark.parametrize("dtype", ["uint8", "float32"])
+def test_crop_and_mirror_are_the_host_oracle_bit_for_bit(
+        monkeypatch, dtype, shape, crop, offsets, flips, mean):
+    """The selection (a uint8 batch: two one-hot products in bfloat16)
+    and the gather (a float32 batch) against the numpy oracle,
+    ``array_equal``: ImageNet's shapes and a one-channel 32 -> 28, the
+    offsets forced to 0 and to ``h - crop``, every sample mirrored and
+    none, no mean / per-channel values / the same broadcast to an image /
+    a mean image, at scale 1 and 1/255."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparknet_tpu.data.transforms import augment_batch_host
+    from sparknet_tpu.ops import augment
+    n, c, h, w = shape
+    rng = np.random.default_rng(3)
+    imgs = rng.integers(0, 256, size=shape).astype(np.uint8).astype(dtype)
+    drawn = augment.draw_offsets
+
+    def forced(key, n, h, w, spec):
+        ys, xs, fl = drawn(key, n, h, w, spec)
+        if offsets != "drawn":
+            at = 0 if offsets == "zero" or not spec.crop else h - spec.crop
+            ys = xs = jnp.full((n,), at, jnp.int32)
+        if flips != "drawn":
+            fl = jnp.full((n,), int(flips == "all"), jnp.int32)
+        return ys, xs, fl
+
+    monkeypatch.setattr(augment, "draw_offsets", forced)
+    key = jax.random.PRNGKey(11)
+    for scale in (1.0, 1.0 / 255):
+        spec = augment.AugmentSpec(crop=crop, mirror=True,
+                                   mean=_MEANS[mean](c, h, w, rng),
+                                   scale=scale, train=True)
+        dev = np.asarray(augment.augment_batch(imgs, key, spec))
+        host = augment_batch_host(imgs, key, spec)
+        assert dev.dtype == np.float32
+        assert dev.shape == augment.out_shape(shape, spec)
+        assert np.array_equal(dev, host)
+
+
+@pytest.mark.parametrize("dtype,path", [("uint8", "select"),
+                                        ("int8", "select"),
+                                        ("float32", "gather"),
+                                        ("int32", "gather")])
+def test_the_lowering_follows_the_batch_and_is_counted(monkeypatch, dtype,
+                                                       path):
+    """Integers of at most 8 bits take the selection, anything else the
+    gather; ``augment_lowering_total`` counts the choice once a trace."""
+    import jax
+
+    from sparknet_tpu.ops import augment
+    from sparknet_tpu.utils import telemetry
+    for k in ("SPARKNET_TELEMETRY", "SPARKNET_TRACE_DIR",
+              "SPARKNET_METRICS_SNAP"):
+        monkeypatch.delenv(k, raising=False)
+    telemetry.reset()
+    try:
+        spec = augment.AugmentSpec(crop=8, mirror=True, mean=[16.0])
+        imgs = np.random.default_rng(0).integers(
+            0, 100, size=(4, 1, 12, 12)).astype(dtype)
+        step = jax.jit(lambda i, k: augment.augment_batch(i, k, spec))
+        text = step.lower(imgs, jax.random.PRNGKey(0)).as_text()
+        assert ("dot_general" in text) == (path == "select")
+        for seed in range(3):               # three calls of that trace
+            step(imgs, jax.random.PRNGKey(seed))
+        fam = telemetry.get_registry().snapshot()["augment_lowering_total"]
+        assert fam["kind"] == "counter"
+        assert {s["labels"]["path"]: s["value"]
+                for s in fam["samples"]} == {path: 1.0}
+    finally:
+        telemetry.reset()
+
+
 def test_solver_device_augment_losses_bit_identical():
     """set_augment(device=True) — augmentation traced into the jitted
     step — must reproduce the host-numpy path's losses bit for bit at
