@@ -35,8 +35,8 @@ BENCH_SHARD=0 to skip, BENCH_SHARD_N/_TAU/_BATCH; serving tier
 (closed-loop latency/QPS through the
 inference engine — see measure_serving): BENCH_SERVING=0 to skip,
 BENCH_SERVE_MODEL/_CLIENTS/_WINDOW/_SECONDS; vertical fusion:
-BENCH_FUSE=off|auto|all|<plan.json> pins SPARKNET_FUSE for the run
-(graph/fusion.py; captures carry the resulting fuse_plan id).
+BENCH_FUSE=off sets SPARKNET_FUSE=off for the run (graph/fusion.py;
+captures carry the resulting fuse_plan id).
 """
 
 from __future__ import annotations
@@ -76,10 +76,9 @@ def _log(msg: str) -> None:
 
 
 def main() -> int:
-    # BENCH_FUSE pins the vertical-fusion plan source for every net this
-    # run builds (off | auto | all | <plan.json> — graph/fusion.py);
-    # unset inherits the ambient SPARKNET_FUSE (default auto).  Must land
-    # before the first Net construction: the plan latches there.
+    # BENCH_FUSE=off runs every net this run builds per layer
+    # (graph/fusion.py); unset inherits the ambient SPARKNET_FUSE.  Must
+    # land before the first Net construction: the plan latches there.
     if os.environ.get("BENCH_FUSE"):
         os.environ["SPARKNET_FUSE"] = os.environ["BENCH_FUSE"]
     import jax
@@ -109,8 +108,6 @@ def main() -> int:
         BENCH_SOLVER_PROTOTXT,
         build_bench_model,
         peak_flops,
-        record_fusion_plan,
-        record_tuning,
         scanned_train_block,
         step_cost_flops,
     )
@@ -207,9 +204,7 @@ def main() -> int:
             "flops_per_step": flops_per_step,
             # the train net's vertical-fusion plan id — the ledger
             # fingerprint field keeping fused/unfused bands separate
-            "fuse_plan": record_fusion_plan(solver.train_net),
-            # lowering-autotuner table id (graph/tuner.py), same role
-            "tune_plan": record_tuning(solver.train_net),
+            "fuse_plan": solver.train_net.fuse_plan_id(),
         }
 
     def measure_feed(dtype: str) -> dict:
@@ -682,7 +677,7 @@ def main() -> int:
     fp = perfledger.fingerprint(
         model=MODEL, dtype=best, batch=BATCH, world=1,
         device=f"{dev.platform}/{dev.device_kind}", backend=dev.platform,
-        fuse_plan=b.get("fuse_plan"), tune_plan=b.get("tune_plan"),
+        fuse_plan=b.get("fuse_plan"),
         feed_source="records" if feed_records else "lmdb")
     result = {
         "metric": f"{MODEL}_train_images_per_sec",
@@ -702,7 +697,6 @@ def main() -> int:
         "dtype_note": ("mixed precision; f32 master params/losses/BN stats"
                        if best == "bf16" else None),
         "fuse_plan": b.get("fuse_plan"),
-        "tune_plan": b.get("tune_plan"),
         "batch": BATCH,
         "iters_per_block": ITERS,
         "reps": REPS,

@@ -2,9 +2,8 @@
 
 Functions reachable from a trace root must be pure with respect to the
 host: an ``os.environ`` read inside a jitted function evaluates once at
-trace time and bakes a constant into the executable (breaking the
-``SPARKNET_TUNE=off``-equals-``auto`` structural guarantee and making
-jit cache keys lie); clocks, host RNG, file IO and ``print`` similarly
+trace time and bakes a constant into the executable (making jit cache
+keys lie); clocks, host RNG, file IO and ``print`` similarly
 run at trace time, not step time.
 
 Trace roots recognised (project conventions included):

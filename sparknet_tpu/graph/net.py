@@ -208,7 +208,6 @@ class Net:
                 f"input_overrides for non-input blobs: {sorted(unknown)}")
         self._detect_hfuse_groups()
         self._detect_vfuse_chains()
-        self._latch_tune_plan()
         self._fuse_skip_noted: set[str] = set()
 
     def _detect_hfuse_groups(self) -> None:
@@ -254,47 +253,26 @@ class Net:
                 self._hfuse_member.update(m.lp.name for m in members[1:])
 
     def _detect_vfuse_chains(self) -> None:
-        """Vertical conv+bias+relu(+pool/LRN) chain fusion, planned by
-        ``graph/fusion.py`` from the SPARKNET_FUSE source (off | auto
-        [default, profile-worklist-driven] | all | <plan.json>) —
-        latched at Net construction like the hfuse toggle.  Runs AFTER
-        hfuse detection: horizontal groups keep their members, vertical
+        """Vertical chain fusion: the graph's legal conv..LRN chains
+        (``graph/fusion.py``), or none under SPARKNET_FUSE=off — latched
+        at Net construction like the hfuse toggle.  Runs AFTER hfuse
+        detection: horizontal groups keep their members, vertical
         chains take what's left."""
         from . import fusion
         self._fuse_plan = fusion.resolve_plan(self)
-        self._vfuse_head: dict[str, fusion.FusedChain] = {}
-        self._vfuse_member: set[str] = set()
-        if self._fuse_plan is None:
-            return
-        for ch in self._fuse_plan.chains:
-            if not all(m in self._node_by_name for m in ch.members):
-                continue   # plan from another net's namespace
-            self._vfuse_head[ch.members[0]] = ch
-            self._vfuse_member.update(ch.members[1:])
+        chains = self._fuse_plan.chains
+        self._vfuse_head = {ch.members[0]: ch for ch in chains}
+        self._vfuse_member = {m for ch in chains for m in ch.members[1:]}
 
     def fuse_plan_id(self) -> str:
         """Short id of the active vertical-fusion plan (``off`` when
-        none) — the perf-ledger fingerprint field that keeps fused and
-        unfused captures out of each other's baseline bands."""
-        plan = getattr(self, "_fuse_plan", None)
-        return plan.plan_id() if plan is not None else "off"
-
-    def _latch_tune_plan(self) -> None:
-        """Resolve SPARKNET_TUNE ONCE at Net construction (the hfuse/
-        vfuse latch discipline: flipping the env after jit never
-        retraces) so a typo'd table path or a drifted/wrong-backend
-        table fails HERE, loudly, not mid-training — and so the
-        tune_plan fingerprint the ledger stamps is the table the traced
-        lowerings actually consulted."""
-        from . import tuner
-        self._tune_plan_id = tuner.active_plan_id()
+        none): what a run prints to say which chains it fused."""
+        return self._fuse_plan.plan_id()
 
     def tune_plan_id(self) -> str:
-        """Short id of the lowering-autotuner table active when this net
-        was built (``off`` when none) — the perf-ledger fingerprint
-        field that keeps tuned and untuned captures out of each other's
-        baseline bands (graph/tuner.py)."""
-        return getattr(self, "_tune_plan_id", "off")
+        """Always ``off``: there is no tuning table.  Kept because the
+        benchmark's drivers print it (ROADMAP D12)."""
+        return "off"
 
     def _note_unfused_run(self, reason: str) -> None:
         """A fusable net executing unfused (ranged run, eps injection,
@@ -590,8 +568,9 @@ class Net:
         # apply_all must surface REAL intermediate blobs).  Horizontal
         # 1x1-sibling fusion: on by default (exact transform, measured
         # -5.6% GoogLeNet step), SPARKNET_NO_HFUSE=1 restores per-layer
-        # execution.  Vertical chains: planned per SPARKNET_FUSE
-        # (graph/fusion.py).  Both latched at Net construction.
+        # execution.  Vertical chains: the graph's conv..LRN chains
+        # (graph/fusion.py), SPARKNET_FUSE=off for none.  Both latched
+        # at Net construction.
         full_run = start is None and upto is None and not eps \
             and not introspect
         hfuse_on = (bool(self._hfuse_first) and full_run
@@ -736,15 +715,14 @@ class Net:
 
         The head conv runs through its own impl (XLA's MXU tiling is
         already optimal; on eligible stems that includes the
-        space-to-depth rewrite).  An LRN tail with a fused epilogue
-        collapses [ReLU+]LRN into ``ops.vision.lrn_chain_epilogue`` —
-        the Pallas one-VMEM-trip kernel on TPU, the scale-residual
-        custom-VJP reference elsewhere.  Every other member applies its
-        own impl inside the shared ``L[a+b+...]`` scope, so the whole
-        chain profiles as ONE row (the post-fusion view perfwatch's
-        worklist consumes) and those segments stay bit-identical to
-        per-layer execution."""
+        space-to-depth rewrite), and so do a pool and a ReLU that does
+        not fold.  The tail, [ReLU+]LRN, is
+        ``ops.vision.lrn_chain_epilogue`` — the Pallas one-VMEM-trip
+        kernel on TPU, the scale-residual custom-VJP reference
+        elsewhere.  All of it sits in the shared ``L[a+b+...]`` scope,
+        so the chain profiles as ONE row."""
         from ..ops.vision import lrn_chain_epilogue, lrn_geometry
+        folds = ch.epilogue == "relu+lrn"
         head = members[0]
         x = blobs[head.bottoms[0]]
         p = self.node_params(params, head)
@@ -753,29 +731,11 @@ class Net:
             p = self._cast(p, cd)
         with jax.named_scope(f"L[{ch.scope()}]"):
             (y,) = head.impl.apply(head.lp, p, [x], train, None)
-            i = 1
-            while i < len(members):
-                m = members[i]
-                nxt = members[i + 1] if i + 1 < len(members) else None
-                if (ch.epilogue == "relu+lrn" and m.lp.type == "ReLU"
-                        and nxt is not None and nxt.lp.type == "LRN"):
-                    size, alpha, beta, k, _ = lrn_geometry(nxt.lp)
-                    y = lrn_chain_epilogue(y, size, alpha, beta, k,
-                                           relu=True)
-                    i += 2
-                    continue
-                if (ch.epilogue in ("lrn", "relu+lrn")
-                        and m.lp.type == "LRN" and nxt is None):
-                    size, alpha, beta, k, _ = lrn_geometry(m.lp)
-                    y = lrn_chain_epilogue(y, size, alpha, beta, k,
-                                           relu=False)
-                    i += 1
-                    continue
-                mp = self.node_params(params, m)
-                if cd is not None:
-                    mp = self._cast(mp, cd)
-                (y,) = m.impl.apply(m.lp, mp, [y], train, None)
-                i += 1
+            # between head and tail: a ReLU, a pool; neither has blobs
+            for m in members[1:-2 if folds else -1]:
+                (y,) = m.impl.apply(m.lp, [], [y], train, None)
+            size, alpha, beta, k, _ = lrn_geometry(members[-1].lp)
+            y = lrn_chain_epilogue(y, size, alpha, beta, k, relu=folds)
         return y
 
     # -- introspection (FFI-parity helpers; reference: ccaffe.cpp:86-139,

@@ -178,12 +178,6 @@ def _lrn_vjp_bwd(size, alpha, beta, k, relu, res, dy):
 relu_lrn_across_channels.defvjp(_lrn_vjp_fwd, _lrn_vjp_bwd)
 
 
-def lrn_across_channels(x, size: int, alpha: float, beta: float, k: float):
-    """Caffe ACROSS_CHANNELS LRN as a fused Pallas kernel (the
-    ``relu=False`` face of :func:`relu_lrn_across_channels`)."""
-    return relu_lrn_across_channels(x, size, alpha, beta, k, False)
-
-
 # ---------------------------------------------------------------------------
 # VMEM-resident MAX-pool backward
 #

@@ -60,15 +60,6 @@ def conv_geometry(lp: LayerParameter):
     return kh, kw, sh, sw, ph, pw, dh, dw, num_output, group, bias_term
 
 
-def _s2d_geometry_ok(c_in: int, kh, kw, sh, sw, ph, pw, dh, dw,
-                     group) -> bool:
-    """Pure geometry predicate for the space-to-depth rewrite (no env
-    reads — the tuner registers s2d as a candidate exactly where this
-    holds, and uses it for the structural default)."""
-    return (group == 1 and dh == 1 and dw == 1 and c_in * sh * sw <= 64
-            and (sh > 1 or sw > 1) and kh >= sh and kw >= sw)
-
-
 def _s2d_eligible(c_in: int, kh, kw, sh, sw, ph, pw, dh, dw, group) -> bool:
     """Space-to-depth rewrite pays off when the input-channel count starves
     the MXU's 128-wide contraction (RGB stems: C=3 → C·s² after regroup).
@@ -78,7 +69,8 @@ def _s2d_eligible(c_in: int, kh, kw, sh, sw, ph, pw, dh, dw, group) -> bool:
     after compilation has no effect on cached executables)."""
     if knobs.raw("SPARKNET_NO_S2D") == "1":
         return False
-    return _s2d_geometry_ok(c_in, kh, kw, sh, sw, ph, pw, dh, dw, group)
+    return (group == 1 and dh == 1 and dw == 1 and c_in * sh * sw <= 64
+            and (sh > 1 or sw > 1) and kh >= sh and kw >= sw)
 
 
 def _space_to_depth_conv(x, weight, kh, kw, sh, sw, ph, pw):
@@ -120,48 +112,11 @@ def _space_to_depth_conv(x, weight, kh, kw, sh, sw, ph, pw):
         dimension_numbers=DIMNUMS)
 
 
-def _im2col_conv(x, weight, kh, kw, sh, sw, ph, pw, dh, dw, group):
-    """Convolution as explicit patch extraction + grouped contraction —
-    the reference's im2col + GEMM lowering (caffe/src/caffe/util/
-    im2col.cpp, math_functions::caffe_gpu_gemm), kept as a registered
-    tuner candidate because on some backends a dense dot beats the
-    direct conv (Caffe con Troll's per-layer strategy flip).  The
-    patches feature dim is c-major — index = ch·(kh·kw) + offset — so
-    per-group slices of input channels are contiguous blocks."""
-    n, c, h, w = x.shape
-    o = weight.shape[0]
-    patches = lax.conv_general_dilated_patches(
-        x, (kh, kw), (sh, sw), ((ph, ph), (pw, pw)),
-        rhs_dilation=(dh, dw), dimension_numbers=DIMNUMS,
-    )  # (N, C·kh·kw, oh, ow)
-    oh, ow = patches.shape[2], patches.shape[3]
-    pg = patches.reshape(n, group, (c // group) * kh * kw, oh * ow)
-    wg = weight.reshape(group, o // group, (c // group) * kh * kw)
-    y = jnp.einsum("gok,ngkp->ngop", wg, pg)
-    return y.reshape(n, o, oh, ow)
-
-
-def _conv_lowering(x, weight, kh, kw, sh, sw, ph, pw, dh, dw, group,
-                   choice: str | None):
-    """Dispatch one conv bottom through the tuner-selected lowering
-    (None = the hardcoded default: s2d where eligible, else the direct
-    conv).  A table that names s2d at an ineligible geometry is a
-    drifted table — refused loudly, never silently rerouted."""
-    if choice == "s2d" or (choice is None
-                           and _s2d_eligible(x.shape[1], kh, kw, sh, sw,
-                                             ph, pw, dh, dw, group)):
-        if choice == "s2d" and not _s2d_geometry_ok(
-                x.shape[1], kh, kw, sh, sw, ph, pw, dh, dw, group):
-            raise ValueError(
-                "tuning table selects s2d for a geometry the rewrite "
-                "cannot express — drifted table, re-run tools/tune.py run")
+def _conv(x, weight, kh, kw, sh, sw, ph, pw, dh, dw, group):
+    """One conv bottom: the space-to-depth rewrite where it is eligible,
+    else the direct convolution."""
+    if _s2d_eligible(x.shape[1], kh, kw, sh, sw, ph, pw, dh, dw, group):
         return _space_to_depth_conv(x, weight, kh, kw, sh, sw, ph, pw)
-    if choice == "im2col":
-        return _im2col_conv(x, weight, kh, kw, sh, sw, ph, pw, dh, dw,
-                            group)
-    if choice not in (None, "native"):
-        raise ValueError(f"tuning table selects unknown conv lowering "
-                         f"{choice!r} — drifted table")
     return lax.conv_general_dilated(
         x, weight,
         window_strides=(sh, sw),
@@ -199,17 +154,11 @@ class ConvolutionLayer(LayerImpl):
         return blobs
 
     def apply(self, lp, params, bottoms, train, rng):
-        from ..graph import tuner
         kh, kw, sh, sw, ph, pw, dh, dw, num_output, group, bias_term = conv_geometry(lp)
         weight = params[0]
         tops = []
         for x in bottoms:
-            choice = tuner.resolve_lowering(
-                "conv", x.shape, x.dtype,
-                extra=tuner.conv_extra(kh, kw, sh, sw, ph, pw, dh, dw,
-                                       num_output, group))
-            y = _conv_lowering(x, weight, kh, kw, sh, sw, ph, pw, dh, dw,
-                               group, choice)
+            y = _conv(x, weight, kh, kw, sh, sw, ph, pw, dh, dw, group)
             if bias_term:
                 y = y + params[1].reshape(1, -1, 1, 1)
             tops.append(y)
@@ -319,31 +268,6 @@ def max_pool(x, kh, kw, sh, sw, ph, pw, oh, ow):
     )
 
 
-def _patches_pool_ok(h, w, kh, kw, sh, sw, ph, pw) -> bool:
-    """Geometry where the patches-based MAX pool is exact: zero padding
-    only (conv_general_dilated_patches pads with 0, not -inf, so any
-    padded window could wrongly beat an all-negative real window) and no
-    ceil-mode remainder (the patch count must equal Caffe's ceil-mode
-    output size, which with p=0 requires (dim-k) to divide the stride)."""
-    return (ph == 0 and pw == 0 and kh <= h and kw <= w
-            and (h - kh) % sh == 0 and (w - kw) % sw == 0)
-
-
-def max_pool_patches(x, kh, kw, sh, sw, oh, ow):
-    """MAX pooling via patch extraction + argmax/take_along_axis — a
-    registered tuner candidate for :func:`max_pool`'s geometry subset
-    (:func:`_patches_pool_ok`).  max is association-free so the forward
-    is bit-identical to reduce_window, and argmax/take_along_axis routes
-    the gradient to the window's FIRST maximum — the same choice XLA's
-    select-and-scatter makes, so gradients match even on ties."""
-    n, c, h, w = x.shape
-    patches = lax.conv_general_dilated_patches(
-        x, (kh, kw), (sh, sw), ((0, 0), (0, 0)), dimension_numbers=DIMNUMS)
-    p = patches.reshape(n, c, kh * kw, oh, ow)
-    idx = jnp.argmax(p, axis=2)
-    return jnp.take_along_axis(p, idx[:, :, None], axis=2)[:, :, 0]
-
-
 def ave_pool(x, kh, kw, sh, sw, ph, pw, oh, ow):
     """Caffe AVE pooling: zero-pad, divide by the pool window size clipped to
     the padded extent [0, dim+pad) — not the kernel area and not the valid
@@ -416,27 +340,12 @@ class PoolingLayer(LayerImpl):
         kh, kw, sh, sw, ph, pw, method = _pool_geometry(lp, x.shape)
         oh, ow = pool_output_size(h, w, kh, kw, sh, sw, ph, pw)
         if method == "MAX":
-            from ..graph import tuner
-            choice = tuner.resolve_lowering(
-                "pool", x.shape, x.dtype,
-                extra=tuner.pool_extra(kh, kw, sh, sw, ph, pw))
-            if choice == "patches_max":
-                if not _patches_pool_ok(h, w, kh, kw, sh, sw, ph, pw):
-                    raise ValueError(
-                        "tuning table selects patches_max for a padded/"
-                        "remainder pool geometry it cannot express "
-                        "exactly — drifted table, re-run tools/tune.py")
-                return [max_pool_patches(x, kh, kw, sh, sw, oh, ow)]
-            if choice == "pallas_bwd" or (choice is None
-                                          and self._use_pallas_bwd()):
+            if self._use_pallas_bwd():
                 # opt-in VMEM-resident Pallas backward (forward stays
                 # XLA reduce_window); see ops/pallas_kernels.py
                 from .pallas_kernels import max_pool_vmem_bwd
                 return [max_pool_vmem_bwd(x, kh, kw, sh, sw, ph, pw,
                                           oh, ow)]
-            if choice not in (None, "reduce_window"):
-                raise ValueError(f"tuning table selects unknown pool "
-                                 f"lowering {choice!r} — drifted table")
             return [max_pool(x, kh, kw, sh, sw, ph, pw, oh, ow)]
         if method == "AVE":
             return [ave_pool(x, kh, kw, sh, sw, ph, pw, oh, ow)]
@@ -459,26 +368,24 @@ def lrn_geometry(lp: LayerParameter):
             str(p.get("norm_region", "ACROSS_CHANNELS")))
 
 
-# Channel-count floor for the cumsum window sum when no tuning-table
-# pin decides, TPU only.  The round-10 CPU probe re-run (tools/perf_probe.py
-# lrn, RESULTS.md r10 table) REVERSED the round-6 CPU verdict: on the
-# current XLA CPU build reduce_window wins every zoo LRN shape fwd+bwd
-# (cumsum at 0.64-0.95x), so auto stays OFF on CPU — measured, not
-# assumed.  On TPU the O(C) vs O(C·size) HBM-read argument still only
-# pays where the channel axis is wide, hence the floor; the TPU capture
-# remains the final decider — a capture that contradicts this floor
-# should update it, not hand-set the env.
+# Channel-count floor for the cumsum window sum, TPU only.  The round-10
+# CPU probe re-run (tools/perf_probe.py lrn, RESULTS.md r10 table)
+# REVERSED the round-6 CPU verdict: on the current XLA CPU build
+# reduce_window wins every zoo LRN shape fwd+bwd (cumsum at 0.64-0.95x),
+# so auto stays OFF on CPU — measured, not assumed.  On TPU the O(C) vs
+# O(C·size) HBM-read argument still only pays where the channel axis is
+# wide, hence the floor; the TPU capture remains the final decider — a
+# capture that contradicts this floor should update it, not hand-set the
+# env.
 LRN_CUMSUM_AUTO_C = 128
 
 
 def lrn_use_cumsum(c_dim: int) -> bool:
-    """Default LRN window-sum formulation when neither the tuning table
-    nor a caller override decides (read at TRACE time, like the other
-    vision-layer toggles): off everywhere but TPU (the CPU probe says
-    reduce_window wins there), by channel count on TPU.  To force one
-    form, pass ``use_cumsum=`` explicitly or pin the ``lrn`` op in a
-    SPARKNET_TUNE table — the pre-tuner env pin is gone (knobs.py
-    tombstones it)."""
+    """LRN window-sum formulation when the caller does not say (read at
+    TRACE time, like the other vision-layer toggles): off everywhere but
+    TPU (the CPU probe says reduce_window wins there), by channel count
+    on TPU.  To force one form, pass ``use_cumsum=`` to
+    :func:`lrn_window_sum`."""
     if jax.default_backend() != "tpu":
         return False
     return c_dim >= LRN_CUMSUM_AUTO_C
@@ -490,9 +397,8 @@ def lrn_window_sum(sq, pre: int, post: int, use_cumsum: bool | None = None):
     Two exact-to-association formulations: ``reduce_window`` (each value
     touched ``size`` times) or a single channel-axis cumsum with two
     static gathers (``ssum[c] = cs[c+post] - cs[c-pre-1]`` — O(C) reads
-    per element).  ``use_cumsum=None`` defers to the tuner-informed
-    default (:func:`lrn_use_cumsum`); the autotuner's registered
-    candidates pass it explicitly."""
+    per element).  ``use_cumsum=None`` defers to :func:`lrn_use_cumsum`;
+    probes and tests pass it explicitly."""
     c_dim = sq.shape[1]
     if use_cumsum is None:
         use_cumsum = lrn_use_cumsum(c_dim)
@@ -523,8 +429,8 @@ def lrn_window_sum(sq, pre: int, post: int, use_cumsum: bool | None = None):
 def _relu_lrn_primal(x, size, alpha, beta, k, relu):
     """The fused-chain tail as plain XLA ops — literally the unfused
     ReLU + LRN formulas in sequence, so the undifferentiated fused
-    forward is the same HLO as the per-layer path (the fusebench
-    bit-parity contract on CPU)."""
+    forward is the same HLO as the per-layer path (bit parity on the
+    CPU, tests/test_fusion.py)."""
     a = jnp.maximum(x, 0.0) if relu else x
     pre = (size - 1) // 2
     post = size - 1 - pre
@@ -573,27 +479,14 @@ relu_lrn_reference.defvjp(_relu_lrn_ref_vjp_fwd, _relu_lrn_ref_vjp_bwd)
 def lrn_chain_epilogue(x, size: int, alpha: float, beta: float, k: float,
                        *, relu: bool):
     """The fused conv-chain tail: [ReLU +] ACROSS_CHANNELS LRN in one
-    pass over the producer's output.  On TPU this is the Pallas
-    epilogue kernel (one VMEM trip instead of the 555 GB/s
+    pass over the producer's output.  On TPU, for 4-D float32/bfloat16,
+    this is the Pallas epilogue kernel (one VMEM trip instead of the
     reduce_window chain); elsewhere the XLA reference above (same
-    custom VJP, same residuals).  The tuning table
-    (graph/tuner.py, op "lrn_epilogue") can pick per shape — read at
-    trace time, the A/B knob a profile capture flips."""
-    from ..graph import tuner
-    choice = tuner.resolve_lowering(
-        "lrn_epilogue", x.shape, x.dtype,
-        extra=tuner.epilogue_extra(size, relu))
-    pallas_ok = (x.ndim == 4 and x.dtype in (jnp.float32, jnp.bfloat16)
-                 and jax.default_backend() == "tpu")
-    if choice == "per_layer":
-        a, scale = _relu_lrn_primal(x, size, alpha, beta, k, relu)
-        return a / scale ** beta
-    if (choice == "pallas" and pallas_ok) or (choice is None and pallas_ok):
+    custom VJP, same residuals)."""
+    if (x.ndim == 4 and x.dtype in (jnp.float32, jnp.bfloat16)
+            and jax.default_backend() == "tpu"):
         from .pallas_kernels import relu_lrn_across_channels
         return relu_lrn_across_channels(x, size, alpha, beta, k, relu)
-    if choice not in (None, "reference", "pallas"):
-        raise ValueError(f"tuning table selects unknown lrn_epilogue "
-                         f"lowering {choice!r} — drifted table")
     return relu_lrn_reference(x, size, alpha, beta, k, relu)
 
 
@@ -604,13 +497,8 @@ class LRNLayer(LayerImpl):
     size-n window, out = x / scale^beta.  ACROSS_CHANNELS windows the channel
     axis; WITHIN_CHANNEL uses AVE-pooling semantics spatially.
 
-    SPARKNET_PALLAS_LRN=1 routes ACROSS_CHANNELS through the fused Pallas
-    kernel (ops/pallas_kernels.py).  Off by default: measured on TPU v5e
-    CaffeNet batch 256, the kernel wins in isolation (23.0 vs 24.2
-    ms/step) but LOSES inside the fully-fused scanned train block
-    (10.6k vs 11.0k img/s) — pallas_call is a fusion barrier, and the
-    surrounding relu/pool elementwise work XLA would have fused into the
-    LRN costs more than the kernel saves.
+    Behind a convolution (``graph/fusion.py``) the ACROSS_CHANNELS form
+    runs in :func:`lrn_chain_epilogue` instead of here.
 
     The cumsum formulation rewrites the ACROSS_CHANNELS window sum
     algebraically: instead of ``reduce_window`` touching each x² value
@@ -625,42 +513,17 @@ class LRNLayer(LayerImpl):
     round-10 probe re-run reversed round 6's CPU verdict, reduce_window
     now wins every zoo shape there (RESULTS.md r10 table) — and
     channel-count-gated on TPU, where the capture remains the final
-    decider.  A SPARKNET_TUNE table pin (op "lrn") forces either form,
-    and tools/perf_probe.py ``lrn`` is the harness (its ``auto``
+    decider.  tools/perf_probe.py ``lrn`` is the harness (its ``auto``
     variant audits the default)."""
-
-    @staticmethod
-    def _use_pallas() -> bool:
-        return knobs.raw("SPARKNET_PALLAS_LRN") == "1"
 
     def apply(self, lp, params, bottoms, train, rng):
         size, alpha, beta, k, region = lrn_geometry(lp)
         x = bottoms[0]
-        choice = None
-        if region == "ACROSS_CHANNELS" and x.ndim == 4:
-            from ..graph import tuner
-            choice = tuner.resolve_lowering(
-                "lrn", x.shape, x.dtype, extra=tuner.lrn_extra(size))
-        if (region == "ACROSS_CHANNELS" and x.ndim == 4
-                and x.dtype in (jnp.float32, jnp.bfloat16)
-                and (choice == "pallas"
-                     or (choice is None and self._use_pallas()))):
-            from .pallas_kernels import lrn_across_channels
-            return [lrn_across_channels(x, size, alpha, beta, k)]
-        if choice == "closed_vjp":
-            # same forward HLO as the per-layer formulas below, but the
-            # closed-form scale-residual VJP (the fusebench contract)
-            return [relu_lrn_reference(x, size, alpha, beta, k, False)]
-        if choice not in (None, "pallas", "reduce_window", "cumsum"):
-            raise ValueError(f"tuning table selects unknown lrn lowering "
-                             f"{choice!r} — drifted table")
         sq = x * x
         if region == "ACROSS_CHANNELS":
             pre = (size - 1) // 2
             post = size - 1 - pre
-            ssum = lrn_window_sum(
-                sq, pre, post,
-                use_cumsum=None if choice is None else choice == "cumsum")
+            ssum = lrn_window_sum(sq, pre, post)
         else:  # WITHIN_CHANNEL: x · (1 + α·avgpool(x²))^-β  (lrn_layer.cpp
             # WithinChannelForward: square → AVE pool → power(shift=1,
             # scale=α, power=-β) → eltwise product; k is unused there)

@@ -35,7 +35,7 @@ refused, same discipline as the checkpoint/manifest planes.
 
 ``shard_plan_id()`` is a content hash over everything that changes the
 placement (axis, shard count, per-leaf dims), the same discipline as
-``fuse_plan_id``/``tune_plan_id`` — it is stamped into perf-ledger
+``fuse_plan_id`` — it is stamped into perf-ledger
 fingerprints and checkpoint manifests so captures from different
 shardings never pool.
 """

@@ -26,7 +26,7 @@ Design constraints: imports nothing from the rest of ``sparknet_tpu``
 caches values — every accessor reads ``os.environ`` live, so tests
 that monkeypatch the env keep working and the existing latch-at-trace/
 latch-at-construction semantics stay where they are implemented today
-(tuner, fusion, Net), not here.
+(fusion, Net), not here.
 """
 
 from __future__ import annotations
@@ -163,26 +163,12 @@ def get_bool(name: str, default: bool) -> bool:
 # ---------------------------------------------------------------------------
 
 _register(
-    # --- graph: lowering autotuner (WALKTHROUGH §6.15) ---
-    Knob("SPARKNET_TUNE", "enum", "auto",
-         "Lowering-table mode: off = built-in defaults, auto = committed "
-         "profiles/<backend>/tuning.json, else a table path.",
-         "sparknet_tpu/graph/tuner.py", choices=("off", "auto", "<path>")),
-    Knob("SPARKNET_TUNE_REPS", "int", "5",
-         "Timed repetitions per tuning candidate.",
-         "sparknet_tpu/graph/tuner.py"),
-    Knob("SPARKNET_TUNE_TARGET_S", "float", "0.1",
-         "Target measured seconds per candidate (reps auto-scale down).",
-         "sparknet_tpu/graph/tuner.py"),
-    Knob("SPARKNET_TUNE_WARMUP", "int", "2",
-         "Untimed warmup iterations per tuning candidate.",
-         "sparknet_tpu/graph/tuner.py"),
     # --- graph: fusion + structure toggles ---
-    Knob("SPARKNET_FUSE", "enum", "auto",
-         "Vertical fusion plan: off/0 = unfused, auto = committed profile "
-         "worklist, all = every legal chain, else a plan-file path.",
-         "sparknet_tpu/graph/fusion.py",
-         choices=("off", "0", "auto", "all", "<path>")),
+    Knob("SPARKNET_FUSE", "enum", "",
+         "Set to off for per-layer execution; unset, the graph's legal "
+         "conv..LRN chains run their LRN in the fused epilogue (latched "
+         "at Net construction).",
+         "sparknet_tpu/graph/fusion.py", choices=("off",)),
     Knob("SPARKNET_NO_HFUSE", "bool", "",
          "Set to 1 to disable horizontal inception-branch fusion "
          "(latched at Net construction).",
@@ -192,9 +178,6 @@ _register(
          "sparknet_tpu/ops/vision.py"),
     Knob("SPARKNET_PALLAS_MAXPOOL", "bool", "",
          "Set to 1 to opt in to the Pallas maxpool backward kernel on TPU.",
-         "sparknet_tpu/ops/vision.py"),
-    Knob("SPARKNET_PALLAS_LRN", "bool", "",
-         "Set to 1 to opt in to the Pallas cross-channel LRN kernel on TPU.",
          "sparknet_tpu/ops/vision.py"),
     # --- chaos / fault injection ---
     Knob("SPARKNET_FAULT", "spec", "",
@@ -547,12 +530,6 @@ _register(
     Knob("SPARKNET_OBSSMOKE", "bool", "",
          "Set to 1 to run the observability smoke gate in run_tier1.sh.",
          "tools/run_tier1.sh"),
-    Knob("SPARKNET_FUSEBENCH", "bool", "",
-         "Set to 1 to run the fusion bench gate in run_tier1.sh.",
-         "tools/run_tier1.sh"),
-    Knob("SPARKNET_TUNEBENCH", "bool", "",
-         "Set to 1 to run the autotuner loop gate in run_tier1.sh.",
-         "tools/run_tier1.sh"),
     Knob("SPARKNET_PERFGATE", "bool", "",
          "Set to 1 to run the perf regression gate in run_tier1.sh.",
          "tools/run_tier1.sh"),
@@ -572,17 +549,15 @@ _register(
          "tools/run_tier1.sh"),
     # --- tombstones: window closed, any surviving mention fails lint ---
     Knob("SPARKNET_LRN_CUMSUM", "bool", "",
-         "REMOVED: pin LRN window-sum form per key in the SPARKNET_TUNE "
-         "table instead.",
-         "sparknet_tpu/graph/tuner.py",
-         removed="r14: use a SPARKNET_TUNE table pin (winner=cumsum / "
-                 "reduce_window)"),
+         "REMOVED: ops.vision.lrn_use_cumsum picks the LRN window-sum form "
+         "from backend and width.",
+         "sparknet_tpu/ops/vision.py",
+         removed="r14: pass use_cumsum= to ops.vision.lrn_window_sum"),
     Knob("SPARKNET_FUSE_PALLAS", "bool", "",
-         "REMOVED: pin the lrn_epilogue lowering per key in the "
-         "SPARKNET_TUNE table instead.",
-         "sparknet_tpu/graph/tuner.py",
-         removed="r14: use a SPARKNET_TUNE table pin (winner=reference / "
-                 "pallas)"),
+         "REMOVED: ops.vision.lrn_chain_epilogue picks the epilogue from "
+         "backend, rank and dtype.",
+         "sparknet_tpu/ops/vision.py",
+         removed="r14: SPARKNET_FUSE=off is the per-layer side of the A/B"),
 )
 
 # Symbols (not knobs) past their deprecation window: any surviving
@@ -590,9 +565,9 @@ _register(
 # shims this release deletes — the rule that would have flagged them.
 DEPRECATED_SYMBOLS: dict[str, str] = {
     "deprecated_lrn_cumsum_pin":
-        "r14: removed with SPARKNET_LRN_CUMSUM; pin via SPARKNET_TUNE",
+        "r14: removed with SPARKNET_LRN_CUMSUM; pass use_cumsum=",
     "_shim_pin":
-        "r14: removed with the PR-12 env shims; pin via SPARKNET_TUNE",
+        "r14: removed with the PR-12 env shims",
 }
 
 

@@ -930,60 +930,6 @@ def entries_from_op_table(doc: Mapping[str, Any],
                        notes=f"mode={mode}" if mode else None)]
 
 
-def entries_from_tuning_table(doc: Mapping[str, Any],
-                              path: str | None = None, *,
-                              round_tag: str | None = None,
-                              t: float | None = None) -> list[dict]:
-    """``profiles/<backend>/tuning.json`` (graph/tuner.py): every
-    candidate timing at every key becomes a metric, so the next capture
-    of the same key gates against this one — the staleness check's
-    noise-band argument, but with the ledger's MAD bands and full
-    history behind it.  Metric names: ``tune_ms/<key>`` for the winner
-    (the ``_ms`` suffix makes lower better, like every other timing),
-    ``tune_cand_ms/<key>=<candidate>`` for the rest, and
-    ``tune_margin/<key>`` for the winner's lead over the runner-up
-    (suffix-less -> higher is better: a shrinking margin is the early
-    rot signal)."""
-    if doc.get("kind") != "tuning_table":
-        return []
-    entries = doc.get("entries") or []
-    if not entries:
-        return []
-    prov = doc.get("provenance") or {}
-    backend = doc.get("backend") or "unknown"
-    device = (prov.get("fingerprint") or {}).get("device")
-    if not device or device == "unknown":
-        device = backend
-    dtypes = {parts[2] for e in entries
-              if len(parts := str(e.get("key", "")).split("/")) >= 3}
-    fp = fingerprint(model="tuner",
-                     dtype=dtypes.pop() if len(dtypes) == 1 else "mixed",
-                     batch=0, world=1, device=device, backend=backend,
-                     tune_plan=doc.get("table_id"))
-    metrics: dict[str, Any] = {}
-    for e in entries:
-        key, winner = e.get("key"), e.get("winner")
-        if not key or not winner:
-            continue
-        for cand, rec in (e.get("timings") or {}).items():
-            if not isinstance(rec, Mapping) or rec.get("ms") is None:
-                continue  # typed skip — no measurement, never 0
-            if cand == winner:
-                metrics[f"tune_ms/{key}"] = rec["ms"]
-            else:
-                metrics[f"tune_cand_ms/{key}={cand}"] = rec["ms"]
-        if e.get("margin") is not None:
-            metrics[f"tune_margin/{key}"] = e["margin"]
-    if not metrics:
-        return []
-    ts = [e.get("measured_at") for e in entries
-          if isinstance(e.get("measured_at"), (int, float))]
-    return [make_entry("tuning", path, fp, metrics, round_tag=round_tag,
-                       t=t if t is not None else (max(ts) if ts else None),
-                       sha=prov.get("git_sha"), run=prov.get("run"),
-                       rank=prov.get("rank"), job=prov.get("job"))]
-
-
 def entries_from_metrics_rollup(folded: Mapping[str, Any],
                                 path: str | None = None, *,
                                 round_tag: str | None = None,
@@ -1040,9 +986,6 @@ def entries_from_any(doc: Mapping[str, Any], path: str | None = None, *,
     if doc.get("mode") == "rollout" and "episodes" in doc:
         return entries_from_rollout(doc, path, round_tag=round_tag, t=t,
                                     device_hint=device_hint)
-    if doc.get("kind") == "tuning_table":
-        return entries_from_tuning_table(doc, path, round_tag=round_tag,
-                                         t=t)
     if "summary" in doc and "by_category" in doc:
         return entries_from_op_table(doc, path, round_tag=round_tag, t=t)
     if doc.get("commbench"):

@@ -88,41 +88,6 @@ def build_bench_model(name: str, batch: int):
     raise ValueError(f"unknown bench model {name!r}")
 
 
-def record_fusion_plan(net, out_dir: str | None = None) -> str:
-    """The capture-stamping half of the vertical fusion pass
-    (graph/fusion.py): returns the net's plan id (the perf-ledger
-    fingerprint field — "off" when nothing fuses) and, given a profile
-    ``out_dir``, writes ``fusion_plan.json`` next to the op_table so the
-    capture is reproducible — ``SPARKNET_FUSE=<that file>`` replays
-    exactly the chains this capture ran, and refused hotspots are on
-    record rather than silently dropped.  Shared by bench.py and
-    tools/profile_step.py so the benchmarked and the profiled program
-    stamp identically."""
-    import os
-    plan = getattr(net, "_fuse_plan", None)
-    if out_dir is not None and plan is not None:
-        plan.save(os.path.join(out_dir, "fusion_plan.json"))
-    return net.fuse_plan_id()
-
-
-def record_tuning(net, out_dir: str | None = None) -> str:
-    """The capture-stamping half of the lowering autotuner
-    (graph/tuner.py), mirroring :func:`record_fusion_plan`: returns the
-    net's tune-plan id (the perf-ledger ``tune_plan`` fingerprint field
-    — "off" when no table is active) and, given a profile ``out_dir``,
-    copies the active tuning table next to the op_table so the capture
-    is reproducible — ``SPARKNET_TUNE=<that file>`` replays exactly the
-    lowerings this capture ran."""
-    import os
-    from ..graph import tuner
-    tune_id = net.tune_plan_id() if hasattr(net, "tune_plan_id") else "off"
-    if out_dir is not None and tune_id != "off":
-        table = tuner.active_table()
-        if table is not None and table.table_id() == tune_id:
-            table.save(os.path.join(out_dir, "tuning.json"))
-    return tune_id
-
-
 def step_cost_flops(solver, batch) -> float | None:
     """Model FLOPs of one compiled train step via XLA cost analysis
     (best-effort; a fori_loop block would undercount — cost the single

@@ -116,7 +116,7 @@ def test_registered_read_outside_registry_is_kr002_only(tmp_path):
     project = plant(tmp_path, {"sparknet_tpu/mod.py": """\
         import os
 
-        x = os.environ.get("SPARKNET_TUNE")
+        x = os.environ.get("SPARKNET_FUSE")
     """})
     found = rules_of(engine.run_rules(project, {"knobs"}))
     assert "KR002" in found and "KR001" not in found
@@ -126,8 +126,8 @@ def test_env_writes_and_scrub_pops_are_allowed(tmp_path):
     project = plant(tmp_path, {"sparknet_tpu/mod.py": """\
         import os
 
-        os.environ["SPARKNET_TUNE"] = "off"
-        os.environ.pop("SPARKNET_TUNE", None)
+        os.environ["SPARKNET_FUSE"] = "off"
+        os.environ.pop("SPARKNET_FUSE", None)
     """})
     assert not any(f.rule == "KR002"
                    for f in engine.run_rules(project, {"knobs"}))
@@ -261,9 +261,9 @@ def test_removed_knob_mention_is_dp002(tmp_path):
 
 def test_dead_symbol_reference_is_dp003(tmp_path):
     project = plant(tmp_path, {"sparknet_tpu/mod.py": """\
-        from sparknet_tpu.graph import tuner
+        from sparknet_tpu.ops import vision
 
-        tuner._shim_pin("lrn")
+        vision._shim_pin("lrn")
     """})
     assert "DP003" in rules_of(engine.run_rules(project, {"deprecation"}))
 

@@ -285,34 +285,149 @@ def test_lrn_gradient(np_rng):
     check_grads(f, (x,), order=1, modes=["rev"], atol=1e-2, rtol=1e-2)
 
 
-def test_lrn_cumsum_reformulation_matches_default(np_rng, monkeypatch,
-                                                  tmp_path):
-    """A tuning-table pin of the cumsum lowering (prefix-sum window
-    reformulation of the cross-channel sum) must match the
-    reduce_window path to float tolerance — the window total is the
-    same set of addends, associated differently — including the clipped
-    windows at both channel edges, and its gradient must check (cumsum
+def test_lrn_cumsum_reformulation_matches_default(np_rng):
+    """The cumsum lowering (prefix-sum window reformulation of the
+    cross-channel sum, ``use_cumsum=True``) must match the reduce_window
+    path to float tolerance — the window total is the same set of
+    addends, associated differently — including the clipped windows at
+    both channel edges, and its gradient must check (cumsum
     transpose)."""
-    from sparknet_tpu.graph import tuner
+    from sparknet_tpu.ops.vision import lrn_window_sum
     x = np_rng.normal(size=(2, 9, 5, 5)).astype(np.float32)
     lp = make("LRN", lrn_param={"local_size": 5, "alpha": 1e-2,
                                 "beta": 0.75})
     base = np.asarray(apply_op(lp, [x])[0])
-    key = tuner.key_str("lrn", x.shape, jnp.float32, tuner.lrn_extra(5))
-    path = tmp_path / "pin.json"
-    tuner.TuningTable(tuner._backend(), [
-        {"key": key, "winner": "cumsum", "timings": {}}]).save(str(path))
-    monkeypatch.setenv("SPARKNET_TUNE", str(path))
-    tuner._clear_caches()
-    fast = np.asarray(apply_op(lp, [x])[0])
-    np.testing.assert_allclose(fast, base, rtol=1e-5, atol=1e-6)
+
+    def f(x):
+        ssum = lrn_window_sum(x * x, 2, 2, use_cumsum=True)
+        return x / (1.0 + (1e-2 / 5) * ssum) ** 0.75
+
+    np.testing.assert_allclose(np.asarray(f(jnp.asarray(x))), base,
+                               rtol=1e-5, atol=1e-6)
     # bf16 input keeps its dtype out (f32 prefix accumulation inside)
-    yb = apply_op(lp, [jnp.asarray(x, jnp.bfloat16)])[0]
-    assert yb.dtype == jnp.bfloat16
-    impl = get_layer_impl("LRN")
-    f = lambda x: impl.apply(lp, [], [x], True, None)[0]
+    assert f(jnp.asarray(x, jnp.bfloat16)).dtype == jnp.bfloat16
     check_grads(f, (jnp.asarray(x),), order=1, modes=["rev"],
                 atol=1e-2, rtol=1e-2)
+
+
+# -- one lowering a family, and the ones it was chosen over -------------------
+
+ZOO_LRN = {"googlenet_norm1": (2, 64, 56, 56),
+           "googlenet_norm2": (2, 192, 56, 56),
+           "caffenet_norm1": (2, 96, 55, 55),
+           "caffenet_norm2": (2, 256, 27, 27)}
+# (c_in, side, num_output, kernel, stride, pad)
+ZOO_STEMS = {"caffenet_conv1": (3, 227, 96, 11, 4, 0),
+             "googlenet_conv1": (3, 224, 64, 7, 2, 3)}
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.max(np.abs(a - b))) / (float(np.max(np.abs(b))) or 1.0)
+
+
+def _plain_lrn(x, relu, size=5, alpha=1e-4, beta=0.75, k=1.0):
+    """Caffe's [ReLU +] ACROSS_CHANNELS LRN as written in lrn_layer.cpp,
+    differentiated by JAX through the window sum."""
+    from jax import lax
+    a = jnp.maximum(x, 0.0) if relu else x
+    pre = (size - 1) // 2
+    ssum = lax.reduce_window(a * a, 0.0, lax.add, (1, size, 1, 1),
+                             (1, 1, 1, 1),
+                             ((0, 0), (pre, size - 1 - pre), (0, 0), (0, 0)))
+    return a / (k + (alpha / size) * ssum) ** beta
+
+
+LOWERING_CASES = (
+    [("cumsum", s, d) for s in ZOO_LRN for d in ("f32", "bf16")]
+    + [("s2d", s, d) for s in ZOO_STEMS for d in ("f32", "bf16")]
+    + [("epilogue", s, r) for s in ZOO_LRN for r in ("relu", "norelu")])
+
+
+@pytest.mark.parametrize("family,shape,variant", LOWERING_CASES,
+                         ids=["-".join(c) for c in LOWERING_CASES])
+def test_lowerings_agree_at_zoo_shapes(family, shape, variant, np_rng):
+    """What every family's chosen lowering is held to against the plain
+    one, at the shapes the zoo runs: cumsum against reduce_window,
+    space-to-depth against the direct convolution (forward and both
+    gradients), ``relu_lrn_reference`` against the plain formulas
+    (forward bit for bit, its closed-form VJP within 1e-5)."""
+    from jax import lax
+    from sparknet_tpu.ops import vision
+    dtype = jnp.bfloat16 if variant == "bf16" else jnp.float32
+    tol = 2e-2 if variant == "bf16" else 1e-5
+    if family == "cumsum":
+        sq = jnp.asarray(np_rng.normal(size=ZOO_LRN[shape]) ** 2, dtype)
+        a = vision.lrn_window_sum(sq, 2, 2, use_cumsum=True)
+        b = vision.lrn_window_sum(sq, 2, 2, use_cumsum=False)
+        assert a.dtype == b.dtype == dtype
+        assert _rel(a, b) <= tol
+    elif family == "s2d":
+        c, side, o, k, s, p = ZOO_STEMS[shape]
+        assert vision._s2d_eligible(c, k, k, s, s, p, p, 1, 1, 1)
+        x = jnp.asarray(np_rng.normal(size=(2, c, side, side)), dtype)
+        w = jnp.asarray(np_rng.normal(size=(o, c, k, k)) * 0.05, dtype)
+
+        def s2d(x, w):
+            return vision._space_to_depth_conv(x, w, k, k, s, s, p, p)
+
+        def native(x, w):
+            return lax.conv_general_dilated(
+                x, w, (s, s), ((p, p), (p, p)),
+                dimension_numbers=vision.DIMNUMS)
+
+        def grads(f):
+            return jax.grad(lambda x, w: jnp.sum(jnp.sin(
+                f(x, w).astype(jnp.float32))), argnums=(0, 1))(x, w)
+
+        assert s2d(x, w).shape == native(x, w).shape
+        assert _rel(s2d(x, w), native(x, w)) <= tol
+        for got, ref in zip(grads(s2d), grads(native)):
+            assert _rel(got, ref) <= tol
+    else:
+        relu = variant == "relu"
+        x = jnp.asarray(np_rng.normal(size=ZOO_LRN[shape]) * 20.0,
+                        jnp.float32)
+        y = vision.relu_lrn_reference(x, 5, 1e-4, 0.75, 1.0, relu)
+        ref = _plain_lrn(x, relu)
+        assert np.asarray(y).tobytes() == np.asarray(ref).tobytes()
+        g = jax.grad(lambda x: jnp.mean(vision.relu_lrn_reference(
+            x, 5, 1e-4, 0.75, 1.0, relu)))(x)
+        g_ref = jax.grad(lambda x: jnp.mean(_plain_lrn(x, relu)))(x)
+        assert _rel(g, g_ref) <= 1e-5
+
+
+@pytest.mark.parametrize("case,want", [
+    # (c_in, kh, kw, sh, sw, ph, pw, dh, dw, group)
+    ("s2d-caffenet_conv1_11x11s4", True),
+    ("s2d-googlenet_conv1_7x7s2", True),
+    ("s2d-vgg_3x3s1", False),
+    ("s2d-caffenet_conv2_grouped_5x5", False),
+    ("s2d-dilated_3x3s2", False),
+    ("cumsum-by_backend_and_width", None),
+])
+def test_default_lowering(case, want, monkeypatch):
+    """The one place each family picks its lowering, from shape, dtype
+    and backend: ``_s2d_eligible`` over the zoo's convolution geometries,
+    ``lrn_use_cumsum`` by backend and channel count."""
+    from sparknet_tpu.ops import vision
+    geometry = {
+        "s2d-caffenet_conv1_11x11s4": (3, 11, 11, 4, 4, 0, 0, 1, 1, 1),
+        "s2d-googlenet_conv1_7x7s2": (3, 7, 7, 2, 2, 3, 3, 1, 1, 1),
+        "s2d-vgg_3x3s1": (3, 3, 3, 1, 1, 1, 1, 1, 1, 1),
+        "s2d-caffenet_conv2_grouped_5x5": (96, 5, 5, 1, 1, 2, 2, 1, 1, 2),
+        "s2d-dilated_3x3s2": (3, 3, 3, 2, 2, 1, 1, 2, 2, 1),
+    }
+    if case in geometry:
+        assert vision._s2d_eligible(*geometry[case]) is want
+        monkeypatch.setenv("SPARKNET_NO_S2D", "1")
+        assert vision._s2d_eligible(*geometry[case]) is False
+        return
+    c = vision.LRN_CUMSUM_AUTO_C
+    assert not any(vision.lrn_use_cumsum(w) for w in (c - 1, c, 4096))
+    monkeypatch.setattr(vision.jax, "default_backend", lambda: "tpu")
+    assert [vision.lrn_use_cumsum(w) for w in (96, c - 1, c, 256)] == [
+        False, False, True, True]
 
 
 # -- inner product ----------------------------------------------------------

@@ -12,9 +12,13 @@ from jax import lax
 
 from sparknet_tpu.models.dsl import layer
 from sparknet_tpu.ops import get_layer_impl
-from sparknet_tpu.ops.pallas_kernels import lrn_across_channels
+from sparknet_tpu.ops.pallas_kernels import relu_lrn_across_channels
 
 SIZE, ALPHA, BETA, K = 5, 1e-2, 0.75, 1.0
+
+
+def lrn_across_channels(x, size, alpha, beta, k):
+    return relu_lrn_across_channels(x, size, alpha, beta, k, False)
 
 
 @pytest.fixture(autouse=True)
@@ -59,18 +63,47 @@ def test_pallas_lrn_odd_window(np_rng):
         rtol=1e-5, atol=1e-6)
 
 
-def test_lrn_layer_pallas_dispatch(x, monkeypatch):
-    """SPARKNET_PALLAS_LRN=1 routes LRNLayer through the kernel (interpret
-    mode here) and matches the default XLA path."""
-    lp = layer("n", "LRN", ["x"], ["y"],
-               lrn_param={"local_size": SIZE, "alpha": ALPHA, "beta": BETA})
-    impl = get_layer_impl("LRN")
-    monkeypatch.setenv("SPARKNET_PALLAS_LRN", "0")
-    ref = impl.apply(lp, [], [x], True, None)[0]
-    monkeypatch.setenv("SPARKNET_PALLAS_LRN", "1")
-    got = impl.apply(lp, [], [x], True, None)[0]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-6)
+def test_planned_chain_lrn_reaches_the_kernel(rng, monkeypatch):
+    """On the chip a planned chain's LRN is the kernel: with the backend
+    check steered to ``tpu`` the train net's lowered gradient holds the
+    forward and backward Pallas calls (interpret mode here, so by their
+    jaxpr names), and its loss matches per-layer execution."""
+    from sparknet_tpu.graph import Net
+    from sparknet_tpu.models.dsl import (
+        convolution_layer, inner_product_layer, lrn_layer, net_param,
+        relu_layer, softmax_with_loss_layer)
+    from sparknet_tpu.ops import vision
+    from sparknet_tpu.proto import NetState, Phase
+
+    netp = net_param("chain", [
+        layer("data", "Input", tops=["data", "label"], input_param={
+            "shape": [{"dim": [2, 3, 6, 6]}, {"dim": [2]}]}),
+        convolution_layer("conv", "data", "conv", num_output=8, kernel=3,
+                          pad=1, weight_filler={"type": "gaussian",
+                                                "std": 0.05}),
+        relu_layer("relu", "conv", "conv"),
+        lrn_layer("norm", "conv", "norm", local_size=SIZE, alpha=ALPHA,
+                  beta=BETA),
+        inner_product_layer("ip", "norm", "ip", num_output=5,
+                            weight_filler={"type": "gaussian", "std": 0.01}),
+        softmax_with_loss_layer("loss", ["ip", "label"])])
+    net = Net(netp, NetState(Phase.TRAIN))
+    assert [c.scope() for c in net._fuse_plan.chains] == ["conv+relu+norm"]
+    monkeypatch.setenv("SPARKNET_FUSE", "off")
+    per_layer = Net(netp, NetState(Phase.TRAIN))
+    params = net.init(rng)
+    ins = {"data": jnp.ones((2, 3, 6, 6)) * 0.5,
+           "label": jnp.asarray([1.0, 3.0])}
+
+    def loss(net):
+        return lambda p: net.apply(p, ins, rng=rng).loss
+
+    ref = loss(per_layer)(params)
+    monkeypatch.setattr(vision.jax, "default_backend", lambda: "tpu")
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss(net)))(params))
+    assert "relu_lrn_fwd" in jaxpr and "relu_lrn_bwd" in jaxpr
+    np.testing.assert_allclose(float(loss(net)(params)), float(ref),
+                               rtol=1e-5)
 
 
 def test_pallas_lrn_even_window_vjp(np_rng):

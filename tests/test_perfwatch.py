@@ -403,6 +403,23 @@ def test_diff_missing_category_edge():
     assert rw2["delta_ms"] == pytest.approx(15.0)
 
 
+def test_worklist_reports_fused_chains_against_ref_band():
+    doc = {"by_layer": [
+        {"op": "a+b+c", "total_ms": 20.0, "pct": 10.0, "gb_per_s": 1000.0},
+        {"op": "slow+chain", "total_ms": 10.0, "pct": 5.0,
+         "gb_per_s": 400.0},
+        {"op": "norm", "total_ms": 30.0, "pct": 20.0, "gb_per_s": 500.0,
+         "gflops_per_s": 100.0},
+    ]}
+    wl = perfwatch.fusion_worklist(doc)
+    assert [c["chain"] for c in wl["candidates"]] == ["norm"]
+    assert wl["candidates"][0]["kind"] == perfwatch.chain_kind("norm") \
+        == "conv+bias+relu+LRN"
+    fused = {c["chain"]: c for c in wl["fused_chains"]}
+    assert fused["a+b+c"]["at_ref_band"] is True
+    assert fused["slow+chain"]["at_ref_band"] is False
+
+
 def test_worklist_without_by_layer_says_so():
     doc = {"summary": {"model": "m"}, "by_category": []}
     wl = perfwatch.fusion_worklist(doc)

@@ -1276,8 +1276,8 @@ def test_async_pipelined_loop_matches_sync_bit_for_bit(tmp_path):
                     jax.tree_util.tree_leaves(b["params"])):
         np.testing.assert_array_equal(x, y)
     # the async loop recorded its (near-zero) stalls under the same keys
-    assert set(tr.stall_s) == {"loss_fetch", "finite_check",
-                               "audit_fetch", "checkpoint"}
+    assert set(tr.stall_s) == set(sync.stall_s) >= {
+        "loss_fetch", "finite_check", "audit_fetch", "checkpoint"}
 
 
 def test_async_ckpt_escape_hatch_restores_sync_path(tmp_path, monkeypatch):

@@ -393,7 +393,7 @@ def test_bench_cpu_smoke(tmp_path):
                BENCH_ATTEMPTS="1", BENCH_TIMEOUT_S="280",
                BENCH_ROUND="0",  # the round leg has its own gate (roundbench)
                BENCH_SERVING="0",  # as does serving (servesmoke)
-               BENCH_FUSE="off")  # and vertical fusion (fusebench)
+               BENCH_FUSE="off")  # and vertical fusion
     env.pop("XLA_FLAGS", None)  # conftest's 8-device flag slows the child
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run([sys.executable, os.path.join(root, "bench.py")],
@@ -440,7 +440,7 @@ def test_bench_feed_overlap_nondegenerate(tmp_path):
                BENCH_ATTEMPTS="1", BENCH_TIMEOUT_S="280",
                BENCH_ROUND="0",  # the round leg has its own gate (roundbench)
                BENCH_SERVING="0",  # as does serving (servesmoke)
-               BENCH_FUSE="off")  # and vertical fusion (fusebench)
+               BENCH_FUSE="off")  # and vertical fusion
     env.pop("XLA_FLAGS", None)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run([sys.executable, os.path.join(root, "bench.py")],
@@ -909,3 +909,25 @@ def test_plot_learning_proxy_renders_png(tmp_path):
     verdict = json.loads(proc.stdout.decode().strip().splitlines()[-1])
     assert verdict["synthesized_wall"] == ["8way"]
     assert verdict["dropped"] == ["hierarchical 2×4"]
+
+
+def test_perf_probe_time_block_typed_skip(capsys):
+    """A candidate that raises is a typed ``skipped`` record and a None
+    time, never an aborted probe run and never 0."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("perf_probe", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", "perf_probe.py"))
+    perf_probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(perf_probe)
+
+    def bad_iter(s):
+        raise ValueError("no backend for this op")
+
+    got = perf_probe.time_block("probe_bad", bad_iter, extra={"tag": 1})
+    assert got is None
+    out = [json.loads(line) for line in
+           capsys.readouterr().out.strip().splitlines() if line]
+    rec = next(r for r in out if r.get("exp") == "probe_bad")
+    assert rec["skipped"].startswith("ValueError: no backend")
+    assert rec["tag"] == 1

@@ -1,0 +1,215 @@
+"""Is it still the same program?  Lower it and hash the text.
+
+    python tools/lowered_text.py --root <checkout> --set nets|cells
+                                 [--devices real|described] [--cell NAME ...]
+                                 [--out DIR]
+
+Imports ``sparknet_tpu`` and ``benchmark`` from ``--root`` (another checkout
+of this repository, e.g. ``git archive <parent>`` unpacked beside this one),
+lowers the programs of the set with shapes in place of arrays, and prints one
+JSON line ``{"root": ..., "backend": ..., "fuse_plan": {program: id},
+"hashes": {program: sha1[:12]}}``, each hash
+of the StableHLO text without source locations (``Lowered.as_text()``: a
+scope's name and a line number live only in the locations; a Pallas kernel's
+body is Mosaic bytecode that carries the call stack's paths and lines, so it
+is parsed and printed again without them).  Two checkouts
+run the same program where the lines agree; ``--out`` keeps the texts, for a
+``diff`` where they do not.  One process a root: the modules are the root's.
+
+- ``nets``: ``jax.grad`` of the train net's loss for CaffeNet (float32 and
+  bfloat16), GoogLeNet and AlexNet (float32) at batch 2, on whatever backend
+  JAX has (here the CPU).
+- ``cells``: what the benchmark's cells run, built as their drivers build it
+  (``benchmark/rehearse.py`` is the model): ``Solver._step`` for
+  ``caffenet_train_resident`` (bfloat16, 1,024) and
+  ``googlenet_train_resident`` (bfloat16, 256), the trainer's round for
+  ``caffenet_rounds_x4`` (float32, 512 a chip, tau 10).  ``--devices real``
+  lowers for the chips JAX holds and leaves out a cell that needs more;
+  ``described`` lowers for a v5e:2x2 that is described and not attached, with
+  trace-time backend checks steered to the chip's branch, and needs no chip.
+
+Nothing is compiled and nothing runs: equal text is the whole criterion.
+"""
+
+from __future__ import annotations
+
+import argparse
+import base64
+import hashlib
+import json
+import os
+import re
+import sys
+
+
+def without_kernel_locations(text: str) -> str:
+    """``text`` with every ``tpu_custom_call`` body (base64 of MLIR bytecode)
+    replaced by the kernel's assembly without debug information."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True       # stable_mosaic.*
+
+    def asm(m: re.Match) -> str:
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(2)))
+            return m.group(1) + json.dumps(module.operation.get_asm(
+                enable_debug_info=False))[1:-1]
+
+    return re.sub(r'(body\\22: \\22)([A-Za-z0-9+/=]+)', asm, text)
+
+
+def net_texts() -> dict[str, tuple[str, str]]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparknet_tpu import models
+    from sparknet_tpu.graph import Net
+    from sparknet_tpu.proto import NetState, Phase
+
+    texts = {}
+    for model, dtype in (("caffenet", None), ("caffenet", jnp.bfloat16),
+                         ("googlenet", None), ("alexnet", None)):
+        net = Net(getattr(models, model)(2, 2), NetState(Phase.TRAIN),
+                  compute_dtype=dtype)
+        key = jax.random.PRNGKey(0)
+        params = jax.eval_shape(net.init, key)
+        ins = {b: jax.ShapeDtypeStruct(s, np.float32)
+               for b, s in net.input_blobs.items()}
+
+        def loss(p, ins, rng, net=net):
+            return net.apply(p, ins, rng=rng).loss
+
+        name = f"{model}_{'f32' if dtype is None else 'bf16'}_grad"
+        texts[name] = (jax.jit(jax.grad(loss)).lower(params, ins,
+                                                     key).as_text(),
+                       net.fuse_plan_id())
+    return texts
+
+
+CELLS = ("caffenet_train_resident", "googlenet_train_resident",
+         "caffenet_rounds_x4")
+
+
+def cell_texts(described: bool, names) -> dict[str, tuple[str, str]]:
+    import jax
+    import numpy as np
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    from benchmark.lib import harness
+
+    if described:
+        from jax.experimental import topologies
+        jax.default_backend = lambda: "tpu"
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    else:
+        devices = jax.devices()
+
+    def struct(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                           sharding=sharding), tree)
+
+    spec = harness.load_json(os.path.join(harness.REPO, "BENCHMARK.json"))
+    texts = {}
+    for name in names:
+        cell = harness.resolve_cell(spec, name, seed=0)
+        if cell.chips > len(devices):
+            print(f"{name}: needs {cell.chips} devices, {len(devices)} "
+                  f"here: left out", file=sys.stderr)
+            continue
+        driver = harness.load_driver(cell.mix).Driver(cell)
+        raw = driver.raw_shape()
+        if cell.mix["driver"] == "solver_steps":
+            one = SingleDeviceSharding(devices[0])
+            solver = driver.make_solver()
+            b = driver.batch
+            batch = {"data": jax.ShapeDtypeStruct((1, b, *raw), np.uint8,
+                                                  sharding=one),
+                     "label": jax.ShapeDtypeStruct((1, b), np.float32,
+                                                   sharding=one)}
+            lowered = solver._step.lower(
+                struct(solver.params, one), struct(solver.state, one), 0,
+                batch, struct(jax.random.PRNGKey(0), one))
+            texts[f"{name}:Solver._step"] = (
+                lowered.as_text(), solver.train_net.fuse_plan_id())
+            continue
+        n = cell.chips
+        trainer, _ = driver.make_trainer(n)
+        mesh = Mesh(np.asarray(devices[:n]).reshape(n, 1),
+                    trainer.mesh.axis_names)
+        rep = NamedSharding(mesh, P())
+        stacked = NamedSharding(mesh, trainer._state_tier()[1])
+        feed = NamedSharding(mesh, trainer.input_sharding.spec)
+        params, state = trainer.params, trainer.state
+        # closed over by the round: as host arrays they are constants of
+        # the program, whatever devices the trainer was built on
+        trainer._lr_mults = jax.tree_util.tree_map(np.asarray,
+                                                   trainer._lr_mults)
+        trainer._decay_mults = jax.tree_util.tree_map(np.asarray,
+                                                      trainer._decay_mults)
+        trainer.mesh = mesh
+        gb, rows = driver.batch * n, driver.tau * trainer.sp.iter_size
+        batches = {"data": jax.ShapeDtypeStruct((rows, gb, *raw), np.uint8,
+                                                sharding=feed),
+                   "label": jax.ShapeDtypeStruct((rows, gb), np.float32,
+                                                 sharding=feed)}
+        lowered = trainer._build_round().lower(
+            struct(params, rep), struct(state, stacked),
+            jax.ShapeDtypeStruct((), np.int32, sharding=rep), batches,
+            struct(jax.random.PRNGKey(0), rep),
+            jax.ShapeDtypeStruct((), np.float32, sharding=rep))
+        texts[f"{name}:round"] = (lowered.as_text(),
+                                  trainer.train_net.fuse_plan_id())
+    return texts
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    ap.add_argument("--set", choices=("nets", "cells"), required=True)
+    ap.add_argument("--devices", choices=("real", "described"),
+                    default="real")
+    ap.add_argument("--cell", action="append", choices=CELLS,
+                    help="of --set cells, only these (default: all)")
+    ap.add_argument("--out")
+    args = ap.parse_args()
+
+    root = os.path.abspath(args.root)
+    sys.path.insert(0, root)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    # nothing is compiled, and a described chip's entry could not be read
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    texts = (net_texts() if args.set == "nets"
+             else cell_texts(args.devices == "described",
+                             args.cell or CELLS))
+    texts = {name: (without_kernel_locations(text), plan)
+             for name, (text, plan) in texts.items()}
+    import sparknet_tpu
+    if not os.path.abspath(sparknet_tpu.__file__).startswith(root + os.sep):
+        raise SystemExit(f"sparknet_tpu came from {sparknet_tpu.__file__}, "
+                         f"not from --root {root}")
+    if args.out:
+        os.makedirs(args.out, exist_ok=True)
+        for name, (text, _) in texts.items():
+            with open(os.path.join(
+                    args.out, name.split(":")[0] + ".mlir"), "w") as f:
+                f.write(text)
+    print(json.dumps({
+        "root": args.root, "backend": jax.default_backend(),
+        "devices": args.devices if args.set == "cells" else "real",
+        "fuse_plan": {name: plan for name, (_, plan) in texts.items()},
+        "hashes": {name: hashlib.sha1(text.encode()).hexdigest()[:12]
+                   for name, (text, _) in texts.items()}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -16,9 +16,8 @@ Parts (select with argv, default all):
   hlo    — transpose/copy census of the optimized HLO for the compiled
            train step (layout-assignment cost evidence).
   lrn    — the cross-channel LRN window sum as reduce_window (default)
-           vs the prefix-sum-difference reformulation, pinned per
-           variant via one-entry SPARKNET_TUNE tables
-           (VERDICT r5 weak #2), fwd and fwd+bwd, at both
+           vs the prefix-sum-difference reformulation, use_cumsum=
+           passed per variant (VERDICT r5 weak #2), fwd and fwd+bwd, at both
            LRN-bearing headline models' shapes.  PROBE_LRN_DTYPE=f32
            switches from the bf16 default.
 
@@ -68,8 +67,7 @@ def time_block(name: str, make_iter, iters: int = 0,
     A candidate that RAISES (Pallas kernel on CPU, an op a backend can't
     lower, OOM on a small rig) records a typed ``skipped`` entry and
     returns None instead of aborting the whole probe run — callers must
-    treat a None per-iter time as "no measurement", never 0.  The
-    autotuner (sparknet_tpu/graph/tuner.py) inherits this contract."""
+    treat a None per-iter time as "no measurement", never 0."""
     try:
         return _time_block_measured(name, make_iter, extra)
     except Exception as e:  # noqa: BLE001 — typed skip, not abort
@@ -490,25 +488,19 @@ def run_poolbwd() -> None:
 def run_lrn() -> None:
     """reduce_window vs prefix-sum-difference cross-channel LRN,
     forward and forward+backward, at the LRN shapes of both LRN-bearing
-    headline models.  Each pinned variant runs under a one-entry
-    SPARKNET_TUNE table (the sanctioned pin path since the env shim was
-    retired); tables are read at trace time, so each variant compiles
-    its own block.  The layer code under test is the production
-    ``ops.vision.LRNLayer``."""
-    import tempfile
-
+    headline models.  Each variant is ``ops.vision.LRNLayer``'s
+    ACROSS_CHANNELS formula over ``lrn_window_sum`` with ``use_cumsum=``
+    passed (``auto``: left to ``lrn_use_cumsum``), so each compiles its
+    own block."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from sparknet_tpu.graph import tuner
-    from sparknet_tpu.models.dsl import layer
-    from sparknet_tpu.utils import knobs
-    from sparknet_tpu.ops.registry import get_layer_impl
+    from sparknet_tpu.ops.vision import lrn_window_sum
 
-    impl = get_layer_impl("LRN")
-    lp = layer("probe_lrn", "LRN", ["x"], ["y"],
-               lrn_param={"local_size": 5, "alpha": 1e-4, "beta": 0.75})
+    size, alpha, beta, k = 5, 1e-4, 0.75, 1.0
+    pre = (size - 1) // 2
+    post = size - 1 - pre
     dtype = (jnp.float32 if os.environ.get("PROBE_LRN_DTYPE") == "f32"
              else jnp.bfloat16)
     rng = np.random.default_rng(0)
@@ -523,64 +515,39 @@ def run_lrn() -> None:
     if only:  # comma-separated substring filter (CPU smokes)
         shapes = {k: v for k, v in shapes.items()
                   if any(s and s in k for s in only.split(","))}
-    saved = knobs.raw("SPARKNET_TUNE")
-    tmpdir = tempfile.mkdtemp(prefix="probe_lrn_tables_")
     results: dict[str, dict[str, float]] = {}
-    try:
-        for name, shape in shapes.items():
-            x = jnp.asarray(rng.normal(size=shape), dtype)
-            nbytes = x.size * x.dtype.itemsize
+    for name, shape in shapes.items():
+        x = jnp.asarray(rng.normal(size=shape), dtype)
+        nbytes = x.size * x.dtype.itemsize
+        # the shipping default is measured as its own variant so the
+        # flip is auditable
+        for variant, use_cumsum in (("reduce_window", False),
+                                    ("cumsum", True), ("auto", None)):
+            def loss(xx, use_cumsum=use_cumsum):
+                scale = k + (alpha / size) * lrn_window_sum(
+                    xx * xx, pre, post, use_cumsum=use_cumsum)
+                return jnp.mean(xx / scale ** beta).astype(jnp.float32)
 
-            def loss(xx):
-                y = impl.apply(lp, [], [xx], True, None)[0]
-                return jnp.mean(y).astype(jnp.float32)
+            def fwd(s, x=x, loss=loss):
+                return loss(x + s.astype(dtype))
 
-            # a one-entry table pins each form; the shipping auto
-            # default (committed table, else lrn_use_cumsum by channel
-            # count) is measured as its own variant so the flip is
-            # auditable
-            for variant in ("reduce_window", "cumsum", "auto"):
-                if variant == "auto":
-                    if saved is None:
-                        os.environ.pop("SPARKNET_TUNE", None)
-                    else:
-                        os.environ["SPARKNET_TUNE"] = saved
-                else:
-                    key = tuner.key_str("lrn", shape, jnp.dtype(dtype),
-                                        tuner.lrn_extra(5))
-                    path = os.path.join(tmpdir, f"{name}_{variant}.json")
-                    tuner.TuningTable(tuner._backend(), [
-                        {"key": key, "winner": variant,
-                         "timings": {}}]).save(path)
-                    os.environ["SPARKNET_TUNE"] = path
-                tuner._clear_caches()
+            def fwdbwd(s, x=x, loss=loss):
+                g = jax.grad(loss)(x + s.astype(dtype))
+                return jnp.mean(g).astype(jnp.float32)
 
-                def fwd(s, x=x, loss=loss):
-                    return loss(x + s.astype(dtype))
-
-                def fwdbwd(s, x=x, loss=loss):
-                    g = jax.grad(loss)(x + s.astype(dtype))
-                    return jnp.mean(g).astype(jnp.float32)
-
-                extra = {"shape": list(shape), "dtype": str(jnp.dtype(dtype))}
-                f_ms = time_block(f"lrn_{name}_{variant}_fwd", fwd,
-                                  extra=extra)
-                fb_ms = time_block(f"lrn_{name}_{variant}_fwdbwd", fwdbwd,
-                                   extra=extra)
-                # None = typed skip (time_block contract) — leave the
-                # variant out of the verdict rather than divide by it
-                if fb_ms is not None:
-                    results.setdefault(name, {})[variant] = fb_ms
-                if f_ms is not None:
-                    # effective traffic at the fwd floor: read x, write y
-                    results.setdefault(name, {})[f"{variant}_fwd_gbps"] = \
-                        round(2 * nbytes / max(f_ms, 1e-6) / 1e6, 1)
-    finally:
-        if saved is None:
-            os.environ.pop("SPARKNET_TUNE", None)
-        else:
-            os.environ["SPARKNET_TUNE"] = saved
-        tuner._clear_caches()
+            extra = {"shape": list(shape), "dtype": str(jnp.dtype(dtype))}
+            f_ms = time_block(f"lrn_{name}_{variant}_fwd", fwd,
+                              extra=extra)
+            fb_ms = time_block(f"lrn_{name}_{variant}_fwdbwd", fwdbwd,
+                               extra=extra)
+            # None = typed skip (time_block contract) — leave the
+            # variant out of the verdict rather than divide by it
+            if fb_ms is not None:
+                results.setdefault(name, {})[variant] = fb_ms
+            if f_ms is not None:
+                # effective traffic at the fwd floor: read x, write y
+                results.setdefault(name, {})[f"{variant}_fwd_gbps"] = \
+                    round(2 * nbytes / max(f_ms, 1e-6) / 1e6, 1)
     verdict = {
         name: {"speedup_fwdbwd": (
                    round(r["reduce_window"] / max(r["cumsum"], 1e-9), 3)

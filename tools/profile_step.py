@@ -62,8 +62,6 @@ def main() -> None:
         build_bench_model,
         eval_cost_flops,
         peak_flops,
-        record_fusion_plan,
-        record_tuning,
         scanned_eval_block,
         scanned_train_block,
         step_cost_flops,
@@ -146,16 +144,13 @@ def main() -> None:
 
     tables = xplane.op_tables(out_dir, top=args.top, layer_map=layer_map)
     print(xplane.format_tables(tables))
-    # the profiled net's vertical-fusion plan: stamped into the summary
-    # (the perf-ledger fingerprint field) and recorded next to the
-    # op_table as fusion_plan.json so a capture is reproducible —
-    # SPARKNET_FUSE=profiles/<model>/fusion_plan.json replays it exactly
+    # the profiled net's vertical-fusion plan id, stamped into the
+    # summary (the perf-ledger fingerprint field)
     prof_net = solver.test_net if args.eval else solver.train_net
     summary = {
         "model": args.model, "batch": args.batch, "dtype": args.dtype,
         "mode": "eval_forward" if args.eval else "train_step",
-        "fuse_plan": record_fusion_plan(prof_net, out_dir),
-        "tune_plan": record_tuning(prof_net, out_dir),
+        "fuse_plan": prof_net.fuse_plan_id(),
         "device": f"{dev.platform}/{dev.device_kind}",
         "step_ms": round(step_s * 1e3, 2),
         "img_s": round(args.batch / step_s, 1),

@@ -242,37 +242,6 @@ maybe_shardsmoke() {
   fi
 }
 
-# ~7-second vertical-fusion parity gate (tools/fusebench.py) — opt-in
-# via SPARKNET_FUSEBENCH=1.  Fails the gate unless fused execution
-# (SPARKNET_FUSE=all) reproduces per-layer execution bit-for-bit in the
-# forward (f32 + bf16), matches gradients inside the documented ulp
-# bound on every chain shape (conv+bias+relu, +pool, +LRN), refuses a
-# planted unfusable (fan-out) hotspot with a recorded reason, and does
-# not slow the LRN-chain train step down.  (A fast in-tree smoke of the
-# same contracts always runs inside tier-1: tests/test_fusion.py.)
-maybe_fusebench() {
-  if [ "${SPARKNET_FUSEBENCH:-}" = "1" ]; then
-    timeout -k 10 120 env JAX_PLATFORMS=cpu \
-      python tools/fusebench.py --out /tmp/_fusebench.json
-  fi
-}
-
-# ~10-second lowering-autotuner self-test (tools/tune.py tunebench) —
-# opt-in via SPARKNET_TUNEBENCH=1.  Tunes a 2-op synthetic net on CPU
-# and fails unless the measured winner beats a planted 3x-work slow
-# candidate, a planted numerics-bad candidate is disqualified before it
-# can win, SPARKNET_TUNE=off vs the fresh table is forward-bit-identical
-# (grads <= 1e-5) through the production layers, the fresh table passes
-# the staleness gate, and a planted rotten winner fails it.  (The same
-# contracts run in-process in tests/test_tuner.py; the committed-table
-# parity tests there cover the real profiles/cpu/tuning.json.)
-maybe_tunebench() {
-  if [ "${SPARKNET_TUNEBENCH:-}" = "1" ]; then
-    timeout -k 10 180 env JAX_PLATFORMS=cpu \
-      python tools/tune.py tunebench --json /tmp/_tunebench.json
-  fi
-}
-
 # ~10-second performance gate (tools/perfwatch.py perfgate) — opt-in
 # via SPARKNET_PERFGATE=1.  Runs a ~2s-leg CPU bench smoke through the
 # regression sentinel against the committed perf/LEDGER.jsonl (CPU
@@ -304,23 +273,19 @@ case "${1:-}" in
   --fleetservesmoke) SPARKNET_FLEETSERVESMOKE=1 maybe_fleetservesmoke ;;
   --obssmoke) SPARKNET_OBSSMOKE=1 maybe_obssmoke ;;
   --perfgate) SPARKNET_PERFGATE=1 maybe_perfgate ;;
-  --fusebench) SPARKNET_FUSEBENCH=1 maybe_fusebench ;;
-  --tunebench) SPARKNET_TUNEBENCH=1 maybe_tunebench ;;
   --all)   maybe_lint && run_tier1 && run_chaos && maybe_soak \
              && maybe_fleetsoak && maybe_podsoak && maybe_netsoak \
              && maybe_rollsmoke \
              && maybe_feedbench && maybe_recordbench && maybe_servesmoke \
              && maybe_fleetservesmoke && maybe_roundbench \
              && maybe_commbench && maybe_shardsmoke \
-             && maybe_obssmoke && maybe_fusebench && maybe_tunebench \
-             && maybe_perfgate ;;
+             && maybe_obssmoke && maybe_perfgate ;;
   "")      maybe_lint && run_tier1 && maybe_soak && maybe_fleetsoak \
              && maybe_podsoak && maybe_netsoak && maybe_rollsmoke \
              && maybe_feedbench && maybe_recordbench \
              && maybe_servesmoke && maybe_fleetservesmoke \
              && maybe_roundbench && maybe_commbench && maybe_shardsmoke \
-             && maybe_obssmoke \
-             && maybe_fusebench && maybe_tunebench && maybe_perfgate ;;
-  *) echo "usage: $0 [--chaos|--lint|--soak|--fleetsoak|--podsoak|--netsoak|--rollsmoke|--feedbench|--recordbench|--roundbench|--commbench|--shardsmoke|--servesmoke|--fleetservesmoke|--obssmoke|--fusebench|--tunebench|--perfgate|--all]" >&2
+             && maybe_obssmoke && maybe_perfgate ;;
+  *) echo "usage: $0 [--chaos|--lint|--soak|--fleetsoak|--podsoak|--netsoak|--rollsmoke|--feedbench|--recordbench|--roundbench|--commbench|--shardsmoke|--servesmoke|--fleetservesmoke|--obssmoke|--perfgate|--all]" >&2
      exit 2 ;;
 esac
