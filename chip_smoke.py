@@ -167,6 +167,96 @@ def leg_kernels(shapes=((4, 96, 27, 27), (4, 256, 13, 13))) -> None:
         f"{list(shapes)}, f32 and bf16, forward and backward")
 
 
+# the benchmark's cells: CaffeNet bf16 1,024 and GoogLeNet bf16 256
+# (resident), CaffeNet float32 512 a chip (rounds); (shape, dtype, relu
+# folded: GoogLeNet pools before its LRN)
+CELL_LRN_SHAPES = (
+    ((1024, 96, 27, 27), "bfloat16", True),
+    ((1024, 256, 13, 13), "bfloat16", True),
+    ((256, 64, 56, 56), "bfloat16", False),
+    ((256, 192, 56, 56), "bfloat16", False),
+    ((512, 96, 27, 27), "float32", True),
+    ((512, 256, 13, 13), "float32", True),
+)
+
+
+def _lrn_one_image_a_block(x, dy, size, alpha, beta, k, relu):
+    """y, scale and dx of the epilogue's own arithmetic
+    (``pallas_kernels._fwd_math``/``_bwd_math``) in the blocking the
+    kernel had until PR 33: ``[N, C, H*W]``, the positions on the lanes,
+    one image and 512 lanes a block, a block at once."""
+    import functools
+
+    import jax
+    from jax.experimental import pallas as pl
+
+    from sparknet_tpu.ops import pallas_kernels as pk
+
+    n, c, h, w = x.shape
+    spec = pl.BlockSpec((None, c, 512), lambda i, j: (i, 0, j))
+    like = jax.ShapeDtypeStruct((n, c, h * w), x.dtype)
+    call = functools.partial(pl.pallas_call, grid=(n, pl.cdiv(h * w, 512)))
+
+    def fwd(x_ref, y_ref, scale_ref):
+        y, scale = pk._fwd_math(x_ref[:], size=size, alpha=alpha,
+                                beta=beta, k=k, relu=relu)
+        y_ref[:] = y.astype(y_ref.dtype)
+        scale_ref[:] = scale.astype(scale_ref.dtype)
+
+    def bwd(x_ref, scale_ref, dy_ref, dx_ref):
+        dx_ref[:] = pk._bwd_math(
+            x_ref[:], scale_ref[:], dy_ref[:], size=size, alpha=alpha,
+            beta=beta, relu=relu).astype(dx_ref.dtype)
+
+    xs = x.reshape(like.shape)
+    y, scale = call(fwd, out_shape=(like, like), in_specs=[spec],
+                    out_specs=(spec, spec))(xs)
+    dx = call(bwd, out_shape=like, in_specs=[spec] * 3, out_specs=spec)(
+        xs, scale, dy.reshape(like.shape))
+    return tuple(v.reshape(x.shape) for v in (y, scale, dx))
+
+
+def leg_kernel_layouts(cases=CELL_LRN_SHAPES) -> None:
+    """The layout is not the arithmetic: at the cells' own LRN shapes the
+    kernel's y, scale and dx, with the batch on the lanes and a block of
+    many positions walked a row and a lane tile at a time, equal bit for
+    bit what the same arithmetic gives with the positions on the lanes,
+    one image a block (the kernel until PR 33).  No timed window."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparknet_tpu.ops import pallas_kernels as pk
+
+    geom = (5, 1e-4, 0.75, 1.0)
+    rng = np.random.default_rng(1)
+    for shape, dtype, relu in cases:
+        check(pk.lrn_lanes(shape) == "batch_lanes",
+              f"{shape} should put the batch on the lanes, "
+              f"got {pk.lrn_lanes(shape)}")
+        x = jnp.asarray(20 * rng.standard_normal(shape, np.float32), dtype)
+        dy = jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
+
+        @jax.jit
+        def now(x, dy):
+            y, (_, scale) = pk._lrn_vjp_fwd(x, *geom, relu)
+            return y, scale, pk._lrn_vjp_bwd(*geom, relu, (x, scale),
+                                             dy)[0]
+
+        before = jax.jit(lambda x, dy: _lrn_one_image_a_block(
+            x, dy, *geom, relu))
+        for what, a, b in zip(("y", "scale", "dx"), now(x, dy),
+                              before(x, dy)):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            check(np.array_equal(a, b),
+                  f"LRN epilogue {what} at {shape} {dtype} relu={relu}: "
+                  f"{int((a != b).sum())} of {a.size} elements differ "
+                  f"between the two blockings, by at most "
+                  f"{float(np.max(np.abs(a - b))):.3e}")
+    say(f"kernels: y, scale and dx array_equal between batch-on-lanes "
+        f"blocks and one image a block at {[c[0] for c in cases]}")
+
+
 # ---------------------------------------------------------------------------
 # rounds
 # ---------------------------------------------------------------------------
@@ -455,6 +545,7 @@ def main() -> int:
                   "libjpeg are on this machine); see stderr")
         with clock.leg("kernels"):
             leg_kernels()
+            leg_kernel_layouts()
         with clock.leg("rounds"):
             leg_rounds(n_dev)
         with clock.leg("steps"):

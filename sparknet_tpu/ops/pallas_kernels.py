@@ -15,9 +15,25 @@ Math (reference: caffe/src/caffe/layers/lrn_layer.cpp):
   dx(c)    = dy(c)*scale(c)^-beta
              - (2*alpha*beta/n) * x(c) * sum_{d} dy(c+d)*y(c+d)/scale(c+d)
 
-Layout: (N, C, H, W) -> grid over (batch, spatial tiles), block (C, TS)
-so the windowed sum runs along sublanes and the spatial axis rides the
-128-wide lanes.
+Layout: the operand handed to ``pallas_call`` is three-dimensional,
+``(rows, C, lanes)``, and which axis of the activation rides the lanes is
+a function of its shape (:func:`lrn_lanes`):
+
+- ``batch_lanes`` (the batch is a multiple of 128: every training
+  batch of the zoo): ``[H*W, C, N]``.  XLA's TPU layouts keep a
+  convolution's activations with the batch on the lanes, the channels on
+  the sublanes and the positions major, so this logical transpose is a
+  bitcast of what the producing convolution wrote and of what the pool
+  or the convolution behind reads: the kernel's edges cost no copy.
+  Every lane is full whatever H*W is.
+- ``space_lanes`` (any other batch: test nets at 50, the classify
+  engine's small buckets): ``[N, C, H*W]``, the positions on the lanes
+  and several images a block, so that a small batch does not pay for
+  128 lanes of one image.
+
+Either way the windowed sum runs along the sublanes, a block holds
+about ``_BLOCK_BYTES`` of the operand, and the body walks it a row and a
+lane tile at a time so that its float32 temporaries stay in registers.
 """
 
 from __future__ import annotations
@@ -28,7 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_TS = 512  # spatial tile (lanes); f32 block C×TS stays well under VMEM
+_BLOCK_BYTES = 1 << 20   # of one operand, in the type it is stored in
+_LANE_TILE = 128         # lanes the body handles at once
 
 # Every call below is the compiled Mosaic kernel.  The CPU tests that
 # check the kernels' math patch this to True (the Pallas interpreter);
@@ -54,42 +71,28 @@ def _fwd_window(size: int) -> tuple[int, int]:
     return pre, size - 1 - pre
 
 
-def _lrn_fwd_kernel(x_ref, y_ref, scale_ref, *, size, alpha, beta, k,
-                    relu=False):
+def _fwd_math(x, *, size, alpha, beta, k, relu):
     # Math in f32 regardless of I/O dtype; bf16 blocks cast at the VMEM
     # boundary so mixed-precision nets keep f32 window sums.  With
     # ``relu`` the block consumes the producer conv's biased output
     # directly and applies the chain's ReLU in-register — the vertical
     # fusion pass's LRN epilogue (graph/fusion.py) — so the post-ReLU
     # activation never round-trips through HBM between the two layers.
-    x = x_ref[:].astype(jnp.float32)
+    x = x.astype(jnp.float32)
     a = jnp.maximum(x, 0.0) if relu else x
     pre, post = _fwd_window(size)
     scale = k + (alpha / size) * _window_sum(a * a, pre, post)
-    scale_ref[:] = scale.astype(scale_ref.dtype)
-    y_ref[:] = (a * scale ** -beta).astype(y_ref.dtype)
+    return a * scale ** -beta, scale
 
 
-def _lrn_infer_kernel(x_ref, y_ref, *, size, alpha, beta, k, relu=False):
-    """Forward without the scale residual — the primal/inference path
-    (a pallas output cannot be dead-code-eliminated by XLA, so writing
-    scale when nothing consumes it costs a full HBM pass)."""
-    x = x_ref[:].astype(jnp.float32)
-    a = jnp.maximum(x, 0.0) if relu else x
-    pre, post = _fwd_window(size)
-    scale = k + (alpha / size) * _window_sum(a * a, pre, post)
-    y_ref[:] = (a * scale ** -beta).astype(y_ref.dtype)
-
-
-def _lrn_bwd_kernel(x_ref, scale_ref, dy_ref, dx_ref, *, size, alpha, beta,
-                    relu=False):
+def _bwd_math(x, scale, dy, *, size, alpha, beta, relu):
     # The ReLU'd activation is recomputed from the saved pre-activation
     # (one VPU max) rather than stored — residuals stay (x, scale),
     # exactly Caffe's CrossMapBackward memory footprint even with the
     # epilogue fused on top.
-    x = x_ref[:].astype(jnp.float32)
-    scale = scale_ref[:].astype(jnp.float32)
-    dy = dy_ref[:].astype(jnp.float32)
+    x = x.astype(jnp.float32)
+    scale = scale.astype(jnp.float32)
+    dy = dy.astype(jnp.float32)
     a = jnp.maximum(x, 0.0) if relu else x
     y = a * scale ** -beta
     pre, post = _fwd_window(size)
@@ -100,31 +103,113 @@ def _lrn_bwd_kernel(x_ref, scale_ref, dy_ref, dx_ref, *, size, alpha, beta,
         # relu_layer.cpp Backward: dx = da * (x > 0); ties at exactly 0
         # route no gradient, matching the unfused ReLU->LRN pair
         da = jnp.where(x > 0, da, 0.0)
-    dx_ref[:] = da.astype(dx_ref.dtype)
+    return da
 
 
-def _specs(n, c, s):
-    grid = (n, pl.cdiv(s, _TS))
-    spec = pl.BlockSpec((None, c, _TS), lambda i, j: (i, 0, j))
-    return grid, spec
+def _over_block(ins, outs, math):
+    """``outs = math(*ins)`` over a ``(rows, C, lanes)`` block, one ``(C,
+    lane tile)`` slab at a time: each slab's float32 temporaries are a
+    few dozen registers, where the whole block's would live in VMEM."""
+    rows, _, lanes = ins[0].shape
+    tiles = [(at, min(_LANE_TILE, lanes - at))
+             for at in range(0, lanes, _LANE_TILE)]
+
+    def row(r, carry):
+        for at, width in tiles:
+            at_slab = (r, slice(None), pl.ds(at, width))
+            for ref, v in zip(outs, math(*(ref[at_slab] for ref in ins))):
+                ref[at_slab] = v.astype(ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, rows, row, 0)
 
 
-def _fwd_call(x, size, alpha, beta, k, relu):
-    n, c, h, w = x.shape
-    xs = x.reshape(n, c, h * w)
-    grid, spec = _specs(n, c, h * w)
-    y, scale = pl.pallas_call(
-        functools.partial(_lrn_fwd_kernel, size=size, alpha=alpha,
-                          beta=beta, k=k, relu=relu),
-        out_shape=(jax.ShapeDtypeStruct(xs.shape, xs.dtype),
-                   jax.ShapeDtypeStruct(xs.shape, xs.dtype)),
+def _lrn_fwd_kernel(x_ref, y_ref, scale_ref, **geometry):
+    _over_block((x_ref,), (y_ref, scale_ref),
+                functools.partial(_fwd_math, **geometry))
+
+
+def _lrn_infer_kernel(x_ref, y_ref, **geometry):
+    """Forward without the scale residual — the primal/inference path
+    (a pallas output cannot be dead-code-eliminated by XLA, so writing
+    scale when nothing consumes it costs a full HBM pass)."""
+    _over_block((x_ref,), (y_ref,),
+                lambda x: _fwd_math(x, **geometry)[:1])
+
+
+def _lrn_bwd_kernel(x_ref, scale_ref, dy_ref, dx_ref, **geometry):
+    _over_block((x_ref, scale_ref, dy_ref), (dx_ref,),
+                lambda *res_dy: (_bwd_math(*res_dy, **geometry),))
+
+
+def lrn_lanes(shape) -> str:
+    """Which axis of an ``[N, C, H, W]`` activation rides the lanes:
+    the batch where it fills them, the positions where it does not."""
+    return "batch_lanes" if shape[0] % 128 == 0 else "space_lanes"
+
+
+def _largest_tile(extent: int, fits: int) -> int:
+    """The largest multiple of 128 that divides ``extent`` and is at most
+    ``fits`` (128 if none is)."""
+    best = 128
+    for t in range(128, min(extent, max(fits, 128)) + 1, 128):
+        if extent % t == 0:
+            best = t
+    return best
+
+
+def _blocked(shape, itemsize: int):
+    """``(to_operand, from_operand, grid, spec)`` for an ``[N, C, H, W]``
+    activation: the two reshapes round the call (bitcasts of the layout
+    the neighbours hold), and the blocking of the 3-D operand."""
+    n, c, h, w = shape
+    s = h * w
+    lane_bytes = c * itemsize                # of one row of the operand
+    if lrn_lanes(shape) == "batch_lanes":
+        extent = (s, n)                      # the operand's rows, lanes
+        lanes = _largest_tile(n, _BLOCK_BYTES // lane_bytes)
+
+        def to_operand(v):
+            return v.reshape(n, c, s).transpose(2, 1, 0)
+
+        def from_operand(v):
+            return v.transpose(2, 1, 0).reshape(shape)
+    else:
+        extent = (n, s)
+        lanes = s                            # whole, or tiles of 128s
+        if lane_bytes * s > _BLOCK_BYTES:
+            lanes = max(128, _BLOCK_BYTES // lane_bytes // 128 * 128)
+
+        def to_operand(v):
+            return v.reshape(n, c, s)
+
+        def from_operand(v):
+            return v.reshape(shape)
+    held = lane_bytes * pl.cdiv(lanes, 128) * 128    # VMEM pads the lanes
+    rows = max(1, min(extent[0], _BLOCK_BYTES // held))
+    return (to_operand, from_operand,
+            (pl.cdiv(extent[0], rows), pl.cdiv(extent[1], lanes)),
+            pl.BlockSpec((rows, c, lanes), lambda i, j: (i, 0, j)))
+
+
+def _call(kernel, name, ins, n_out, **geometry):
+    """One ``pallas_call`` of ``kernel`` over ``[N, C, H, W]`` operands of
+    one shape and type, with ``n_out`` results of the same."""
+    x = ins[0]
+    to_operand, from_operand, grid, spec = _blocked(x.shape,
+                                                    x.dtype.itemsize)
+    ins = [to_operand(v) for v in ins]
+    like = jax.ShapeDtypeStruct(ins[0].shape, x.dtype)
+    outs = pl.pallas_call(
+        functools.partial(kernel, **geometry),
+        out_shape=(like,) * n_out,
         grid=grid,
-        in_specs=[spec],
-        out_specs=(spec, spec),
+        in_specs=[spec] * len(ins),
+        out_specs=(spec,) * n_out,
         interpret=_INTERPRET,
-        name="relu_lrn_fwd",
-    )(xs)
-    return y.reshape(x.shape), scale.reshape(x.shape)
+        name=name,
+    )(*ins)
+    return tuple(from_operand(v) for v in outs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
@@ -136,43 +221,21 @@ def relu_lrn_across_channels(x, size: int, alpha: float, beta: float,
     output is read from HBM ONCE, bias/ReLU/window-sum/normalize all
     happen in VMEM, and only the normalized activation is written back
     (plus ``scale`` on the VJP path, Caffe's own residual)."""
-    n, c, h, w = x.shape
-    xs = x.reshape(n, c, h * w)
-    grid, spec = _specs(n, c, h * w)
-    y = pl.pallas_call(
-        functools.partial(_lrn_infer_kernel, size=size, alpha=alpha,
-                          beta=beta, k=k, relu=relu),
-        out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
-        grid=grid,
-        in_specs=[spec],
-        out_specs=spec,
-        interpret=_INTERPRET,
-        name="relu_lrn_infer",
-    )(xs)
-    return y.reshape(x.shape)
+    (y,) = _call(_lrn_infer_kernel, "relu_lrn_infer", [x], 1, size=size,
+                 alpha=alpha, beta=beta, k=k, relu=relu)
+    return y
 
 
 def _lrn_vjp_fwd(x, size, alpha, beta, k, relu):
-    y, scale = _fwd_call(x, size, alpha, beta, k, relu)
+    y, scale = _call(_lrn_fwd_kernel, "relu_lrn_fwd", [x], 2, size=size,
+                     alpha=alpha, beta=beta, k=k, relu=relu)
     return y, (x, scale)
 
 
 def _lrn_vjp_bwd(size, alpha, beta, k, relu, res, dy):
     x, scale = res
-    n, c, h, w = x.shape
-    grid, spec = _specs(n, c, h * w)
-    dx = pl.pallas_call(
-        functools.partial(_lrn_bwd_kernel, size=size, alpha=alpha,
-                          beta=beta, relu=relu),
-        out_shape=jax.ShapeDtypeStruct((n, c, h * w), x.dtype),
-        grid=grid,
-        in_specs=[spec, spec, spec],
-        out_specs=spec,
-        interpret=_INTERPRET,
-        name="relu_lrn_bwd",
-    )(x.reshape(n, c, h * w), scale.reshape(n, c, h * w),
-      dy.reshape(n, c, h * w))
-    return (dx.reshape(x.shape),)
+    return _call(_lrn_bwd_kernel, "relu_lrn_bwd", [x, scale, dy], 1,
+                 size=size, alpha=alpha, beta=beta, relu=relu)
 
 
 relu_lrn_across_channels.defvjp(_lrn_vjp_fwd, _lrn_vjp_bwd)

@@ -22,7 +22,7 @@ import numpy as np
 from jax import lax
 
 from ..proto.caffe_pb import FillerParameter, LayerParameter
-from ..utils import knobs
+from ..utils import knobs, telemetry
 from .fillers import fill
 from .registry import LayerImpl, Shape, register_layer
 
@@ -481,13 +481,24 @@ def lrn_chain_epilogue(x, size: int, alpha: float, beta: float, k: float,
     """The fused conv-chain tail: [ReLU +] ACROSS_CHANNELS LRN in one
     pass over the producer's output.  On TPU, for 4-D float32/bfloat16,
     this is the Pallas epilogue kernel (one VMEM trip instead of the
-    reduce_window chain); elsewhere the XLA reference above (same
-    custom VJP, same residuals)."""
+    reduce_window chain), with the batch or the positions on the lanes
+    as the shape says (``pallas_kernels.lrn_lanes``); elsewhere the XLA
+    reference above (same custom VJP, same residuals).  The choice is
+    made while tracing and counted there, in
+    ``lrn_epilogue_lowering_total{path=batch_lanes|space_lanes|
+    reference}``."""
+    path = "reference"
     if (x.ndim == 4 and x.dtype in (jnp.float32, jnp.bfloat16)
             and jax.default_backend() == "tpu"):
-        from .pallas_kernels import relu_lrn_across_channels
-        return relu_lrn_across_channels(x, size, alpha, beta, k, relu)
-    return relu_lrn_reference(x, size, alpha, beta, k, relu)
+        from .pallas_kernels import lrn_lanes, relu_lrn_across_channels
+        path = lrn_lanes(x.shape)
+    telemetry.get_registry().counter(
+        "lrn_epilogue_lowering_total",
+        "traces of the conv-chain [ReLU+]LRN epilogue, by lowering").inc(
+            path=path)
+    if path == "reference":
+        return relu_lrn_reference(x, size, alpha, beta, k, relu)
+    return relu_lrn_across_channels(x, size, alpha, beta, k, relu)
 
 
 @register_layer("LRN")

@@ -1,0 +1,170 @@
+"""Compile-only checks against the TPU's own compiler, with no chip.
+
+libtpu compiles for a v5e that is described and not attached, Mosaic
+included, so what the chip's compiler would refuse, or answer with a copy,
+shows here at no chip time.  Nothing runs: no result, no time.  Every test
+that needs the description is in this file (one process loads libtpu, and
+keeps it), and the topology is described inside a fixture, after collection.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+GEOM = (5, 1e-4, 0.75, 1.0)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_branch(monkeypatch):
+    """Trace-time checks of the backend take the chip's branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.fixture(scope="module")
+def lowered_text():
+    spec = importlib.util.spec_from_file_location(
+        "lowered_text", os.path.join(ROOT, "tools", "lowered_text.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# the cells' LRN shapes (bfloat16 resident, float32 rounds) and the small
+# batches a test net and the classify engine bring
+_KERNEL_SHAPES = [
+    ((1024, 96, 27, 27), "bfloat16"), ((1024, 256, 13, 13), "bfloat16"),
+    ((256, 64, 56, 56), "bfloat16"), ((256, 192, 56, 56), "bfloat16"),
+    ((512, 96, 27, 27), "float32"), ((512, 256, 13, 13), "float32"),
+    ((50, 96, 27, 27), "float32"), ((1, 96, 27, 27), "bfloat16"),
+    ((8, 256, 13, 13), "bfloat16"), ((16, 96, 55, 55), "float32"),
+    ((10, 192, 56, 56), "bfloat16"),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,dtype", _KERNEL_SHAPES,
+    ids=["x".join(map(str, s)) + "-" + d for s, d in _KERNEL_SHAPES])
+def test_epilogue_kernels_compile_at_real_widths(one_chip, shape, dtype):
+    """Mosaic takes the forward, backward and inference kernels at the
+    shapes the cells and the small batches run, in either lane choice."""
+    from sparknet_tpu.ops.pallas_kernels import relu_lrn_across_channels
+
+    def all_three(x, dy):
+        y, vjp = jax.vjp(
+            lambda x: relu_lrn_across_channels(x, *GEOM, True), x)
+        return y, vjp(dy)[0], relu_lrn_across_channels(x, *GEOM, False)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    text = jax.jit(all_three).lower(x, x).compile().as_text()
+    for name in ("relu_lrn_fwd", "relu_lrn_bwd", "relu_lrn_infer"):
+        assert name in text
+
+
+def _two_chain_front(batch: int):
+    """CaffeNet's front cut down: conv → relu → pool → LRN twice, then an
+    inner product and the loss; channels as published, 67x67 images."""
+    from sparknet_tpu.models.dsl import (
+        convolution_layer, inner_product_layer, layer, lrn_layer, net_param,
+        pooling_layer, relu_layer, softmax_with_loss_layer)
+    gauss = {"type": "gaussian", "std": 0.01}
+    return net_param("front", [
+        layer("data", "Input", tops=["data", "label"], input_param={
+            "shape": [{"dim": [batch, 3, 67, 67]}, {"dim": [batch]}]}),
+        convolution_layer("conv1", "data", "conv1", num_output=96,
+                          kernel=11, stride=4, weight_filler=gauss),
+        relu_layer("relu1", "conv1"),
+        pooling_layer("pool1", "conv1", "pool1", kernel=3, stride=2),
+        lrn_layer("norm1", "pool1", "norm1", alpha=1e-4),
+        convolution_layer("conv2", "norm1", "conv2", num_output=256,
+                          kernel=5, pad=2, group=2, weight_filler=gauss),
+        relu_layer("relu2", "conv2"),
+        pooling_layer("pool2", "conv2", "pool2", kernel=3, stride=2),
+        lrn_layer("norm2", "pool2", "norm2", alpha=1e-4),
+        convolution_layer("conv3", "norm2", "conv3", num_output=384,
+                          kernel=3, pad=1, weight_filler=gauss),
+        relu_layer("relu3", "conv3"),
+        inner_product_layer("ip", "conv3", "ip", num_output=10,
+                            weight_filler=gauss),
+        softmax_with_loss_layer("loss", ["ip", "label"])])
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_no_copy_at_the_epilogue_kernels_edges(one_chip, chip_branch,
+                                               lowered_text, dtype):
+    """At batch 128 the compiled forward and backward of a conv..LRN chain
+    hold the kernels once a layer each and no layout copy feeding them,
+    reading them, or attributed to them: the kernel's operand is the
+    layout its neighbours keep (``tools/lowered_text.py --set edges`` is
+    the same check on the cells' whole steps)."""
+    from sparknet_tpu.graph import Net
+    from sparknet_tpu.proto import NetState, Phase
+    net = Net(_two_chain_front(128), NetState(Phase.TRAIN),
+              compute_dtype=None if dtype == "float32" else jnp.bfloat16)
+    assert [c.scope() for c in net._fuse_plan.chains] == [
+        "conv1+relu1+pool1+norm1", "conv2+relu2+pool2+norm2"]
+    key = jax.random.PRNGKey(0)
+
+    def struct(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    params = struct(jax.eval_shape(net.init, key))
+    ins = {b: jax.ShapeDtypeStruct(s, np.float32, sharding=one_chip)
+           for b, s in net.input_blobs.items()}
+    grad = jax.jit(jax.grad(lambda p, ins: net.apply(p, ins, rng=key).loss))
+    hlo = grad.lower(params, ins).compile().as_text()
+    calls, copies = lowered_text.kernel_edge_copies(hlo)
+    assert calls == {"relu_lrn_fwd": 2, "relu_lrn_bwd": 2}
+    assert copies == []
+
+
+def test_edge_copies_are_found_where_they_are(lowered_text):
+    """The reader itself, on a module's text with the three kinds of copy
+    the parent's step had: one feeding a kernel through a bitcast, one
+    reading its result, one only attributed to the ``pallas_call``."""
+    hlo = """
+HloModule m
+%fused_copy (p: bf16[8,4,9]) -> bf16[8,4,9] {
+  %p = bf16[8,4,9]{0,1,2} parameter(0)
+  ROOT %c = bf16[8,4,9]{2,1,0} copy(%p)
+}
+ENTRY %main (a: bf16[8,4,9]) -> bf16[8,4,9] {
+  %a = bf16[8,4,9]{0,1,2} parameter(0)
+  %copy.1 = bf16[8,4,9]{2,1,0} copy(%a), metadata={op_name="jit(f)/reshape"}
+  %bitcast.1 = bf16[8,4,9]{2,1,0} bitcast(%copy.1)
+  %k = bf16[8,4,9]{2,1,0} custom-call(%bitcast.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/relu_lrn_fwd/pallas_call"}
+  %fusion.7 = bf16[8,4,9]{2,1,0} fusion(%k), kind=kLoop, calls=%fused_copy
+  %copy.3 = bf16[8,4,9]{0,1,2} copy(%a), metadata={op_name="jit(f)/relu_lrn_bwd/pallas_call"}
+  ROOT %copy.9 = bf16[8,4,9]{0,1,2} copy(%a), metadata={op_name="jit(f)/conv"}
+}
+"""
+    calls, copies = lowered_text.kernel_edge_copies(hlo)
+    assert calls == {"relu_lrn_fwd": 1}
+    assert {(c["name"], c["kernel"], c["edge"], c["bytes"])
+            for c in copies} == {
+        ("copy.1", "relu_lrn_fwd", "in", 576),
+        ("fusion.7", "relu_lrn_fwd", "out", 576),
+        ("copy.3", "relu_lrn_bwd", "attributed", 576)}

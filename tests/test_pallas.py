@@ -117,6 +117,148 @@ def test_pallas_lrn_even_window_vjp(np_rng):
                                rtol=1e-4, atol=1e-5)
 
 
+# The zoo's LRN shapes cut down, over both lane choices: batches of 128
+# and 256 put the batch on the lanes, 1, 8 and 50 the positions; C as
+# CaffeNet (96, 256) and GoogLeNet (64, 192) have it; H*W 169 and 729, and
+# one a block does not hold (28x28 of many lanes; 55x55 and 56x56 of one
+# image, where the lanes are tiled or two images share a block); GoogLeNet
+# pools before its LRN, so no ReLU folds there.
+_LRN_CASES = [
+    # (N, C, H, W, dtype, relu, local_size)
+    (128, 96, 27, 27, "float32", True, 5),       # CaffeNet norm1, rounds
+    (128, 256, 13, 13, "float32", True, 5),      # CaffeNet norm2, rounds
+    (256, 96, 13, 13, "bfloat16", True, 5),
+    (256, 256, 13, 13, "bfloat16", True, 5),
+    (128, 64, 28, 28, "bfloat16", False, 5),     # GoogLeNet norm1
+    (128, 192, 13, 13, "bfloat16", False, 5),    # GoogLeNet norm2
+    (128, 64, 13, 13, "float32", False, 4),
+    (256, 192, 13, 13, "float32", True, 4),
+    (1, 96, 27, 27, "bfloat16", True, 5),        # the engine's buckets
+    (8, 256, 13, 13, "bfloat16", True, 5),
+    (50, 96, 27, 27, "float32", True, 5),        # a test net at 50
+    (50, 256, 13, 13, "float32", False, 4),
+    (1, 96, 55, 55, "float32", True, 5),         # AlexNet norm1
+    (8, 64, 56, 56, "bfloat16", False, 5),
+    (8, 192, 28, 28, "bfloat16", False, 5),
+    (1, 64, 13, 13, "float32", False, 4),
+    (50, 192, 13, 13, "bfloat16", True, 4),
+    (8, 96, 13, 13, "float32", False, 5),
+]
+
+
+@pytest.mark.parametrize(
+    "n,c,h,w,dtype,relu,size", _LRN_CASES,
+    ids=[f"{n}x{c}x{h}x{w}-{d}-{'relu' if r else 'plain'}-w{s}"
+         for n, c, h, w, d, r, s in _LRN_CASES])
+def test_epilogue_kernel_matches_reference(n, c, h, w, dtype, relu, size):
+    """Outputs and gradients of the kernel against ``relu_lrn_reference``
+    in float32 on the same stored values: float32 to rounding, bfloat16
+    to its own 8 bits (the kernel rounds y, scale and dx once each)."""
+    from sparknet_tpu.ops.pallas_kernels import lrn_lanes
+    from sparknet_tpu.ops.vision import relu_lrn_reference
+    assert lrn_lanes((n, c, h, w)) == ("batch_lanes" if n in (128, 256)
+                                       else "space_lanes")
+    geom = (size, 0.05, 0.75, 1.0)
+    r = np.random.default_rng(n * 1000 + c + h)
+    x = jnp.asarray(r.normal(scale=4.0, size=(n, c, h, w)), dtype)
+    dy = jnp.asarray(r.normal(size=(n, c, h, w)), dtype)
+
+    def out_and_grad(fn, x, dy):
+        @jax.jit
+        def both(x, dy):
+            y, vjp = jax.vjp(lambda x: fn(x, *geom, relu), x)
+            return y, vjp(dy)[0]
+        return tuple(np.asarray(v, np.float32) for v in both(x, dy))
+
+    got = out_and_grad(relu_lrn_across_channels, x, dy)
+    want = out_and_grad(relu_lrn_reference, x.astype(jnp.float32),
+                        dy.astype(jnp.float32))
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for what, k, ref in zip(("y", "dx"), got, want):
+        assert np.isfinite(k).all(), what
+        assert np.max(np.abs(k - ref)) <= tol * np.max(np.abs(ref)), what
+    # the inference kernel (no residual) is the training one's y
+    y = relu_lrn_across_channels(x, *geom, relu)
+    assert np.array_equal(np.asarray(y, np.float32), got[0])
+
+
+@pytest.mark.parametrize("shape,itemsize", [
+    ((1024, 96, 27, 27), 2), ((1024, 256, 13, 13), 2),
+    ((256, 64, 56, 56), 2), ((256, 192, 56, 56), 2),
+    ((512, 96, 27, 27), 4), ((512, 256, 13, 13), 4),
+    ((4096, 512, 7, 7), 4), ((128, 2048, 7, 7), 4),
+    ((50, 96, 27, 27), 4), ((1, 96, 27, 27), 2), ((8, 256, 13, 13), 2),
+    ((16, 96, 55, 55), 4), ((10, 192, 56, 56), 2), ((3, 1024, 112, 112), 4),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else f"{v}B")
+def test_epilogue_blocking_fits_and_covers(shape, itemsize):
+    """A block is a legal tile (lanes a multiple of 128 or the whole
+    axis), the grid covers the operand, and a block holds at most what
+    the backward kernel's four operands, double-buffered, can keep in
+    16 MB of VMEM; at the cells' shapes it is not a small one either."""
+    from sparknet_tpu.ops import pallas_kernels as pk
+    n, c, h, w = shape
+    to_operand, from_operand, grid, spec = pk._blocked(shape, itemsize)
+    operand = jax.eval_shape(to_operand, jax.ShapeDtypeStruct(
+        shape, jnp.float32)).shape
+    assert operand == ((h * w, c, n) if n % 128 == 0 else (n, c, h * w))
+    assert jax.eval_shape(from_operand, jax.ShapeDtypeStruct(
+        operand, jnp.float32)).shape == shape
+    rows, channels, lanes = spec.block_shape
+    assert channels == c and (lanes % 128 == 0 or lanes == operand[2])
+    assert grid == (-(-operand[0] // rows), -(-operand[2] // lanes))
+    held = rows * c * -(-lanes // 128) * 128 * itemsize
+    assert held <= max(pk._BLOCK_BYTES, c * 128 * itemsize)
+    assert 4 * 2 * held <= 16 << 20
+    if n >= 256 and c <= 256:              # the cells: blocks near 1 MB
+        assert held >= pk._BLOCK_BYTES // 2
+
+
+@pytest.mark.parametrize("shape,dtype,backend,path", [
+    ((128, 8, 3, 3), "float32", "tpu", "batch_lanes"),
+    ((256, 8, 3, 3), "bfloat16", "tpu", "batch_lanes"),
+    ((384, 8, 3, 3), "float32", "tpu", "batch_lanes"),
+    ((1, 8, 3, 3), "float32", "tpu", "space_lanes"),
+    ((8, 8, 3, 3), "bfloat16", "tpu", "space_lanes"),
+    ((50, 8, 3, 3), "float32", "tpu", "space_lanes"),
+    ((200, 8, 3, 3), "float32", "tpu", "space_lanes"),
+    ((128, 8, 3, 3), "float16", "tpu", "reference"),
+    ((128, 8, 3, 3), "float32", "cpu", "reference"),
+])
+def test_epilogue_path_follows_the_shape_and_is_counted(
+        monkeypatch, shape, dtype, backend, path):
+    """Which axis rides the lanes is a function of the operand's shape
+    alone, the lowered text holds the kernel's operand in that layout,
+    and ``lrn_epilogue_lowering_total`` counts the choice once a
+    trace."""
+    from sparknet_tpu.ops import vision
+    from sparknet_tpu.utils import telemetry
+    for k in ("SPARKNET_TELEMETRY", "SPARKNET_TRACE_DIR",
+              "SPARKNET_METRICS_SNAP"):
+        monkeypatch.delenv(k, raising=False)
+    monkeypatch.setattr(vision.jax, "default_backend", lambda: backend)
+    telemetry.reset()
+    try:
+        x = jnp.ones(shape, dtype)
+        step = jax.jit(lambda x: vision.lrn_chain_epilogue(
+            x, SIZE, ALPHA, BETA, K, relu=True))
+        jaxpr = str(jax.make_jaxpr(step)(x))
+        n, c, h, w = shape
+        operand = {"batch_lanes": f"[{h * w},{c},{n}]",
+                   "space_lanes": f"[{n},{c},{h * w}]"}.get(path)
+        assert ("relu_lrn_infer" in jaxpr) == (path != "reference")
+        if operand:
+            assert operand in jaxpr.replace(" ", "")
+        for _ in range(3):                  # three calls of one trace
+            step(x)
+        fam = telemetry.get_registry().snapshot()[
+            "lrn_epilogue_lowering_total"]
+        assert fam["kind"] == "counter"
+        assert {s["labels"]["path"]: s["value"]
+                for s in fam["samples"]} == {path: 1.0}
+    finally:
+        telemetry.reset()
+
+
 # ---------------------------------------------------------------------------
 # VMEM-resident maxpool backward
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Is it still the same program?  Lower it and hash the text.
 
-    python tools/lowered_text.py --root <checkout> --set nets|cells
+    python tools/lowered_text.py --root <checkout> --set nets|cells|edges
                                  [--devices real|described] [--cell NAME ...]
                                  [--out DIR]
 
@@ -28,13 +28,24 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   ``described`` lowers for a v5e:2x2 that is described and not attached, with
   trace-time backend checks steered to the chip's branch, and needs no chip.
 
-Nothing is compiled and nothing runs: equal text is the whole criterion.
+- ``edges``: the same programs as ``cells``, compiled (``--devices
+  described``: by the TPU's compiler for the described chip, about a minute a
+  cell), and read for layout copies at the edges of the LRN epilogue kernels
+  ``relu_lrn_fwd``/``relu_lrn_bwd``: a ``copy`` or ``transpose`` that feeds
+  such a custom call, reads its result, or is attributed to its
+  ``pallas_call``.  Prints ``{"edges": {program: {"kernel_calls": {...},
+  "copies": [...], "copy_bytes": n}}}`` and exits 1 if any program holds one;
+  ``--out`` keeps the compiled text.
+
+Nothing runs, and for ``nets`` and ``cells`` nothing is compiled: equal text
+is the whole criterion there.
 """
 
 from __future__ import annotations
 
 import argparse
 import base64
+import collections
 import hashlib
 import json
 import os
@@ -93,7 +104,8 @@ CELLS = ("caffenet_train_resident", "googlenet_train_resident",
          "caffenet_rounds_x4")
 
 
-def cell_texts(described: bool, names) -> dict[str, tuple[str, str]]:
+def cell_lowered(described: bool, names) -> dict[str, tuple[object, str]]:
+    """``{program: (jax.stages.Lowered, fuse plan id)}`` of the cells."""
     import jax
     import numpy as np
     from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
@@ -115,7 +127,7 @@ def cell_texts(described: bool, names) -> dict[str, tuple[str, str]]:
                                            sharding=sharding), tree)
 
     spec = harness.load_json(os.path.join(harness.REPO, "BENCHMARK.json"))
-    texts = {}
+    lowereds = {}
     for name in names:
         cell = harness.resolve_cell(spec, name, seed=0)
         if cell.chips > len(devices):
@@ -135,8 +147,8 @@ def cell_texts(described: bool, names) -> dict[str, tuple[str, str]]:
             lowered = solver._step.lower(
                 struct(solver.params, one), struct(solver.state, one), 0,
                 batch, struct(jax.random.PRNGKey(0), one))
-            texts[f"{name}:Solver._step"] = (
-                lowered.as_text(), solver.train_net.fuse_plan_id())
+            lowereds[f"{name}:Solver._step"] = (
+                lowered, solver.train_net.fuse_plan_id())
             continue
         n = cell.chips
         trainer, _ = driver.make_trainer(n)
@@ -163,16 +175,137 @@ def cell_texts(described: bool, names) -> dict[str, tuple[str, str]]:
             jax.ShapeDtypeStruct((), np.int32, sharding=rep), batches,
             struct(jax.random.PRNGKey(0), rep),
             jax.ShapeDtypeStruct((), np.float32, sharding=rep))
-        texts[f"{name}:round"] = (lowered.as_text(),
-                                  trainer.train_net.fuse_plan_id())
-    return texts
+        lowereds[f"{name}:round"] = (lowered,
+                                     trainer.train_net.fuse_plan_id())
+    return lowereds
+
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
+_MOVERS = {"copy", "transpose"}
+_SEE_THROUGH = {"bitcast", "get-tuple-element", "reshape", "tuple"}
+
+
+def _bytes(shape: str) -> int:
+    """Bytes of the first array in an HLO shape string."""
+    m = re.search(r"(pred|[su]\d+|bf16|f16|f32|f64)\[([\d,]*)\]", shape)
+    if not m:
+        return 0
+    n = 1 if m.group(1) == "pred" else int(re.sub(r"\D", "",
+                                                 m.group(1))) // 8
+    for d in filter(None, m.group(2).split(",")):
+        n *= int(d)
+    return n
+
+
+def kernel_edge_copies(hlo: str, kernels=("relu_lrn_fwd", "relu_lrn_bwd")):
+    """The layout copies at the edges of the named Pallas kernels in a
+    compiled module's text: every ``copy``/``transpose`` (alone or as the
+    whole of a fusion) that feeds such a custom call or reads its result,
+    seen through bitcasts and tuple plumbing, and every one the compiler
+    attributes to the kernel's own ``pallas_call``.  Returns
+    ``(calls, copies)``: the custom calls found by kernel name, and a list
+    of ``{"kernel", "edge", "name", "shape", "bytes"}``."""
+    instrs, users, fused = {}, collections.defaultdict(list), {}
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            fused[comp] = set()
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, opcode, rest = m.groups()
+        if comp is not None:
+            fused[comp].add(opcode)
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        calls = re.search(r"calls=%?([\w.\-]+)", rest)
+        instrs[name] = {
+            "opcode": opcode, "shape": shape, "operands": operands,
+            "op_name": op_name.group(1) if op_name else "",
+            "calls": calls.group(1) if calls else None}
+        for o in operands:
+            users[o].append(name)
+
+    def moves(name):
+        i = instrs.get(name)
+        if i is None:
+            return False
+        if i["opcode"] in _MOVERS:
+            return True
+        return (i["opcode"] == "fusion" and bool(
+            fused.get(i["calls"], set()) & _MOVERS) and fused[i["calls"]]
+            <= _MOVERS | _SEE_THROUGH | {"parameter"})
+
+    def walk(start, step):
+        seen, todo = set(), list(step(start))
+        while todo:
+            n = todo.pop()
+            if n in seen or n not in instrs:
+                continue
+            seen.add(n)
+            if moves(n):
+                yield n
+            elif instrs[n]["opcode"] in _SEE_THROUGH:
+                todo.extend(step(n))
+
+    def kernel_of(op_name):
+        for k in kernels:
+            if re.search(rf"(^|/){k}(/pallas_call)?$", op_name):
+                return k
+        return None
+
+    calls = collections.Counter()
+    found = {}
+    for name, i in instrs.items():
+        k = kernel_of(i["op_name"])
+        if k is None:
+            continue
+        if i["opcode"] == "custom-call":
+            calls[k] += 1
+            for edge, step in (("in", lambda n: instrs[n]["operands"]),
+                               ("out", lambda n: users[n])):
+                for c in walk(name, step):
+                    found.setdefault(c, (k, edge))
+        elif moves(name):
+            found.setdefault(name, (k, "attributed"))
+    return dict(calls), [
+        {"kernel": k, "edge": edge, "name": c, "shape": instrs[c]["shape"],
+         "bytes": _bytes(instrs[c]["shape"])}
+        for c, (k, edge) in sorted(found.items())]
+
+
+def edges(args) -> int:
+    """``--set edges``: compile the cells' programs and list the layout
+    copies at the edges of the LRN epilogue kernels; exit 1 if any."""
+    import jax
+    report = {}
+    for name, (lowered, _) in cell_lowered(
+            args.devices == "described", args.cell or CELLS).items():
+        hlo = lowered.compile().as_text()
+        if args.out:
+            os.makedirs(args.out, exist_ok=True)
+            with open(os.path.join(
+                    args.out, name.split(":")[0] + ".hlo"), "w") as f:
+                f.write(hlo)
+        calls, copies = kernel_edge_copies(hlo)
+        report[name] = {"kernel_calls": calls, "copies": copies,
+                        "copy_bytes": sum(c["bytes"] for c in copies)}
+    print(json.dumps({"root": args.root, "backend": jax.default_backend(),
+                      "devices": args.devices, "edges": report}),
+          flush=True)
+    return 1 if any(r["copies"] for r in report.values()) else 0
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    ap.add_argument("--set", choices=("nets", "cells"), required=True)
+    ap.add_argument("--set", choices=("nets", "cells", "edges"),
+                    required=True)
     ap.add_argument("--devices", choices=("real", "described"),
                     default="real")
     ap.add_argument("--cell", action="append", choices=CELLS,
@@ -183,19 +316,27 @@ def main() -> int:
     root = os.path.abspath(args.root)
     sys.path.insert(0, root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    if (args.devices == "described" and "xla_force_host_platform_device_count"
+            not in os.environ.get("XLA_FLAGS", "")):
+        # the rounds cell builds its trainer on four host devices first
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_"
+                                   "force_host_platform_device_count=4")
     import jax
-    # nothing is compiled, and a described chip's entry could not be read
+    # a described chip's cache entry could not be read back
     jax.config.update("jax_enable_compilation_cache", False)
 
-    texts = (net_texts() if args.set == "nets"
-             else cell_texts(args.devices == "described",
-                             args.cell or CELLS))
-    texts = {name: (without_kernel_locations(text), plan)
-             for name, (text, plan) in texts.items()}
     import sparknet_tpu
     if not os.path.abspath(sparknet_tpu.__file__).startswith(root + os.sep):
         raise SystemExit(f"sparknet_tpu came from {sparknet_tpu.__file__}, "
                          f"not from --root {root}")
+    if args.set == "edges":
+        return edges(args)
+    texts = (net_texts() if args.set == "nets" else {
+        name: (lowered.as_text(), plan) for name, (lowered, plan) in
+        cell_lowered(args.devices == "described",
+                     args.cell or CELLS).items()})
+    texts = {name: (without_kernel_locations(text), plan)
+             for name, (text, plan) in texts.items()}
     if args.out:
         os.makedirs(args.out, exist_ok=True)
         for name, (text, _) in texts.items():
