@@ -1,0 +1,21 @@
+"""mfu_lm: model FLOP/s utilization of a language-model cell: the
+operations one sequence's forward and backward passes require, from the
+layers' shapes (``lib/lm_flops.py``: two a multiply-accumulate of every
+projection at the share held, of the routed experts at the rows an even
+router sends them, and of the attention core's causal and window pairs,
+three times for the forward and the two backward products, recomputation
+not counted), times the sequences per second of the traced window, over
+the chip's bf16 peak (``lib/peaks.py``).  The cell's share of the whole
+step's peak.
+
+layer: step; unit: %; source: host_clock; moves: train_img_s.
+"""
+
+from ..lib import lm_flops, peaks
+
+
+def read(cap) -> float | None:
+    per_sequence = lm_flops.train_flops_per_sequence(
+        cap.driver.train_net_param())["total"]
+    peak = peaks.peaks(cap.device["kind"])["flops_per_s"]
+    return 100.0 * per_sequence * cap.traced.img_s / (cap.cell.chips * peak)

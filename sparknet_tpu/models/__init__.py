@@ -20,3 +20,4 @@ from .cifar10 import cifar10_quick, cifar10_full
 from .alexnet import alexnet, caffenet
 from .googlenet import googlenet
 from .vgg import vgg16
+from .laguna import laguna

@@ -1,0 +1,492 @@
+"""Sequence layers: RMSNorm, Attention, GatedMLP, MixtureOfExperts,
+LMHeadLoss.
+
+The layer types a decoder-only language model needs beside ``Embed`` and
+``Eltwise`` (ROADMAP R3): blobs are ``[sequences, positions, width]`` and
+token ids ``[sequences, positions]`` integers.  Matrices are stored
+``[in, out]``.  Shapes are inferred in plain Python like every other
+layer's; nothing here is traced for a net that has none of these types.
+
+Memory.  Each layer's ``apply`` recomputes its own forward in the backward
+pass (``jax.checkpoint``), and the attention, the dense MLP and the head
+run one sequence at a time (``jax.lax.map``), so what a step keeps between
+the passes is the blobs between layers and what it holds at once is one
+sequence's projections, not a batch's.
+
+Lowerings, one an operation, chosen here from shapes and backend and counted
+once a trace (``attn_lowering_total{path}``, ``moe_lowering_total{path}``):
+
+- the attention core (scores, mask, softmax, weighted sum; scope
+  ``attn_core``): on a TPU, where positions and head size fit its blocks,
+  JAX's block-sparse flash kernels (``splash_attention``: ``path=splash``),
+  which skip the blocks a causal or window mask empties and never hold a
+  score matrix; elsewhere masked scores in XLA (``path=xla``);
+- the experts' products (scope ``moe_experts``): rows sorted by expert and
+  multiplied group by group, on a TPU by JAX's ``megablox`` grouped matrix
+  kernels (``path=gmm``), elsewhere by ``jax.lax.ragged_dot``
+  (``path=ragged_dot``).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..proto.caffe_pb import FillerParameter
+from ..utils import telemetry
+from .fillers import fill
+from .registry import LayerImpl, register_layer
+
+_SPLASH_BLOCK = 512     # positions a block of the flash kernels holds
+_GMM_ROWS = 512         # rows a tile of the grouped product holds
+
+
+def _filler(p, key: str = "weight_filler") -> FillerParameter:
+    return FillerParameter.from_pmsg(p.get(key))
+
+
+def _per_sequence(fn, *seqs):
+    """``fn`` over the leading axis one sequence at a time, recomputed in
+    the backward pass."""
+    return jax.lax.map(lambda a: jax.checkpoint(fn)(*a), seqs)
+
+
+def _swiglu(gate, up):
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+@register_layer("RMSNorm")
+class RMSNormLayer(LayerImpl):
+    """``x * rsqrt(mean(x^2, last axis) + eps) * weight``, in float32."""
+
+    def init(self, rng, lp, bottom_shapes):
+        return [jnp.ones((bottom_shapes[0][-1],), jnp.float32)]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        eps = float(lp.sub("rms_norm_param").get("eps", 1e-6))
+
+        def norm(x, w):
+            x32 = x.astype(jnp.float32)
+            ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+            return (x32 * jax.lax.rsqrt(ms + eps)
+                    * w.astype(jnp.float32)).astype(x.dtype)
+
+        return [jax.checkpoint(norm)(bottoms[0], params[0])]
+
+
+# -- rotary position embedding ----------------------------------------------
+
+def rope_inv_freq(rotary_dim: int, theta: float, yarn_factor: float = 0.0,
+                  original_length: int = 0, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """The ``rotary_dim / 2`` inverse frequencies.  With ``yarn_factor``
+    they are YaRN's blend of interpolated (``/ factor``) and original
+    frequencies on the linear ramp between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_length``
+    positions: computed once, whatever the sequence length."""
+    pos_freqs = theta ** (np.arange(0, rotary_dim, 2, dtype=np.float64)
+                          / rotary_dim)
+    if not yarn_factor:
+        return 1.0 / pos_freqs
+
+    def correction_dim(rotations: float) -> float:
+        return (rotary_dim * math.log(original_length
+                                      / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rotary_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp          # 1 where the original frequency is kept
+    return (1.0 / (yarn_factor * pos_freqs)) * (1.0 - keep) \
+        + (1.0 / pos_freqs) * keep
+
+
+def apply_rope(x, inv_freq: np.ndarray, factor: float, scale: float = 1.0):
+    """Rotate the first ``2 * len(inv_freq)`` of the last axis of
+    ``x [positions, heads, head_dim]`` by position, halves paired as
+    ``transformers`` pairs them; ``factor`` multiplies cos and sin.  In
+    float32; ``scale`` (the score scale, on queries) rides along."""
+    half = inv_freq.shape[0]
+    pos = jnp.arange(x.shape[0], dtype=jnp.float32)
+    ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = (jnp.cos(ang) * factor)[:, None, :]
+    sin = (jnp.sin(ang) * factor)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = (x32[..., :half], x32[..., half:2 * half],
+                    x32[..., 2 * half:])
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return (out * scale).astype(x.dtype)
+
+
+# -- attention ---------------------------------------------------------------
+
+def _attn_core_xla(q, k, v, window: int):
+    """q [kv, group, S, D], k and v [kv, S, D] -> [kv, group, S, D]:
+    masked scores, softmax in float32, weighted sum."""
+    s = q.shape[2]
+    scores = jnp.einsum("kgsd,ktd->kgst", q, k,
+                        preferred_element_type=jnp.float32)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    p = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("kgst,ktd->kgsd", p.astype(v.dtype), v)
+
+
+@functools.lru_cache(maxsize=8)
+def _splash_kernel(s: int, group: int, window: int):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    one = (sm.LocalMask((s, s), (window - 1, 0), 0) if window
+           else sm.CausalMask((s, s)))
+    b = _SPLASH_BLOCK
+    sizes = sk.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    # the kernel object holds its block mask as arrays: made concrete
+    # here, or a cached one would carry the tracers of the trace that
+    # first asked for it into the next
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa_single_device(
+            sm.MultiHeadMask([one] * group), block_sizes=sizes)
+
+
+def attn_lowering(positions: int, head_dim: int) -> str:
+    """Which lowering the attention core takes at these sizes on this
+    backend; counted in ``attn_lowering_total``."""
+    splash = (jax.default_backend() == "tpu"
+              and positions % _SPLASH_BLOCK == 0 and head_dim % 128 == 0)
+    path = "splash" if splash else "xla"
+    telemetry.get_registry().counter(
+        "attn_lowering_total",
+        "traces of the attention core, by lowering").inc(path=path)
+    return path
+
+
+def attn_core(q, k, v, window: int, path: str):
+    """Causal (and, with ``window``, sliding) grouped-query attention of
+    one sequence.  q [S, heads, D] already scaled, k and v [S, kv, D];
+    returns [S, heads, D]."""
+    s, heads, d = q.shape
+    kv = k.shape[1]
+    group = heads // kv
+    q = q.reshape(s, kv, group, d).transpose(1, 2, 0, 3)
+    k, v = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
+    with jax.named_scope("attn_core"):
+        if path == "splash":
+            out = jax.vmap(_splash_kernel(s, group, window))(q, k, v)
+        else:
+            out = _attn_core_xla(q, k, v, window)
+    return out.transpose(2, 0, 1, 3).reshape(s, heads, d)
+
+
+@register_layer("Attention")
+class AttentionLayer(LayerImpl):
+    """Gated grouped-query self-attention with rotary positions
+    (``attention_param``): ``num_heads`` query heads share
+    ``num_kv_heads`` key/value heads of ``head_dim``; causal, and with
+    ``window`` w a key j is seen from i only if ``0 <= i - j < w``; the
+    first ``rotary_dim`` dimensions of each head are rotated (``rope_theta``;
+    ``yarn_factor``, ``yarn_original_length``, ``yarn_beta_fast``,
+    ``yarn_beta_slow`` for YaRN; ``rope_attention_factor`` on cos and
+    sin); scores are scaled by ``1/sqrt(head_dim)``; each head's output is
+    multiplied by ``sigmoid(x W_g)``, one scalar a head a token, before
+    the output projection.  Blobs: W_q, W_k, W_v, W_g, W_o; no bias."""
+
+    def _geom(self, lp):
+        p = lp.sub("attention_param")
+        d = int(p.get("head_dim", 0))
+        return dict(
+            heads=int(p.get("num_heads", 0)),
+            kv=int(p.get("num_kv_heads", 0)), d=d,
+            window=int(p.get("window", 0)),
+            factor=float(p.get("rope_attention_factor", 1.0)),
+            inv_freq=rope_inv_freq(
+                int(p.get("rotary_dim", d)), float(p.get("rope_theta", 1e4)),
+                float(p.get("yarn_factor", 0.0)),
+                int(p.get("yarn_original_length", 0)),
+                float(p.get("yarn_beta_fast", 32.0)),
+                float(p.get("yarn_beta_slow", 1.0))))
+
+    def init(self, rng, lp, bottom_shapes):
+        g = self._geom(lp)
+        hidden = bottom_shapes[0][-1]
+        wf = _filler(lp.sub("attention_param"))
+        q, kvw = g["heads"] * g["d"], g["kv"] * g["d"]
+        shapes = [(hidden, q), (hidden, kvw), (hidden, kvw),
+                  (hidden, g["heads"]), (q, hidden)]
+        return [fill(r, wf, s)
+                for r, s in zip(jax.random.split(rng, 5), shapes)]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        g = self._geom(lp)
+        heads, kv, d = g["heads"], g["kv"], g["d"]
+        wq, wk, wv, wg, wo = params
+        path = attn_lowering(bottoms[0].shape[-2], d)
+
+        def one(x):
+            s = x.shape[0]
+            q = apply_rope((x @ wq).reshape(s, heads, d), g["inv_freq"],
+                           g["factor"], scale=d ** -0.5)
+            k = apply_rope((x @ wk).reshape(s, kv, d), g["inv_freq"],
+                           g["factor"])
+            v = (x @ wv).reshape(s, kv, d)
+            out = attn_core(q, k, v, g["window"], path)
+            gate = jax.nn.sigmoid((x @ wg).astype(jnp.float32))
+            out = (out.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+            return out.reshape(s, heads * d) @ wo
+
+        return [_per_sequence(one, bottoms[0])]
+
+
+# -- MLPs ---------------------------------------------------------------------
+
+@register_layer("GatedMLP")
+class GatedMLPLayer(LayerImpl):
+    """``down(silu(gate(x)) * up(x))`` of ``gated_mlp_param.width``.
+    Blobs: W_gate, W_up, W_down; no bias."""
+
+    def init(self, rng, lp, bottom_shapes):
+        p = lp.sub("gated_mlp_param")
+        hidden, width = bottom_shapes[0][-1], int(p.get("width", 0))
+        wf = _filler(p)
+        shapes = [(hidden, width), (hidden, width), (width, hidden)]
+        return [fill(r, wf, s)
+                for r, s in zip(jax.random.split(rng, 3), shapes)]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        wg, wu, wd = params
+        return [_per_sequence(lambda x: _swiglu(x @ wg, x @ wu) @ wd,
+                              bottoms[0])]
+
+
+def moe_geometry(lp) -> dict:
+    p = lp.sub("moe_param")
+    return dict(experts=int(p.get("num_experts", 0)),
+                top_k=int(p.get("top_k", 1)),
+                lo=int(p.get("experts_held_lo", 0)),
+                hi=int(p.get("experts_held_hi", p.get("num_experts", 0))),
+                scaling=float(p.get("routed_scaling", 1.0)),
+                detached=bool(p.get("detach_router", False)))
+
+
+def moe_row_bound(tokens: int, g: dict) -> int:
+    """Rows the experts' products are sized for: a quarter over the
+    ``tokens * top_k * held / experts`` an even router sends here, in whole
+    tiles, and never more than every token choosing every held expert.  A
+    buffer's size and no capacity of an expert: it binds only if a quarter
+    more picks than the held share land here, and ``moe_route`` counts the
+    rows it would then leave out."""
+    held = g["hi"] - g["lo"]
+    most = tokens * min(g["top_k"], held)
+    even = tokens * g["top_k"] * held / g["experts"]
+    return min(most, -(-math.ceil(1.25 * even) // _GMM_ROWS) * _GMM_ROWS)
+
+
+def moe_route(x, w_router, g: dict):
+    """Route ``x [tokens, hidden]`` over all the experts and list the rows
+    the held ones compute.  Scores are ``sigmoid(x W_r)`` in float32; a
+    token takes its ``top_k`` largest (the lower index on a tie) with
+    weights normalised to sum 1, times ``scaling``.  Returns (token index,
+    weight, rows of each held expert as sized for the products) of the
+    ``moe_row_bound`` rows sorted by expert, then (rows each held expert
+    was sent, rows left out because the bound bound)."""
+    tokens, held, k = x.shape[0], g["hi"] - g["lo"], g["top_k"]
+    if g.get("detached"):
+        x = jax.lax.stop_gradient(x)
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+    weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * g["scaling"]
+    here = (top_i >= g["lo"]) & (top_i < g["hi"])
+    key = jnp.where(here, top_i - g["lo"], held).reshape(-1)
+    rows = moe_row_bound(tokens, g)
+    order = jnp.argsort(key, stable=True)[:rows]
+    sent = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(sent), rows)
+    sized = jnp.diff(ends, prepend=0)
+    # the rows past the last one sent go to the last expert with weight 0,
+    # so the products do the same work whatever the router chose
+    sized = sized.at[-1].add(rows - ends[-1])
+    w = jnp.where(key[order] < held, weight.reshape(-1)[order], 0.0)
+    return order // k, w, sized, sent, jnp.sum(sent) - ends[-1]
+
+
+def moe_lowering(rows: int, hidden: int, width: int) -> str:
+    """Which lowering the experts' grouped products take at these sizes on
+    this backend; counted in ``moe_lowering_total``."""
+    gmm = (jax.default_backend() == "tpu" and rows % _GMM_ROWS == 0
+           and hidden % 128 == 0 and width % 128 == 0)
+    path = "gmm" if gmm else "ragged_dot"
+    telemetry.get_registry().counter(
+        "moe_lowering_total",
+        "traces of an expert layer's grouped products, by lowering").inc(
+            path=path)
+    return path
+
+
+def _grouped(rows, w, sizes, path: str):
+    """``rows[group i] @ w[i]`` for rows sorted by group."""
+    if path != "gmm":
+        return jax.lax.ragged_dot(rows, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    k, n = w.shape[1:]
+    return megablox.gmm(rows, w, sizes, rows.dtype,
+                        (_GMM_ROWS, min(k, 1024), min(n, 1024)))
+
+
+@register_layer("MixtureOfExperts")
+class MixtureOfExpertsLayer(LayerImpl):
+    """A router over ``num_experts`` experts, ``top_k`` a token, and a
+    shared expert (``moe_param``).  The layer holds the experts
+    ``[experts_held_lo, experts_held_hi)``: it routes over all of them,
+    computes the held experts' part for the tokens routed to them, adds the
+    shared expert unweighted, and leaves out what the absent experts would
+    add.  No token is dropped.  Blobs: W_router; the held experts' W_gate,
+    W_up ``[held, hidden, width]`` and W_down ``[held, width, hidden]``;
+    the shared expert's W_gate, W_up, W_down.  ``router_column_norm``, if
+    given, scales each column of the filled router to that length.
+    ``detach_router`` keeps the scores' gradient from the layer's input
+    (the router's own weights still get theirs): for a layer that holds a
+    share of the experts and is sent only that share of the gradient."""
+
+    def init(self, rng, lp, bottom_shapes):
+        p, g = lp.sub("moe_param"), moe_geometry(lp)
+        hidden, held = bottom_shapes[0][-1], g["hi"] - g["lo"]
+        width = int(p.get("expert_width", 0))
+        shared = int(p.get("shared_width", 0))
+        wf = _filler(p)
+        shapes = [(held, hidden, width), (held, hidden, width),
+                  (held, width, hidden), (hidden, shared), (hidden, shared),
+                  (shared, hidden)]
+        r = jax.random.split(rng, 7)
+        router = fill(r[0], _filler(p, "router_filler"),
+                      (hidden, g["experts"]))
+        norm = float(p.get("router_column_norm", 0.0))
+        if norm:
+            # every expert's column as long as the next one's: a longer
+            # column's scores spread wider and reach a token's top picks
+            # more often, which at hidden 2048 is 7% of load from the
+            # filler alone
+            router = router * (norm / jnp.linalg.norm(router, axis=0))
+        return [router] + [fill(ri, wf, s) for ri, s in zip(r[1:], shapes)]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        g = moe_geometry(lp)
+        shape = bottoms[0].shape
+        path = moe_lowering(moe_row_bound(math.prod(shape[:-1]), g),
+                            shape[-1], params[1].shape[-1])
+
+        def moe(x, wr, eg, eu, ed, sg, su, sd):
+            with jax.named_scope("moe_route"):
+                token, w, sized, _, _ = moe_route(x, wr, g)
+                rows = x[token]
+            with jax.named_scope("moe_experts"):
+                y = _grouped(_swiglu(_grouped(rows, eg, sized, path),
+                                     _grouped(rows, eu, sized, path)),
+                             ed, sized, path)
+            with jax.named_scope("moe_route"):
+                y = y.astype(jnp.float32) * w[:, None]
+                routed = jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+            return (routed + (_swiglu(x @ sg, x @ su) @ sd)).astype(x.dtype)
+
+        out = jax.checkpoint(moe)(bottoms[0].reshape(-1, shape[-1]), *params)
+        return [out.reshape(shape)]
+
+
+def moe_load(net, params, inputs) -> dict:
+    """What each expert layer of ``net`` was sent on ``inputs``:
+    ``{layer: {"rows": [per held expert], "dropped": n}}`` from a
+    training-mode forward, one sequence at a time so that it fits beside a
+    training step's state.  Also counted in ``moe_rows_total{layer}`` and
+    ``moe_dropped_total``."""
+    nodes = [n for n in net.nodes if n.lp.type == "MixtureOfExperts"]
+
+    @jax.jit
+    def load(params, one):
+        blobs = net.apply_all(params, one, train=True)
+        out = {}
+        for n in nodes:
+            x = blobs[n.bottoms[0]]
+            _, _, _, sent, dropped = moe_route(
+                x.reshape(-1, x.shape[-1]),
+                params[n.lp.name][0].astype(x.dtype), moe_geometry(n.lp))
+            out[n.lp.name] = {"rows": sent, "dropped": dropped}
+        return out
+
+    sequences = len(next(iter(inputs.values())))
+    got = [load(params, {k: v[i:i + 1] for k, v in inputs.items()})
+           for i in range(sequences)]
+    total = jax.tree_util.tree_map(lambda *xs: np.sum(xs, axis=0), *got)
+    reg = telemetry.get_registry()
+    for name, sent in total.items():
+        reg.counter("moe_rows_total",
+                    "rows the held experts were sent").inc(
+                        int(sent["rows"].sum()), layer=name)
+        reg.counter("moe_dropped_total",
+                    "rows an expert layer left out").inc(
+                        int(sent["dropped"]))
+    return {k: {"rows": v["rows"].tolist(), "dropped": int(v["dropped"])}
+            for k, v in total.items()}
+
+
+# -- head and loss ------------------------------------------------------------
+
+@register_layer("LMHeadLoss")
+class LMHeadLossLayer(LayerImpl):
+    """The output head and its loss in one layer, a sequence at a time, so
+    that a step never holds every position's logits: bottoms are the
+    hidden states and the token ids; the loss is the mean softmax
+    cross-entropy of position t's logits (``x W``, float32 out of the
+    product on) against token t+1.  A second top, if named, is the logits.
+    Blob: W ``[hidden, vocab]``, no bias.
+
+    The product runs in the net's compute dtype like any other layer's and
+    the softmax in float32 here, so the layer does not ask for the loss
+    layers' float32 casts: ``is_loss`` is false, and the net's builder
+    gives the top its ``loss_weight: 1``."""
+
+    def min_bottoms(self) -> int:
+        return 2
+
+    def is_loss(self) -> bool:
+        return False
+
+    def out_shapes(self, lp, bottom_shapes):
+        vocab = int(lp.sub("lm_head_param").get("vocab", 0))
+        logits = [tuple(bottom_shapes[0][:-1]) + (vocab,)]
+        return [()] + (logits if len(lp.top) > 1 else [])
+
+    def top_has_batch_axis(self, lp, top_index: int) -> bool:
+        return top_index > 0
+
+    def init(self, rng, lp, bottom_shapes):
+        p = lp.sub("lm_head_param")
+        return [fill(rng, _filler(p),
+                     (bottom_shapes[0][-1], int(p.get("vocab", 0))))]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        (w,) = params
+        hidden, tokens = bottoms
+        want_logits = len(lp.top) > 1
+
+        def one(x, ids):
+            logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+            logp = jax.nn.log_softmax(logits[:-1], axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, ids[1:, None].astype(jnp.int32), axis=-1)
+            return jnp.sum(nll), (logits if want_logits else ())
+
+        total, logits = _per_sequence(one, hidden, tokens)
+        n, s = tokens.shape
+        loss = jnp.sum(total) / (n * (s - 1))
+        return [loss] + ([logits] if want_logits else [])

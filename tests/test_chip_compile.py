@@ -168,3 +168,68 @@ ENTRY %main (a: bf16[8,4,9]) -> bf16[8,4,9] {
         ("copy.1", "relu_lrn_fwd", "in", 576),
         ("fusion.7", "relu_lrn_fwd", "out", 576),
         ("copy.3", "relu_lrn_bwd", "attributed", 576)}
+
+
+# -- the sequence layers (ops/sequence.py) at Laguna XS.2's widths ------------
+
+_ATTENTION = [("sliding", 64, 512), ("full", 48, 0)]
+
+
+@pytest.mark.parametrize("kind,heads,window", _ATTENTION,
+                         ids=[k for k, _, _ in _ATTENTION])
+def test_attention_layer_compiles_at_real_widths(one_chip, chip_branch,
+                                                 kind, heads, window):
+    """One sequence of 8,192 positions through an attention layer of the
+    published widths, forward and backward: the flash kernels are in the
+    program, forward and both backward ones, and no score matrix is."""
+    from sparknet_tpu import models
+    from sparknet_tpu.ops import get_layer_impl
+    net = models.laguna(1, 1, num_layers=2, vocab=128, experts_held=(0, 8))
+    lp = next(l for l in net.layer
+              if l.name == ("L1/attn" if kind == "sliding" else "L0/attn"))
+    impl = get_layer_impl("Attention")
+    shapes = jax.eval_shape(lambda r: impl.init(r, lp, [(1, 8192, 2048)]),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    assert shapes[0].shape == (2048, heads * 128)
+    bf16 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                          sharding=one_chip)
+
+    def loss(params, x):
+        return jnp.sum(impl.apply(lp, params, [x], True, None)[0]
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+        [bf16(s) for s in shapes],
+        bf16(jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # a sequence's scores for one head alone would be 268 MB in float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_expert_layer_compiles_at_real_widths(one_chip, chip_branch):
+    """32,768 tokens through an expert layer holding 32 of 256 experts of
+    the published widths, forward and backward: the grouped products are
+    kernels, sized for a quarter over the even share of rows."""
+    from sparknet_tpu import models
+    from sparknet_tpu.ops import get_layer_impl, sequence
+    net = models.laguna(4, 1, num_layers=2, vocab=128, experts_held=(0, 32))
+    lp = next(l for l in net.layer if l.name == "L1/moe")
+    assert sequence.moe_row_bound(32768, sequence.moe_geometry(lp)) == 40960
+    impl = get_layer_impl("MixtureOfExperts")
+    shapes = jax.eval_shape(lambda r: impl.init(r, lp, [(4, 8192, 2048)]),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    assert [s.shape for s in shapes[:4]] == [
+        (2048, 256), (32, 2048, 512), (32, 2048, 512), (32, 512, 2048)]
+    bf16 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                          sharding=one_chip)
+
+    def loss(params, x):
+        return jnp.sum(impl.apply(lp, params, [x], True, None)[0]
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        [bf16(s) for s in shapes],
+        bf16(jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16))
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 9      # 3 products x 3 passes

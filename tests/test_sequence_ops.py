@@ -1,0 +1,336 @@
+"""The sequence layer types (``ops/sequence.py``) against the benchmark's
+plain reference (``benchmark/lib/reference_lm.py``), forward and gradients,
+at small sizes on the CPU."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.lib import reference_lm as ref
+from sparknet_tpu.models.dsl import layer
+from sparknet_tpu.ops import get_layer_impl
+from sparknet_tpu.ops import sequence as seq
+
+HIDDEN, HEAD_DIM, KV, WINDOW = 32, 8, 2, 8
+YARN = {"rope_theta": 500000.0, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 8, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5}
+PLAIN = {"rope_type": "default", "rope_theta": 10000.0,
+         "partial_rotary_factor": 1}
+_GAUSS = {"type": "gaussian", "std": 0.3}
+
+
+def highest(fn):
+    return ref.highest(fn)
+
+
+def init_and_apply(lp, x_shape, key=0):
+    impl = get_layer_impl(lp.type)
+    params = impl.init(jax.random.PRNGKey(key), lp, [x_shape])
+    return impl, params
+
+
+def close(got, want, tol=2e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    scale = max(float(np.abs(want).max()), 1e-6)
+    assert float(np.abs(got - want).max()) <= tol * scale
+
+
+def attention_lp(heads, window, rope):
+    yarn = rope["rope_type"] == "yarn"
+    p = {"num_heads": heads, "num_kv_heads": KV, "head_dim": HEAD_DIM,
+         "window": window,
+         "rotary_dim": int(HEAD_DIM * rope["partial_rotary_factor"]),
+         "rope_theta": rope["rope_theta"], "weight_filler": _GAUSS}
+    if yarn:
+        p.update(yarn_factor=rope["factor"],
+                 yarn_original_length=rope[
+                     "original_max_position_embeddings"],
+                 yarn_beta_fast=rope["beta_fast"],
+                 yarn_beta_slow=rope["beta_slow"],
+                 rope_attention_factor=rope["attention_factor"])
+    return layer("attn", "Attention", ["x"], ["y"], attention_param=p)
+
+
+# (kind, query heads a kv head, positions, rope): full and sliding, 6 and
+# 8 query heads a key/value head, sequences shorter than, equal to and
+# longer than the window of 8, partial rotary with YaRN and whole rotary
+ATTENTION_CASES = [
+    ("full", 6, 12, YARN), ("full", 8, 12, YARN),
+    ("sliding", 6, 5, PLAIN), ("sliding", 6, 8, PLAIN),
+    ("sliding", 6, 20, PLAIN), ("sliding", 8, 5, PLAIN),
+    ("sliding", 8, 8, PLAIN), ("sliding", 8, 20, PLAIN),
+    ("sliding", 6, 20, YARN), ("full", 8, 12, PLAIN),
+]
+
+
+@pytest.mark.parametrize("kind,group,positions,rope", ATTENTION_CASES)
+def test_attention_against_reference(kind, group, positions, rope):
+    heads = group * KV
+    lp = attention_lp(heads, WINDOW if kind == "sliding" else 0, rope)
+    impl, params = init_and_apply(lp, (2, positions, HIDDEN))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, positions, HIDDEN))
+    m = {"head_dim": HEAD_DIM, "kv_heads": KV, "window": WINDOW,
+         "rope": {kind: rope}}
+    spec = {"kind": kind, "heads": heads}
+
+    def system(p, x):
+        return impl.apply(lp, p, [x], True, None)[0]
+
+    def reference(p, x):
+        return jnp.stack([ref.attention(xi, p, spec, m) for xi in x])
+
+    close(system(params, x), highest(reference)(params, x))
+    cot = jax.random.normal(jax.random.PRNGKey(2), (2, positions, HIDDEN))
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   argnums=(0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) * cot),
+                            argnums=(0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 1e-4)
+
+
+@pytest.mark.parametrize("rope", [YARN, PLAIN], ids=["yarn", "default"])
+def test_rope_frequencies_against_reference(rope):
+    dim = int(HEAD_DIM * rope["partial_rotary_factor"])
+    got = seq.rope_inv_freq(
+        dim, rope["rope_theta"], rope.get("factor", 0.0),
+        rope.get("original_max_position_embeddings", 0),
+        rope.get("beta_fast", 32.0), rope.get("beta_slow", 1.0))
+    np.testing.assert_allclose(got, ref.inv_freq(rope, HEAD_DIM), rtol=1e-12)
+
+
+def test_yarn_blend_at_the_published_sizes():
+    """Full layers: 64 rotated dimensions, theta 500,000, factor 64 over
+    an original length of 4096: the fastest pairs keep their frequency,
+    the slowest are interpolated 64-fold, the ramp lies between."""
+    f = seq.rope_inv_freq(64, 500000.0, 64.0, 4096, 64.0, 1.0)
+    plain = seq.rope_inv_freq(64, 500000.0)
+    assert f.shape == (32,)
+    ratio = plain / f
+    assert ratio[0] == 1.0 and ratio[-1] == pytest.approx(64.0)
+    # the ramp runs from pair 5 (64 turns in 4096 positions) to pair 16
+    assert ratio[5] == 1.0 and ratio[16] == pytest.approx(64.0)
+    assert np.all(np.diff(ratio) >= 0) and 1.0 < ratio[10] < 64.0
+
+
+def test_rms_norm_against_reference():
+    lp = layer("n", "RMSNorm", ["x"], ["y"], rms_norm_param={"eps": 1e-6})
+    impl, (w,) = init_and_apply(lp, (2, 5, HIDDEN))
+    assert np.all(np.asarray(w) == 1.0)
+    w = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(3), (HIDDEN,))
+    x = 3.0 * jax.random.normal(jax.random.PRNGKey(4), (2, 5, HIDDEN))
+    system = lambda x, w: impl.apply(lp, [w], [x], True, None)[0]
+    close(system(x, w), ref.norm(x, w, 1e-6))
+    got = jax.grad(lambda x, w: jnp.sum(jnp.sin(system(x, w))), (0, 1))(x, w)
+    want = jax.grad(lambda x, w: jnp.sum(jnp.sin(ref.norm(x, w, 1e-6))),
+                    (0, 1))(x, w)
+    close(got[0], want[0], 1e-4)
+    close(got[1], want[1], 1e-4)
+
+
+def test_gated_mlp_against_reference():
+    lp = layer("m", "GatedMLP", ["x"], ["y"],
+               gated_mlp_param={"width": 48, "weight_filler": _GAUSS})
+    impl, params = init_and_apply(lp, (2, 7, HIDDEN))
+    assert [p.shape for p in params] == [(32, 48), (32, 48), (48, 32)]
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 7, HIDDEN))
+    system = lambda p, x: impl.apply(lp, p, [x], True, None)[0]
+    reference = lambda p, x: ref.mlp(x, *p)
+    close(system(params, x), highest(reference)(params, x))
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) ** 2), (0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) ** 2),
+                            (0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 1e-4)
+
+
+# -- the router ---------------------------------------------------------------
+
+GEOM = {"experts": 16, "top_k": 8, "lo": 0, "hi": 16, "scaling": 2.5}
+
+
+def test_router_takes_the_eight_largest_of_sixteen():
+    x = jax.random.normal(jax.random.PRNGKey(6), (64, HIDDEN))
+    wr = 0.3 * jax.random.normal(jax.random.PRNGKey(7), (HIDDEN, 16))
+    token, w, sized, sent, dropped = seq.moe_route(x, wr, GEOM)
+    assert int(dropped) == 0 and int(sent.sum()) == 64 * 8
+    assert np.array_equal(np.asarray(sized), np.asarray(sent))
+    weight, chosen = highest(lambda x, wr: ref.route(
+        x, wr, {"top_k": 8, "scaling": 2.5}))(x, wr)
+    # every token's weights sum to the routed scaling factor
+    np.testing.assert_allclose(np.asarray(weight).sum(-1), 2.5, rtol=1e-6)
+    per_token = np.zeros((64, 16))
+    expert = np.repeat(np.arange(16), np.asarray(sent))
+    np.add.at(per_token, (np.asarray(token), expert), np.asarray(w))
+    want = np.zeros((64, 16))
+    np.put_along_axis(want, np.asarray(chosen), np.asarray(weight), axis=-1)
+    np.testing.assert_allclose(per_token, want, rtol=1e-5, atol=1e-7)
+
+
+def test_router_breaks_ties_towards_the_lower_index():
+    """Identical columns of the router give identical scores: program and
+    reference both take the lower indices."""
+    x = jax.random.normal(jax.random.PRNGKey(8), (16, HIDDEN))
+    col = jax.random.normal(jax.random.PRNGKey(9), (HIDDEN, 1))
+    wr = jnp.tile(col, (1, 16))
+    token, w, _, sent, _ = seq.moe_route(x, wr, GEOM)
+    assert np.asarray(sent).tolist() == [16] * 8 + [0] * 8
+    np.testing.assert_allclose(np.asarray(w), 2.5 / 8, rtol=1e-6)
+    _, chosen = ref.route(x, wr, {"top_k": 8, "scaling": 2.5})
+    assert np.array_equal(np.asarray(chosen),
+                          np.tile(np.arange(8), (16, 1)))
+
+
+def test_router_counts_only_the_held_experts():
+    x = jax.random.normal(jax.random.PRNGKey(10), (64, HIDDEN))
+    wr = 0.3 * jax.random.normal(jax.random.PRNGKey(11), (HIDDEN, 16))
+    _, chosen = ref.route(x, wr, {"top_k": 8, "scaling": 2.5})
+    g = {**GEOM, "lo": 4, "hi": 8}
+    token, w, sized, sent, dropped = seq.moe_route(x, wr, g)
+    want = [(np.asarray(chosen) == e).sum() for e in range(4, 8)]
+    assert np.asarray(sent).tolist() == want and int(dropped) == 0
+    # the rows past the last one sent carry weight 0 and go to the last
+    # held expert, so the products are sized alike whatever was chosen
+    n = int(sent.sum())
+    assert int(sized.sum()) == seq.moe_row_bound(64, g) == len(np.asarray(w))
+    assert np.all(np.asarray(w)[n:] == 0) and np.all(np.asarray(w)[:n] > 0)
+
+
+def test_row_bound_counts_what_it_leaves_out():
+    """A router that sends every token to every held expert passes the
+    bound of a quarter over the even share; the rows left out are
+    counted, which is what makes a run not correct."""
+    g = {"experts": 16, "top_k": 4, "lo": 0, "hi": 4, "scaling": 1.0}
+    assert seq.moe_row_bound(512, g) == 1024          # 640 in whole tiles
+    assert seq.moe_row_bound(8, g) == 32              # never above T * held
+    x = jnp.ones((512, HIDDEN))
+    wr = jnp.concatenate([jnp.ones((HIDDEN, 4)), -jnp.ones((HIDDEN, 12))], 1)
+    _, w, sized, sent, dropped = seq.moe_route(x, wr, g)
+    assert np.asarray(sent).tolist() == [512] * 4
+    assert int(dropped) == 1024 and int(sized.sum()) == 1024
+    assert np.asarray(sized).tolist() == [512, 512, 0, 0]
+
+
+# -- the expert layer -----------------------------------------------------------
+
+def moe_lp(lo, hi, experts=16, top_k=8, detach=False):
+    return layer("moe", "MixtureOfExperts", ["x"], ["y"], moe_param={
+        "num_experts": experts, "top_k": top_k, "experts_held_lo": lo,
+        "experts_held_hi": hi, "expert_width": 16, "shared_width": 24,
+        "routed_scaling": 2.5, "weight_filler": _GAUSS,
+        "router_filler": _GAUSS, "detach_router": detach})
+
+
+@pytest.mark.parametrize("lo,hi,detach", [(0, 4, False), (4, 8, False),
+                                          (0, 16, False), (4, 8, True)])
+def test_expert_layer_against_reference(lo, hi, detach):
+    """``detach`` stops the scores' gradient at the router's input, in
+    program and reference alike; the router's own gradient stays."""
+    lp = moe_lp(lo, hi, detach=detach)
+    impl, params = init_and_apply(lp, (2, 24, HIDDEN))
+    assert params[0].shape == (HIDDEN, 16)
+    assert params[1].shape == (hi - lo, HIDDEN, 16)
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 24, HIDDEN))
+    m = {"top_k": 8, "held": (lo, hi), "scaling": 2.5,
+         "train_router": not detach}
+    system = lambda p, x: impl.apply(lp, p, [x], True, None)[0]
+    reference = lambda p, x: jnp.stack([ref.moe(xi, p, m) for xi in x])
+    close(system(params, x), highest(reference)(params, x))
+    cot = jax.random.normal(jax.random.PRNGKey(13), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   (0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) * cot),
+                            (0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 1e-4)
+    assert float(jnp.abs(got[0][0]).max()) > 0      # the router's gradient
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """The parts all 8 shares of the experts give, the shared expert
+    counted once, are the uncut reference's layer output."""
+    whole = moe_lp(0, 16)
+    impl, params = init_and_apply(whole, (1, 40, HIDDEN))
+    wr, eg, eu, ed, *shared = params
+    x = jax.random.normal(jax.random.PRNGKey(14), (1, 40, HIDDEN))
+    m = {"top_k": 8, "held": (0, 16), "scaling": 2.5}
+    uncut = highest(lambda p, x: ref.moe(x, p, m))(params, x[0])
+    shared_part = highest(lambda x: ref.mlp(x, *shared))(x[0])
+    total = np.zeros_like(np.asarray(uncut))
+    for share in range(8):
+        lo, hi = 2 * share, 2 * share + 2
+        part = impl.apply(moe_lp(lo, hi),
+                          [wr, eg[lo:hi], eu[lo:hi], ed[lo:hi], *shared],
+                          [x], True, None)[0][0]
+        total += np.asarray(part) - np.asarray(shared_part)
+    close(total + np.asarray(shared_part), uncut, 1e-5)
+
+
+@pytest.mark.parametrize("seed", range(5))
+def test_held_experts_load_stays_in_a_binomial_band(seed):
+    """Seeded weights at the router's filler the configuration assumes
+    (gaussian 0.006, every column scaled to one length) and inputs of unit
+    scale: the rows a held expert gets lie within five standard deviations
+    of its binomial mean.  Without the scaling a column a tenth longer
+    than the mean draws a fifth more rows at this width."""
+    tokens, hidden, experts, top_k, held = 4096, 64, 32, 4, 8
+    lp = layer("moe", "MixtureOfExperts", ["x"], ["y"], moe_param={
+        "num_experts": experts, "top_k": top_k, "experts_held_lo": 0,
+        "experts_held_hi": held, "expert_width": 8, "shared_width": 8,
+        "router_filler": {"type": "gaussian", "std": 0.006},
+        "router_column_norm": 0.006 * hidden ** 0.5,
+        "weight_filler": _GAUSS})
+    kx, kw = jax.random.split(jax.random.PRNGKey(100 + seed))
+    wr = get_layer_impl(lp.type).init(kw, lp, [(1, tokens, hidden)])[0]
+    np.testing.assert_allclose(np.linalg.norm(np.asarray(wr), axis=0),
+                               0.048, rtol=1e-5)
+    x = jax.random.normal(kx, (tokens, hidden))
+    _, _, _, sent, dropped = seq.moe_route(x, wr, seq.moe_geometry(lp))
+    p = top_k / experts
+    mean, sd = tokens * p, (tokens * p * (1 - p)) ** 0.5
+    assert int(dropped) == 0
+    assert np.all(np.abs(np.asarray(sent) - mean) < 5 * sd)
+    assert abs(int(sent.sum()) - held * mean) < 5 * sd * held ** 0.5
+
+
+# -- head and loss --------------------------------------------------------------
+
+@pytest.mark.parametrize("with_logits", [False, True])
+def test_head_loss_against_reference(with_logits):
+    vocab = 40
+    tops = ["loss", "logits"] if with_logits else ["loss"]
+    lp = layer("head", "LMHeadLoss", ["x", "tokens"], tops,
+               lm_head_param={"vocab": vocab, "weight_filler": _GAUSS})
+    impl = get_layer_impl("LMHeadLoss")
+    assert impl.out_shapes(lp, [(2, 9, HIDDEN), (2, 9)]) == (
+        [(), (2, 9, vocab)] if with_logits else [()])
+    (w,) = impl.init(jax.random.PRNGKey(15), lp, [(2, 9, HIDDEN), (2, 9)])
+    x = jax.random.normal(jax.random.PRNGKey(16), (2, 9, HIDDEN))
+    tokens = jax.random.randint(jax.random.PRNGKey(17), (2, 9), 0, vocab)
+
+    def reference(w, x):
+        logp = jax.nn.log_softmax(x @ w, axis=-1)[:, :-1]
+        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
+        return jnp.mean(nll)
+
+    out = impl.apply(lp, [w], [x, tokens], True, None)
+    close(out[0], highest(reference)(w, x))
+    if with_logits:
+        close(out[1], highest(lambda w, x: x @ w)(w, x))
+    got = jax.grad(lambda w, x: impl.apply(lp, [w], [x, tokens], True,
+                                           None)[0], (0, 1))(w, x)
+    want = highest(jax.grad(reference, (0, 1)))(w, x)
+    close(got[0], want[0], 1e-4)
+    close(got[1], want[1], 1e-4)
