@@ -13,6 +13,13 @@ run one sequence at a time (``jax.lax.map``), so what a step keeps between
 the passes is the blobs between layers and what it holds at once is one
 sequence's projections, not a batch's.
 
+Layout.  Between its projections and its flash kernels the attention layer
+keeps one shape, one layout and one width: ``[kv heads, query heads a kv
+head, positions, head_dim]`` (keys and values without the second), head_dim
+on the lanes and positions on the sublanes, in the compute dtype.  The
+products write and read it as it is (``_heads_major``), rotary turns a head
+by a product and not by slicing it, and float32 lives only inside a fusion.
+
 Lowerings, one an operation, chosen here from shapes and backend and counted
 once a trace (``attn_lowering_total{path}``, ``moe_lowering_total{path}``):
 
@@ -35,6 +42,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..proto.caffe_pb import FillerParameter
 from ..utils import telemetry
@@ -110,22 +118,57 @@ def rope_inv_freq(rotary_dim: int, theta: float, yarn_factor: float = 0.0,
         + (1.0 / pos_freqs) * keep
 
 
+def _rotate_half_matrix(head_dim: int, half: int, dtype) -> jnp.ndarray:
+    """The ``[head_dim, head_dim]`` matrix of 0 and ±1 with ``x @ R`` =
+    ``[-x2, x1, 0]`` for ``x = [x1, x2, rest]``, x1 and x2 of ``half``."""
+    r = np.zeros((head_dim, head_dim), np.float32)
+    i = np.arange(half)
+    r[i + half, i] = -1.0
+    r[i, i + half] = 1.0
+    return jnp.asarray(r, dtype)
+
+
+def _rotate(x, inv_freq: tuple, factor: float, scale: float, sign: float):
+    """``(x * cos + rotate_half(x) * sign * sin) * scale`` in float32, cos
+    and sin ``[positions, head_dim]`` tables that read 1 and 0 on the
+    dimensions past the rotated ones."""
+    s, d = x.shape[-2:]
+    half = len(inv_freq)
+    pos = jnp.arange(s, dtype=jnp.float32)
+    ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    rest = d - 2 * half
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * (sign * factor)
+    cos = jnp.concatenate([cos, cos, jnp.ones((s, rest), jnp.float32)], -1)
+    sin = jnp.concatenate([sin, sin, jnp.zeros((s, rest), jnp.float32)], -1)
+    # one term a column, so the product is exact in any dtype: the half
+    # turn stays on the lanes and no head is sliced
+    turned = jnp.matmul(x, _rotate_half_matrix(d, half, x.dtype),
+                        precision=jax.lax.Precision.HIGHEST)
+    out = x.astype(jnp.float32) * cos + turned.astype(jnp.float32) * sin
+    return (out * scale).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _rope(x, inv_freq, factor, scale):
+    return _rotate(x, inv_freq, factor, scale, 1.0)
+
+
+# a rotation's transpose is the rotation by the negative angle: the
+# backward pass is the forward's one fusion on the cotangent
+_rope.defvjp(
+    lambda x, inv_freq, factor, scale: (_rope(x, inv_freq, factor, scale),
+                                        None),
+    lambda inv_freq, factor, scale, _, g: (
+        _rotate(g, inv_freq, factor, scale, -1.0),))
+
+
 def apply_rope(x, inv_freq: np.ndarray, factor: float, scale: float = 1.0):
     """Rotate the first ``2 * len(inv_freq)`` of the last axis of
-    ``x [positions, heads, head_dim]`` by position, halves paired as
+    ``x [..., positions, head_dim]`` by position, halves paired as
     ``transformers`` pairs them; ``factor`` multiplies cos and sin.  In
     float32; ``scale`` (the score scale, on queries) rides along."""
-    half = inv_freq.shape[0]
-    pos = jnp.arange(x.shape[0], dtype=jnp.float32)
-    ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
-    cos = (jnp.cos(ang) * factor)[:, None, :]
-    sin = (jnp.sin(ang) * factor)[:, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2, rest = (x32[..., :half], x32[..., half:2 * half],
-                    x32[..., 2 * half:])
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-    return (out * scale).astype(x.dtype)
+    return _rope(x, tuple(float(f) for f in inv_freq), float(factor),
+                 float(scale))
 
 
 # -- attention ---------------------------------------------------------------
@@ -172,21 +215,24 @@ def attn_lowering(positions: int, head_dim: int) -> str:
     return path
 
 
+def _heads_major(t):
+    """``t [..., positions, head_dim]`` and its cotangent kept in that
+    order in memory, head_dim on the lanes: what the flash kernels read
+    and write, so the products on either side make and take it as it is
+    and nothing is transposed between them."""
+    return with_layout_constraint(
+        t, Layout(major_to_minor=tuple(range(t.ndim))))
+
+
 def attn_core(q, k, v, window: int, path: str):
     """Causal (and, with ``window``, sliding) grouped-query attention of
-    one sequence.  q [S, heads, D] already scaled, k and v [S, kv, D];
-    returns [S, heads, D]."""
-    s, heads, d = q.shape
-    kv = k.shape[1]
-    group = heads // kv
-    q = q.reshape(s, kv, group, d).transpose(1, 2, 0, 3)
-    k, v = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
+    one sequence.  q [kv, group, S, D] already scaled, k and v [kv, S, D];
+    returns [kv, group, S, D]."""
+    _, group, s, _ = q.shape
     with jax.named_scope("attn_core"):
         if path == "splash":
-            out = jax.vmap(_splash_kernel(s, group, window))(q, k, v)
-        else:
-            out = _attn_core_xla(q, k, v, window)
-    return out.transpose(2, 0, 1, 3).reshape(s, heads, d)
+            return jax.vmap(_splash_kernel(s, group, window))(q, k, v)
+        return _attn_core_xla(q, k, v, window)
 
 
 @register_layer("Attention")
@@ -233,17 +279,26 @@ class AttentionLayer(LayerImpl):
         wq, wk, wv, wg, wo = params
         path = attn_lowering(bottoms[0].shape[-2], d)
 
+        hidden, group = wq.shape[0], heads // kv
+        # the query heads grouped by their key/value head, as the kernels
+        # take them: no tensor between the products is reshaped
+        wq = wq.reshape(hidden, kv, group, d)
+        wk, wv = wk.reshape(hidden, kv, d), wv.reshape(hidden, kv, d)
+        wg = wg.reshape(hidden, kv, group)
+        wo = wo.reshape(kv, group, d, hidden)
+
         def one(x):
-            s = x.shape[0]
-            q = apply_rope((x @ wq).reshape(s, heads, d), g["inv_freq"],
-                           g["factor"], scale=d ** -0.5)
-            k = apply_rope((x @ wk).reshape(s, kv, d), g["inv_freq"],
-                           g["factor"])
-            v = (x @ wv).reshape(s, kv, d)
+            q = apply_rope(_heads_major(jnp.einsum("sh,hkgd->kgsd", x, wq)),
+                           g["inv_freq"], g["factor"], scale=d ** -0.5)
+            k = apply_rope(_heads_major(jnp.einsum("sh,hkd->ksd", x, wk)),
+                           g["inv_freq"], g["factor"])
+            v = _heads_major(jnp.einsum("sh,hkd->ksd", x, wv))
             out = attn_core(q, k, v, g["window"], path)
-            gate = jax.nn.sigmoid((x @ wg).astype(jnp.float32))
-            out = (out.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
-            return out.reshape(s, heads * d) @ wo
+            gate = jax.nn.sigmoid(
+                jnp.einsum("sh,hkg->kgs", x, wg).astype(jnp.float32))
+            out = _heads_major((out.astype(jnp.float32) * gate[..., None])
+                               .astype(x.dtype))
+            return jnp.einsum("kgsd,kgdh->sh", out, wo)
 
         return [_per_sequence(one, bottoms[0])]
 

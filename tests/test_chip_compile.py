@@ -175,13 +175,9 @@ ENTRY %main (a: bf16[8,4,9]) -> bf16[8,4,9] {
 _ATTENTION = [("sliding", 64, 512), ("full", 48, 0)]
 
 
-@pytest.mark.parametrize("kind,heads,window", _ATTENTION,
-                         ids=[k for k, _, _ in _ATTENTION])
-def test_attention_layer_compiles_at_real_widths(one_chip, chip_branch,
-                                                 kind, heads, window):
-    """One sequence of 8,192 positions through an attention layer of the
-    published widths, forward and backward: the flash kernels are in the
-    program, forward and both backward ones, and no score matrix is."""
+def _attention_gradient(one_chip, kind, heads):
+    """The gradient of one sequence of 8,192 positions through an attention
+    layer of the published widths, compiled for the described chip."""
     from sparknet_tpu import models
     from sparknet_tpu.ops import get_layer_impl
     net = models.laguna(1, 1, num_layers=2, vocab=128, experts_held=(0, 8))
@@ -198,13 +194,97 @@ def test_attention_layer_compiles_at_real_widths(one_chip, chip_branch,
         return jnp.sum(impl.apply(lp, params, [x], True, None)[0]
                        .astype(jnp.float32))
 
-    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+    return jax.jit(jax.grad(loss, (0, 1))).lower(
         [bf16(s) for s in shapes],
         bf16(jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16))).compile()
+
+
+@pytest.mark.parametrize("kind,heads,window", _ATTENTION,
+                         ids=[k for k, _, _ in _ATTENTION])
+def test_attention_layer_compiles_at_real_widths(one_chip, chip_branch,
+                                                 kind, heads, window):
+    """One sequence of 8,192 positions through an attention layer of the
+    published widths, forward and backward: the flash kernels are in the
+    program, forward and both backward ones, and no score matrix is."""
+    compiled = _attention_gradient(one_chip, kind, heads)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     # a sequence's scores for one head alone would be 268 MB in float32
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+# what the compiled gradient of one layer may write a sequence outside
+# matrix-product fusions and Pallas calls: what PR 36's code reaches and a
+# tenth more (the parent of PR 36: 2.75 and 3.72 GB)
+_ATTENTION_WRITES = [("full", 48, 0.43e9), ("sliding", 64, 0.54e9)]
+
+
+@pytest.mark.parametrize("kind,heads,most", _ATTENTION_WRITES,
+                         ids=[k for k, *_ in _ATTENTION_WRITES])
+def test_attention_layer_keeps_one_layout_and_width(
+        one_chip, chip_branch, lowered_text, kind, heads, most):
+    """Between the projections and the flash kernels every tensor is made
+    once, in the kernels' own shape and layout in bfloat16: the compiled
+    backward pass of one sequence (the recomputed forward and the
+    backward) writes little outside products and kernels (half of it the
+    log-sum-exp that JAX's wrapper of the kernels copies), and outside
+    that wrapper no slice, concatenate or copy makes a float32 array the
+    size of the queries."""
+    text = _attention_gradient(one_chip, kind, heads).as_text()
+    written, ops = lowered_text.written_bytes(text)
+    assert written["kernel"] > 0 and written["product"] > 0
+    assert written["other"] <= most
+    queries = 8192 * heads * 128 * 4
+    assert [o["name"] for o in ops
+            if o["opcode"] in ("slice", "concatenate", "copy")
+            and o["shape"].startswith("f32") and o["bytes"] == queries
+            and "/attn_core/" not in o["op_name"]] == []
+
+
+def test_written_bytes_sorts_operations_by_class(lowered_text):
+    """The reader itself, on a module's text: a loop's body counted once,
+    a fusion as one operation, products and kernels apart from the rest."""
+    hlo = """
+HloModule m
+%fused_dot (p: bf16[8,4], q: bf16[4,4]) -> bf16[8,4] {
+  %p = bf16[8,4]{1,0} parameter(0)
+  %q = bf16[4,4]{1,0} parameter(1)
+  ROOT %c = bf16[8,4]{1,0} convolution(%p, %q), dim_labels=bf_io->bf
+}
+%fused_add (p: bf16[8,4]) -> (f32[8,4], bf16[8,4]) {
+  %p = bf16[8,4]{1,0} parameter(0)
+  %c = f32[8,4]{1,0} convert(%p)
+  ROOT %t = (f32[8,4]{1,0}, bf16[8,4]{1,0}) tuple(%c, %p)
+}
+%body (t: (s32[], bf16[8,4])) -> (s32[], bf16[8,4]) {
+  %t = (s32[], bf16[8,4]{1,0}) parameter(0)
+  %x = bf16[8,4]{1,0} get-tuple-element(%t), index=1
+  %copy.1 = bf16[8,4]{0,1} copy(%x), metadata={op_name="jit(f)/attn_core/x"}
+  ROOT %r = (s32[], bf16[8,4]{1,0}) tuple(%t, %x)
+}
+%cond (t: (s32[], bf16[8,4])) -> pred[] {
+  %t = (s32[], bf16[8,4]{1,0}) parameter(0)
+  ROOT %lt = pred[] constant(false)
+}
+ENTRY %main (a: bf16[8,4], w: bf16[4,4]) -> bf16[8,4] {
+  %a = bf16[8,4]{1,0} parameter(0)
+  %w = bf16[4,4]{1,0} parameter(1)
+  %fusion.1 = bf16[8,4]{1,0} fusion(%a, %w), kind=kOutput, calls=%fused_dot
+  %fusion.2 = (f32[8,4]{1,0}, bf16[8,4]{1,0}) fusion(%fusion.1), kind=kLoop, calls=%fused_add
+  %k = bf16[8,4]{1,0} custom-call(%a), custom_call_target="tpu_custom_call"
+  %s = (bf16[8,4]{1,0}, bf16[8,4]{1,0}, u32[]) copy-start(%k)
+  %z = s32[] constant(0)
+  %init = (s32[], bf16[8,4]{1,0}) tuple(%z, %k)
+  %while.1 = (s32[], bf16[8,4]{1,0}) while(%init), condition=%cond, body=%body
+  ROOT %out = bf16[8,4]{1,0} get-tuple-element(%while.1), index=1
+}
+"""
+    written, ops = lowered_text.written_bytes(hlo)
+    assert written == {"product": 64, "other": 192 + 64, "kernel": 64,
+                       "async": 132}
+    by_name = {o["name"]: o for o in ops}
+    assert by_name["fusion.2"]["fused"] == ["convert", "tuple"]
+    assert by_name["copy.1"]["op_name"] == "jit(f)/attn_core/x"
 
 
 def test_expert_layer_compiles_at_real_widths(one_chip, chip_branch):

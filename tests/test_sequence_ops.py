@@ -109,6 +109,69 @@ def test_rope_frequencies_against_reference(rope):
     np.testing.assert_allclose(got, ref.inv_freq(rope, HEAD_DIM), rtol=1e-12)
 
 
+def rope_before(x, inv_freq, factor, scale=1.0):
+    """The rotation as it was written before PR 36, kept as the oracle:
+    ``x [positions, heads, head_dim]``, the head sliced at the halves and
+    concatenated again, in float32."""
+    half = inv_freq.shape[0]
+    pos = jnp.arange(x.shape[0], dtype=jnp.float32)
+    ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = (jnp.cos(ang) * factor)[:, None, :]
+    sin = (jnp.sin(ang) * factor)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = (x32[..., :half], x32[..., half:2 * half],
+                    x32[..., 2 * half:])
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return (out * scale).astype(x.dtype)
+
+
+def _rope_case(head_dim, partial, yarn):
+    rotary = head_dim // 2 if partial else head_dim
+    inv_freq = (seq.rope_inv_freq(rotary, 500000.0, 64.0, 8, 64.0, 1.0)
+                if yarn else seq.rope_inv_freq(rotary, 10000.0))
+    return inv_freq, (YARN["attention_factor"] if yarn else 1.0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("scaled", [False, True], ids=["unscaled", "scaled"])
+@pytest.mark.parametrize("yarn", [False, True], ids=["plain", "yarn"])
+@pytest.mark.parametrize("partial", [False, True], ids=["whole", "partial"])
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_rope_is_the_rotation_it_was(head_dim, partial, yarn, scaled, dtype):
+    """The lane-keeping rotation (cos and sin tables of the head's width
+    and the half turn as a product with a matrix of 0 and ±1) gives the
+    values the sliced one gave: equal in float32, within one unit of the
+    last place in bfloat16."""
+    inv_freq, factor = _rope_case(head_dim, partial, yarn)
+    scale = head_dim ** -0.5 if scaled else 1.0
+    x = (3.0 * jax.random.normal(jax.random.PRNGKey(20), (40, 3, head_dim))
+         ).astype(dtype)
+    want = np.asarray(rope_before(x, inv_freq, factor, scale)
+                      .astype(jnp.float32)).transpose(1, 0, 2)
+    got = np.asarray(seq.apply_rope(x.transpose(1, 0, 2), inv_freq, factor,
+                                    scale).astype(jnp.float32))
+    if dtype == "float32":
+        assert np.array_equal(got, want)
+    else:
+        assert np.all(np.abs(got - want) <= 2.0 ** -8 * np.abs(want))
+
+
+@pytest.mark.parametrize("partial", [False, True], ids=["whole", "partial"])
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_rope_gradient_is_the_turn_back(head_dim, partial):
+    """The backward pass is written out (the rotation by the negative
+    angle on the cotangent): it is the sliced formula's own gradient."""
+    inv_freq, factor = _rope_case(head_dim, partial, True)
+    x = jax.random.normal(jax.random.PRNGKey(21), (40, 3, head_dim))
+    cot = jax.random.normal(jax.random.PRNGKey(22), (3, 40, head_dim))
+    got = jax.grad(lambda x: jnp.sum(seq.apply_rope(
+        x.transpose(1, 0, 2), inv_freq, factor, 0.25) * cot))(x)
+    want = jax.grad(lambda x: jnp.sum(rope_before(
+        x, inv_freq, factor, 0.25).transpose(1, 0, 2) * cot))(x)
+    close(got, want, 1e-6)
+
+
 def test_yarn_blend_at_the_published_sizes():
     """Full layers: 64 rotated dimensions, theta 500,000, factor 64 over
     an original length of 4096: the fastest pairs keep their frequency,

@@ -23,19 +23,26 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   (``benchmark/rehearse.py`` is the model): ``Solver._step`` for
   ``caffenet_train_resident`` (bfloat16, 1,024) and
   ``googlenet_train_resident`` (bfloat16, 256), the trainer's round for
-  ``caffenet_rounds_x4`` (float32, 512 a chip, tau 10).  ``--devices real``
+  ``caffenet_rounds_x4`` (float32, 512 a chip, tau 10), and the token cell's
+  step (``laguna_xs_2_train_8k``: bfloat16, 4 sequences of 8,192 ids) from
+  the shapes of its parameters and Adam's state alone, 11 GB that are never
+  made.  ``--devices real``
   lowers for the chips JAX holds and leaves out a cell that needs more;
   ``described`` lowers for a v5e:2x2 that is described and not attached, with
   trace-time backend checks steered to the chip's branch, and needs no chip.
 
 - ``edges``: the same programs as ``cells``, compiled (``--devices
   described``: by the TPU's compiler for the described chip, about a minute a
-  cell), and read for layout copies at the edges of the LRN epilogue kernels
-  ``relu_lrn_fwd``/``relu_lrn_bwd``: a ``copy`` or ``transpose`` that feeds
+  cell), and read for layout copies at the edges of the Pallas kernels (the
+  LRN epilogue's ``relu_lrn_fwd``/``relu_lrn_bwd``, the attention's
+  ``splash_mqa_fwd_residuals``/``splash_mqa_dkv_no_residuals``/
+  ``splash_mqa_dq_no_residuals``): a ``copy`` or ``transpose`` that feeds
   such a custom call, reads its result, or is attributed to its
   ``pallas_call``.  Prints ``{"edges": {program: {"kernel_calls": {...},
-  "copies": [...], "copy_bytes": n}}}`` and exits 1 if any program holds one;
-  ``--out`` keeps the compiled text.
+  "copies": [...], "copy_bytes": n, "written": {class: bytes}}}}``
+  (``written``: what the program's operations write by class, see
+  ``written_bytes``) and exits 1 if any program holds a copy; ``--out``
+  keeps the compiled text.
 
 Nothing runs, and for ``nets`` and ``cells`` nothing is compiled: equal text
 is the whole criterion there.
@@ -101,7 +108,9 @@ def net_texts() -> dict[str, tuple[str, str]]:
 
 
 CELLS = ("caffenet_train_resident", "googlenet_train_resident",
-         "caffenet_rounds_x4")
+         "caffenet_rounds_x4", "laguna_xs_2_train_8k")
+KERNELS = ("relu_lrn_fwd", "relu_lrn_bwd", "splash_mqa_fwd_residuals",
+           "splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals")
 
 
 def cell_lowered(described: bool, names) -> dict[str, tuple[object, str]]:
@@ -135,6 +144,10 @@ def cell_lowered(described: bool, names) -> dict[str, tuple[object, str]]:
                   f"here: left out", file=sys.stderr)
             continue
         driver = harness.load_driver(cell.mix).Driver(cell)
+        if cell.mix["driver"] == "solver_tokens":
+            lowereds[f"{name}:Solver._step"] = (
+                _token_step_lowered(driver, devices[0], struct), "off")
+            continue
         raw = driver.raw_shape()
         if cell.mix["driver"] == "solver_steps":
             one = SingleDeviceSharding(devices[0])
@@ -180,25 +193,69 @@ def cell_lowered(described: bool, names) -> dict[str, tuple[object, str]]:
     return lowereds
 
 
+def _token_step_lowered(driver, device, struct):
+    """The token cell's step as ``Solver`` builds it, lowered from shapes:
+    its parameters and Adam's moments are 11 GB, so no ``Solver`` is made."""
+    import jax
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+
+    from sparknet_tpu.graph import Net
+    from sparknet_tpu.proto import (NetState, Phase,
+                                    load_solver_prototxt_with_net)
+    from sparknet_tpu.solvers.step import make_step_fns
+    from sparknet_tpu.solvers.update_rules import make_update_rule
+
+    one = SingleDeviceSharding(device)
+    sp = load_solver_prototxt_with_net(
+        driver.mix["solver"], driver.net_for(driver.batch, driver.positions))
+    net = Net(sp.net_param, NetState(Phase.TRAIN),
+              compute_dtype=driver._compute_dtype())
+    key = jax.ShapeDtypeStruct((2,), np.uint32)
+    rule = make_update_rule(sp)
+    params = jax.eval_shape(net.init, key)
+    state = jax.eval_shape(rule.init, params)
+    _, step, _ = make_step_fns(sp, net, rule, net.lr_mult_tree(params),
+                               net.decay_mult_tree(params))
+    tokens = jax.ShapeDtypeStruct(driver.raw_shape(1, driver.batch),
+                                  np.int32, sharding=one)
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
+        struct(params, one), struct(state, one), 0, {"tokens": tokens},
+        struct(key, one))
+
+
 _INSTR = re.compile(
     r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
 _MOVERS = {"copy", "transpose"}
 _SEE_THROUGH = {"bitcast", "get-tuple-element", "reshape", "tuple"}
 
 
-def _bytes(shape: str) -> int:
-    """Bytes of the first array in an HLO shape string."""
-    m = re.search(r"(pred|[su]\d+|bf16|f16|f32|f64)\[([\d,]*)\]", shape)
-    if not m:
-        return 0
-    n = 1 if m.group(1) == "pred" else int(re.sub(r"\D", "",
-                                                 m.group(1))) // 8
-    for d in filter(None, m.group(2).split(",")):
-        n *= int(d)
-    return n
+_ARRAY = re.compile(r"(pred|[su]\d+|bf16|f16|f32|f64)\[([\d,]*)\]")
 
 
-def kernel_edge_copies(hlo: str, kernels=("relu_lrn_fwd", "relu_lrn_bwd")):
+def _bytes(shape: str, every: bool = False) -> int:
+    """Bytes of the first array in an HLO shape string, or of ``every``
+    array of a tuple's."""
+    total = 0
+    for m in _ARRAY.finditer(shape):
+        n = 1 if m.group(1) == "pred" else int(re.sub(r"\D", "",
+                                                     m.group(1))) // 8
+        for d in filter(None, m.group(2).split(",")):
+            n *= int(d)
+        if not every:
+            return n
+        total += n
+    return total
+
+
+def _one_line_each(hlo: str) -> str:
+    """``hlo`` with every instruction on one line: a Pallas call and the
+    copies attributed to it carry the kernel's metadata over three."""
+    return re.sub(r"frontend_attributes=\{kernel_metadata=\{\n.*?\n\}\},? ?",
+                  "", hlo, flags=re.S)
+
+
+def kernel_edge_copies(hlo: str, kernels=KERNELS):
     """The layout copies at the edges of the named Pallas kernels in a
     compiled module's text: every ``copy``/``transpose`` (alone or as the
     whole of a fusion) that feeds such a custom call or reads its result,
@@ -208,7 +265,7 @@ def kernel_edge_copies(hlo: str, kernels=("relu_lrn_fwd", "relu_lrn_bwd")):
     of ``{"kernel", "edge", "name", "shape", "bytes"}``."""
     instrs, users, fused = {}, collections.defaultdict(list), {}
     comp = None
-    for line in hlo.splitlines():
+    for line in _one_line_each(hlo).splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
         if head:
             comp = head.group(1)
@@ -278,9 +335,77 @@ def kernel_edge_copies(hlo: str, kernels=("relu_lrn_fwd", "relu_lrn_bwd")):
         for c, (k, edge) in sorted(found.items())]
 
 
+_PLUMBING = {"parameter", "tuple", "get-tuple-element", "bitcast", "constant",
+             "while", "conditional", "call", "after-all", "partition-id",
+             "replica-id", "opt-barrier"}
+_PRODUCTS = {"convolution", "dot"}
+
+
+def written_bytes(hlo: str):
+    """What a compiled module's operations write, by class: every
+    instruction of the entry computation and of the loops' bodies it
+    reaches (a loop's body once, whatever its trip count), a fusion as the
+    one operation it is.  ``product`` is a convolution or dot, alone or
+    inside a fusion; ``kernel`` a custom call; ``async`` the start and
+    done halves of a copy or slice between memory spaces; ``other``
+    everything else that makes an array.  Returns ``(by_class, ops)``:
+    bytes by class, and ``{"name", "opcode", "class", "shape", "bytes",
+    "fused", "op_name"}`` an operation, ``fused`` the opcodes inside a
+    fusion."""
+    comps, comp = {}, None
+    for line in _one_line_each(hlo).splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(2)
+            comps[comp] = {"entry": bool(head.group(1)), "instrs": []}
+            continue
+        m = _INSTR.match(line)
+        if m and comp is not None:
+            name, shape, opcode, rest = m.groups()
+            comps[comp]["instrs"].append((name, shape, opcode, rest))
+
+    def reached(comp, seen):
+        seen.add(comp)
+        for _, _, opcode, rest in comps[comp]["instrs"]:
+            if opcode in ("while", "conditional", "call"):
+                for callee in re.findall(r"[=,{] ?%?([\w.\-]+)",
+                                         rest.split("), ", 1)[-1]):
+                    if callee in comps and callee not in seen:
+                        reached(callee, seen)
+
+    top = set()
+    for name, c in comps.items():
+        if c["entry"]:
+            reached(name, top)
+    by_class, ops = collections.Counter(), []
+    for comp in sorted(top):
+        for name, shape, opcode, rest in comps[comp]["instrs"]:
+            if opcode in _PLUMBING:
+                continue
+            calls = re.search(r"calls=%?([\w.\-]+)", rest)
+            fused = sorted({o for _, _, o, _ in comps.get(
+                calls.group(1), {"instrs": []})["instrs"]}
+                - {"parameter"}) if opcode == "fusion" and calls else []
+            if opcode in _PRODUCTS or _PRODUCTS & set(fused):
+                kind = "product"
+            elif opcode == "custom-call":
+                kind = "kernel"
+            elif opcode.endswith(("-start", "-done")):
+                kind = "async"
+            else:
+                kind = "other"
+            n = _bytes(shape, every=True)
+            by_class[kind] += n
+            op_name = re.search(r'op_name="([^"]*)"', rest)
+            ops.append({"name": name, "opcode": opcode, "class": kind,
+                        "shape": shape, "bytes": n, "fused": fused,
+                        "op_name": op_name.group(1) if op_name else ""})
+    return dict(by_class), ops
+
+
 def edges(args) -> int:
     """``--set edges``: compile the cells' programs and list the layout
-    copies at the edges of the LRN epilogue kernels; exit 1 if any."""
+    copies at the edges of the Pallas kernels; exit 1 if any."""
     import jax
     report = {}
     for name, (lowered, _) in cell_lowered(
@@ -293,7 +418,8 @@ def edges(args) -> int:
                 f.write(hlo)
         calls, copies = kernel_edge_copies(hlo)
         report[name] = {"kernel_calls": calls, "copies": copies,
-                        "copy_bytes": sum(c["bytes"] for c in copies)}
+                        "copy_bytes": sum(c["bytes"] for c in copies),
+                        "written": written_bytes(hlo)[0]}
     print(json.dumps({"root": args.root, "backend": jax.default_backend(),
                       "devices": args.devices, "edges": report}),
           flush=True)
