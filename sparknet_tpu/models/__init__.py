@@ -21,3 +21,4 @@ from .alexnet import alexnet, caffenet
 from .googlenet import googlenet
 from .vgg import vgg16
 from .laguna import laguna
+from .lfm2 import lfm2

@@ -52,6 +52,11 @@ def layer(name: str, type: str, bottoms: Sequence[str] = (),
     return lp
 
 
+def gaussian(std: float) -> dict:
+    """A Gaussian filler of that spread, as a layer's ``*_filler``."""
+    return {"type": "gaussian", "std": std}
+
+
 def net_param(name: str, layers: Sequence[LayerParameter]) -> NetParameter:
     """NetParam (reference: Layers.scala:130-137)."""
     return NetParameter(name=name, layer=list(layers))

@@ -17,13 +17,9 @@ the CPU tests pass small widths.  What the configuration does not say
 from __future__ import annotations
 
 from ..proto.caffe_pb import NetParameter, Phase
-from .dsl import java_data_layer, layer, net_param
+from .dsl import gaussian, java_data_layer, layer, net_param
 
 _PERIOD = ("full", "sliding", "sliding", "sliding")
-
-
-def _gaussian(std: float) -> dict:
-    return {"type": "gaussian", "std": std}
 
 
 def laguna(train_batch: int = 4, test_batch: int = 1, *,
@@ -62,7 +58,7 @@ def laguna(train_batch: int = 4, test_batch: int = 1, *,
                         (test_batch, seq_len)),
         layer("embed", "Embed", ["tokens"], ["x0"], embed_param={
             "num_output": hidden, "input_dim": vocab, "bias_term": False,
-            "weight_filler": _gaussian(embed_std)}),
+            "weight_filler": gaussian(embed_std)}),
     ]
     x = "x0"
     for i in range(num_layers):
@@ -75,7 +71,7 @@ def laguna(train_batch: int = 4, test_batch: int = 1, *,
                   attention_param={
                       "num_heads": heads[kind], "num_kv_heads": kv_heads,
                       "head_dim": head_dim, **by_kind[kind],
-                      "weight_filler": _gaussian(std)}),
+                      "weight_filler": gaussian(std)}),
             layer(f"{p}/res1", "Eltwise", [x, f"{p}/a"], [f"{p}/h"]),
             layer(f"{p}/norm2", "RMSNorm", [f"{p}/h"], [f"{p}/n2"], **norm),
         ]
@@ -83,7 +79,7 @@ def laguna(train_batch: int = 4, test_batch: int = 1, *,
             layers.append(layer(
                 f"{p}/mlp", "GatedMLP", [f"{p}/n2"], [f"{p}/m"],
                 gated_mlp_param={"width": dense_width,
-                                 "weight_filler": _gaussian(std)}))
+                                 "weight_filler": gaussian(std)}))
         else:
             layers.append(layer(
                 f"{p}/moe", "MixtureOfExperts", [f"{p}/n2"], [f"{p}/m"],
@@ -94,8 +90,8 @@ def laguna(train_batch: int = 4, test_batch: int = 1, *,
                     "expert_width": expert_width,
                     "shared_width": shared_width,
                     "routed_scaling": routed_scaling,
-                    "weight_filler": _gaussian(std),
-                    "router_filler": _gaussian(router_std),
+                    "weight_filler": gaussian(std),
+                    "router_filler": gaussian(router_std),
                     "router_column_norm": router_std * hidden ** 0.5,
                     "detach_router": not train_router}))
         x = f"x{i + 1}"
@@ -103,7 +99,7 @@ def laguna(train_batch: int = 4, test_batch: int = 1, *,
                             [x]))
     head = layer("lm_loss", "LMHeadLoss", ["xf", "tokens"], ["loss"],
                  lm_head_param={"vocab": vocab,
-                                "weight_filler": _gaussian(std)})
+                                "weight_filler": gaussian(std)})
     head.loss_weight = [1.0]
     layers += [
         layer("final_norm", "RMSNorm", [x], ["xf"],

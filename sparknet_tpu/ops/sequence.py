@@ -1,5 +1,5 @@
-"""Sequence layers: RMSNorm, Attention, GatedMLP, MixtureOfExperts,
-LMHeadLoss.
+"""Sequence layers: RMSNorm, Attention, ShortConv, GatedMLP,
+MixtureOfExperts, LMHeadLoss.
 
 The layer types a decoder-only language model needs beside ``Embed`` and
 ``Eltwise`` (ROADMAP R3): blobs are ``[sequences, positions, width]`` and
@@ -27,7 +27,8 @@ once a trace (``attn_lowering_total{path}``, ``moe_lowering_total{path}``):
   ``attn_core``): on a TPU, where positions and head size fit its blocks,
   JAX's block-sparse flash kernels (``splash_attention``: ``path=splash``),
   which skip the blocks a causal or window mask empties and never hold a
-  score matrix; elsewhere masked scores in XLA (``path=xla``);
+  score matrix, at heads of 128 and of 64 alike; elsewhere masked scores
+  in XLA (``path=xla``), which a TPU refuses where they would not fit;
 - the experts' products (scope ``moe_experts``): rows sorted by expert and
   multiplied group by group, on a TPU by JAX's ``megablox`` grouped matrix
   kernels (``path=gmm``), elsewhere by ``jax.lax.ragged_dot``
@@ -68,6 +69,14 @@ def _swiglu(gate, up):
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
+def _rms_norm(x, w, eps: float):
+    """``x * rsqrt(mean(x^2, last axis) + eps) * w`` in float32."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps)
+            * w.astype(jnp.float32)).astype(x.dtype)
+
+
 @register_layer("RMSNorm")
 class RMSNormLayer(LayerImpl):
     """``x * rsqrt(mean(x^2, last axis) + eps) * weight``, in float32."""
@@ -77,13 +86,7 @@ class RMSNormLayer(LayerImpl):
 
     def apply(self, lp, params, bottoms, train, rng):
         eps = float(lp.sub("rms_norm_param").get("eps", 1e-6))
-
-        def norm(x, w):
-            x32 = x.astype(jnp.float32)
-            ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-            return (x32 * jax.lax.rsqrt(ms + eps)
-                    * w.astype(jnp.float32)).astype(x.dtype)
-
+        norm = functools.partial(_rms_norm, eps=eps)
         return [jax.checkpoint(norm)(bottoms[0], params[0])]
 
 
@@ -203,12 +206,28 @@ def _splash_kernel(s: int, group: int, window: int):
             sm.MultiHeadMask([one] * group), block_sizes=sizes)
 
 
-def attn_lowering(positions: int, head_dim: int) -> str:
+# float32 scores the masked-scores lowering may hold for one sequence on a
+# chip: a sixteenth of its 16 GB
+_XLA_SCORE_BYTES = 1 << 30
+
+
+def attn_lowering(positions: int, head_dim: int, heads: int = 1) -> str:
     """Which lowering the attention core takes at these sizes on this
-    backend; counted in ``attn_lowering_total``."""
-    splash = (jax.default_backend() == "tpu"
-              and positions % _SPLASH_BLOCK == 0 and head_dim % 128 == 0)
+    backend; counted in ``attn_lowering_total``.  The flash kernels take a
+    head of 64 as it is (half a lane row a head: the kernels pad their own
+    scratch, nothing is padded here).  On a TPU the masked scores are
+    refused, not taken, where one sequence's would not fit."""
+    tpu = jax.default_backend() == "tpu"
+    splash = (tpu and positions % _SPLASH_BLOCK == 0 and head_dim % 64 == 0)
     path = "splash" if splash else "xla"
+    if tpu and not splash and (4 * heads * positions * positions
+                               > _XLA_SCORE_BYTES):
+        raise ValueError(
+            f"attention over {positions} positions with heads of "
+            f"{head_dim} fits no flash kernel (positions in blocks of "
+            f"{_SPLASH_BLOCK}, heads of a multiple of 64), and its masked "
+            f"scores ({heads} x {positions} x {positions} float32) do not "
+            f"fit the chip")
     telemetry.get_registry().counter(
         "attn_lowering_total",
         "traces of the attention core, by lowering").inc(path=path)
@@ -237,16 +256,20 @@ def attn_core(q, k, v, window: int, path: str):
 
 @register_layer("Attention")
 class AttentionLayer(LayerImpl):
-    """Gated grouped-query self-attention with rotary positions
+    """Grouped-query self-attention with rotary positions
     (``attention_param``): ``num_heads`` query heads share
     ``num_kv_heads`` key/value heads of ``head_dim``; causal, and with
     ``window`` w a key j is seen from i only if ``0 <= i - j < w``; the
     first ``rotary_dim`` dimensions of each head are rotated (``rope_theta``;
     ``yarn_factor``, ``yarn_original_length``, ``yarn_beta_fast``,
     ``yarn_beta_slow`` for YaRN; ``rope_attention_factor`` on cos and
-    sin); scores are scaled by ``1/sqrt(head_dim)``; each head's output is
-    multiplied by ``sigmoid(x W_g)``, one scalar a head a token, before
-    the output projection.  Blobs: W_q, W_k, W_v, W_g, W_o; no bias."""
+    sin); scores are scaled by ``1/sqrt(head_dim)``.  With ``gate`` (the
+    default) each head's output is multiplied by ``sigmoid(x W_g)``, one
+    scalar a head a token, before the output projection.  With ``qk_norm``
+    each head of q and of k is RMS-normalised (``qk_norm_eps``) before it
+    is rotated, by one weight of ``head_dim`` for q and one for k.  Blobs:
+    W_q, W_k, W_v, W_g if gated, W_o, then the q and k norm weights if
+    normalised; no bias."""
 
     def _geom(self, lp):
         p = lp.sub("attention_param")
@@ -255,6 +278,9 @@ class AttentionLayer(LayerImpl):
             heads=int(p.get("num_heads", 0)),
             kv=int(p.get("num_kv_heads", 0)), d=d,
             window=int(p.get("window", 0)),
+            gate=bool(p.get("gate", True)),
+            qk_norm=bool(p.get("qk_norm", False)),
+            qk_eps=float(p.get("qk_norm_eps", 1e-6)),
             factor=float(p.get("rope_attention_factor", 1.0)),
             inv_freq=rope_inv_freq(
                 int(p.get("rotary_dim", d)), float(p.get("rope_theta", 1e4)),
@@ -269,36 +295,103 @@ class AttentionLayer(LayerImpl):
         wf = _filler(lp.sub("attention_param"))
         q, kvw = g["heads"] * g["d"], g["kv"] * g["d"]
         shapes = [(hidden, q), (hidden, kvw), (hidden, kvw),
-                  (hidden, g["heads"]), (q, hidden)]
-        return [fill(r, wf, s)
-                for r, s in zip(jax.random.split(rng, 5), shapes)]
+                  *([(hidden, g["heads"])] if g["gate"] else []),
+                  (q, hidden)]
+        # two arrays, not one twice: a step donates each blob's buffer
+        norms = [jnp.ones((g["d"],), jnp.float32)
+                 for _ in range(2 * g["qk_norm"])]
+        return [fill(r, wf, s) for r, s in zip(jax.random.split(rng, 5),
+                                               shapes)] + norms
 
     def apply(self, lp, params, bottoms, train, rng):
         g = self._geom(lp)
         heads, kv, d = g["heads"], g["kv"], g["d"]
-        wq, wk, wv, wg, wo = params
-        path = attn_lowering(bottoms[0].shape[-2], d)
+        blobs = iter(params)
+        wq, wk, wv = next(blobs), next(blobs), next(blobs)
+        wg = next(blobs) if g["gate"] else None
+        wo = next(blobs)
+        q_norm, k_norm = ((next(blobs), next(blobs)) if g["qk_norm"]
+                          else (None, None))
+        path = attn_lowering(bottoms[0].shape[-2], d, heads)
 
         hidden, group = wq.shape[0], heads // kv
         # the query heads grouped by their key/value head, as the kernels
         # take them: no tensor between the products is reshaped
         wq = wq.reshape(hidden, kv, group, d)
         wk, wv = wk.reshape(hidden, kv, d), wv.reshape(hidden, kv, d)
-        wg = wg.reshape(hidden, kv, group)
+        if wg is not None:
+            wg = wg.reshape(hidden, kv, group)
         wo = wo.reshape(kv, group, d, hidden)
 
         def one(x):
-            q = apply_rope(_heads_major(jnp.einsum("sh,hkgd->kgsd", x, wq)),
-                           g["inv_freq"], g["factor"], scale=d ** -0.5)
-            k = apply_rope(_heads_major(jnp.einsum("sh,hkd->ksd", x, wk)),
-                           g["inv_freq"], g["factor"])
+            q = _heads_major(jnp.einsum("sh,hkgd->kgsd", x, wq))
+            if q_norm is not None:
+                q = _rms_norm(q, q_norm, g["qk_eps"])
+            q = apply_rope(q, g["inv_freq"], g["factor"], scale=d ** -0.5)
+            k = _heads_major(jnp.einsum("sh,hkd->ksd", x, wk))
+            if k_norm is not None:
+                k = _rms_norm(k, k_norm, g["qk_eps"])
+            k = apply_rope(k, g["inv_freq"], g["factor"])
             v = _heads_major(jnp.einsum("sh,hkd->ksd", x, wv))
             out = attn_core(q, k, v, g["window"], path)
-            gate = jax.nn.sigmoid(
-                jnp.einsum("sh,hkg->kgs", x, wg).astype(jnp.float32))
-            out = _heads_major((out.astype(jnp.float32) * gate[..., None])
-                               .astype(x.dtype))
-            return jnp.einsum("kgsd,kgdh->sh", out, wo)
+            if wg is not None:
+                gate = jax.nn.sigmoid(
+                    jnp.einsum("sh,hkg->kgs", x, wg).astype(jnp.float32))
+                out = (out.astype(jnp.float32)
+                       * gate[..., None]).astype(x.dtype)
+            return jnp.einsum("kgsd,kgdh->sh", _heads_major(out), wo)
+
+        return [_per_sequence(one, bottoms[0])]
+
+
+# -- short convolution --------------------------------------------------------
+
+def _conv_mix(b, c, x, taps):
+    """``c * conv(b * x)``: the part of the gated short convolution that is
+    no matrix product, in float32 inside and the operands' dtype out.
+    ``b, c, x [positions, width]``; ``taps [width, kernel]``, tap ``kernel -
+    1`` on the position itself and tap ``kernel - 1 - j`` on the one ``j``
+    before it, zeros before the sequence.  The shift is along the
+    positions (the sublanes) and a sequence is whole here, so no halo."""
+    s, kernel = x.shape[0], taps.shape[1]
+    u = b.astype(jnp.float32) * x.astype(jnp.float32)
+    w = taps.astype(jnp.float32)
+    acc = u * w[:, kernel - 1]
+    for j in range(1, kernel):
+        acc = acc + jnp.pad(u, ((j, 0), (0, 0)))[:s] * w[:, kernel - 1 - j]
+    return (c.astype(jnp.float32) * acc).astype(x.dtype)
+
+
+@register_layer("ShortConv")
+class ShortConvLayer(LayerImpl):
+    """Double-gated short convolution over the positions
+    (``short_conv_param``): ``[B, C, x] = h W_in`` in that order, ``W_in:
+    hidden -> 3 x hidden``; ``u = B * x``; a depthwise causal convolution
+    of ``kernel`` taps over the positions of ``u``; ``(C * conv) W_out``.
+    No activation, no bias.  Blobs: W_in ``[hidden, 3, hidden]`` (the three
+    thirds are three outputs of one product: none is cut out of a
+    ``[positions, 3 x hidden]`` array), the taps ``[hidden, kernel]``
+    (``kernel_filler``), W_out ``[hidden, hidden]``.  A sequence at a
+    time, recomputed in the backward pass; what lies between the two
+    products runs under the scope ``conv_mix``, as XLA fuses it on every
+    backend."""
+
+    def init(self, rng, lp, bottom_shapes):
+        p = lp.sub("short_conv_param")
+        hidden, kernel = bottom_shapes[0][-1], int(p.get("kernel", 3))
+        r = jax.random.split(rng, 3)
+        return [fill(r[0], _filler(p), (hidden, 3, hidden)),
+                fill(r[1], _filler(p, "kernel_filler"), (hidden, kernel)),
+                fill(r[2], _filler(p), (hidden, hidden))]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        w_in, taps, w_out = params
+
+        def one(h):
+            b, c, x = _heads_major(jnp.einsum("sh,hjo->jso", h, w_in))
+            with jax.named_scope("conv_mix"):
+                mixed = _heads_major(_conv_mix(b, c, x, taps))
+            return mixed @ w_out
 
         return [_per_sequence(one, bottoms[0])]
 
@@ -331,6 +424,9 @@ def moe_geometry(lp) -> dict:
                 lo=int(p.get("experts_held_lo", 0)),
                 hi=int(p.get("experts_held_hi", p.get("num_experts", 0))),
                 scaling=float(p.get("routed_scaling", 1.0)),
+                eps=float(p.get("norm_eps", 0.0)),
+                shared=int(p.get("shared_width", 0)),
+                select_bias=bool(p.get("select_bias", False)),
                 detached=bool(p.get("detach_router", False)))
 
 
@@ -347,21 +443,31 @@ def moe_row_bound(tokens: int, g: dict) -> int:
     return min(most, -(-math.ceil(1.25 * even) // _GMM_ROWS) * _GMM_ROWS)
 
 
-def moe_route(x, w_router, g: dict):
+def moe_route(x, w_router, g: dict, bias=None):
     """Route ``x [tokens, hidden]`` over all the experts and list the rows
     the held ones compute.  Scores are ``sigmoid(x W_r)`` in float32; a
-    token takes its ``top_k`` largest (the lower index on a tie) with
-    weights normalised to sum 1, times ``scaling``.  Returns (token index,
-    weight, rows of each held expert as sized for the products) of the
-    ``moe_row_bound`` rows sorted by expert, then (rows each held expert
-    was sent, rows left out because the bound bound)."""
+    token takes the ``top_k`` experts with the largest score, or with
+    ``bias [experts]`` the largest ``score + bias`` (the lower index on a
+    tie); the weights are the chosen experts' scores, without the bias,
+    over their sum plus ``g["eps"]``, times ``scaling``.  Returns (token
+    index, weight, rows of each held expert as sized for the products) of
+    the ``moe_row_bound`` rows sorted by expert, then (rows each held
+    expert was sent, rows left out because the bound bound)."""
     tokens, held, k = x.shape[0], g["hi"] - g["lo"], g["top_k"]
     if g.get("detached"):
         x = jax.lax.stop_gradient(x)
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), k)
-    weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * g["scaling"]
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    total = jnp.sum(top_s, axis=-1, keepdims=True)
+    if g.get("eps"):
+        total = total + g["eps"]
+    weight = top_s / total * g["scaling"]
     here = (top_i >= g["lo"]) & (top_i < g["hi"])
     key = jnp.where(here, top_i - g["lo"], held).reshape(-1)
     rows = moe_row_bound(tokens, g)
@@ -396,19 +502,30 @@ def _grouped(rows, w, sizes, path: str):
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     k, n = w.shape[1:]
     return megablox.gmm(rows, w, sizes, rows.dtype,
-                        (_GMM_ROWS, min(k, 1024), min(n, 1024)))
+                        (_GMM_ROWS, _gmm_tile(k), _gmm_tile(n)))
+
+
+def _gmm_tile(width: int) -> int:
+    """The widest tile of whole lane rows, at most 1024, that divides
+    ``width``: no tile of a grouped product hangs over the matrix's edge."""
+    return max(t for t in range(128, min(width, 1024) + 1, 128)
+               if width % t == 0)
 
 
 @register_layer("MixtureOfExperts")
 class MixtureOfExpertsLayer(LayerImpl):
     """A router over ``num_experts`` experts, ``top_k`` a token, and a
-    shared expert (``moe_param``).  The layer holds the experts
-    ``[experts_held_lo, experts_held_hi)``: it routes over all of them,
-    computes the held experts' part for the tokens routed to them, adds the
-    shared expert unweighted, and leaves out what the absent experts would
-    add.  No token is dropped.  Blobs: W_router; the held experts' W_gate,
-    W_up ``[held, hidden, width]`` and W_down ``[held, width, hidden]``;
-    the shared expert's W_gate, W_up, W_down.  ``router_column_norm``, if
+    shared expert of ``shared_width`` if that is not 0 (``moe_param``).
+    The layer holds the experts ``[experts_held_lo, experts_held_hi)``: it
+    routes over all of them, computes the held experts' part for the tokens
+    routed to them, adds the shared expert unweighted, and leaves out what
+    the absent experts would add.  No token is dropped.  Blobs: W_router;
+    the held experts' W_gate, W_up ``[held, hidden, width]`` and W_down
+    ``[held, width, hidden]``; the shared expert's W_gate, W_up, W_down if
+    there is one; with ``select_bias`` a bias ``[num_experts]`` last
+    (``select_bias_filler``), added to the scores where the experts are
+    chosen and nowhere else.  ``norm_eps`` is added to the sum the chosen
+    scores are divided by.  ``router_column_norm``, if
     given, scales each column of the filled router to that length.
     ``detach_router`` keeps the scores' gradient from the layer's input
     (the router's own weights still get theirs): for a layer that holds a
@@ -417,12 +534,12 @@ class MixtureOfExpertsLayer(LayerImpl):
     def init(self, rng, lp, bottom_shapes):
         p, g = lp.sub("moe_param"), moe_geometry(lp)
         hidden, held = bottom_shapes[0][-1], g["hi"] - g["lo"]
-        width = int(p.get("expert_width", 0))
-        shared = int(p.get("shared_width", 0))
+        width, shared = int(p.get("expert_width", 0)), g["shared"]
         wf = _filler(p)
         shapes = [(held, hidden, width), (held, hidden, width),
-                  (held, width, hidden), (hidden, shared), (hidden, shared),
-                  (shared, hidden)]
+                  (held, width, hidden)]
+        if shared:
+            shapes += [(hidden, shared), (hidden, shared), (shared, hidden)]
         r = jax.random.split(rng, 7)
         router = fill(r[0], _filler(p, "router_filler"),
                       (hidden, g["experts"]))
@@ -433,7 +550,12 @@ class MixtureOfExpertsLayer(LayerImpl):
             # more often, which at hidden 2048 is 7% of load from the
             # filler alone
             router = router * (norm / jnp.linalg.norm(router, axis=0))
-        return [router] + [fill(ri, wf, s) for ri, s in zip(r[1:], shapes)]
+        blobs = [router] + [fill(ri, wf, s) for ri, s in zip(r[1:], shapes)]
+        if g["select_bias"]:
+            blobs.append(fill(jax.random.fold_in(rng, 7),
+                              _filler(p, "select_bias_filler"),
+                              (g["experts"],)))
+        return blobs
 
     def apply(self, lp, params, bottoms, train, rng):
         g = moe_geometry(lp)
@@ -441,9 +563,10 @@ class MixtureOfExpertsLayer(LayerImpl):
         path = moe_lowering(moe_row_bound(math.prod(shape[:-1]), g),
                             shape[-1], params[1].shape[-1])
 
-        def moe(x, wr, eg, eu, ed, sg, su, sd):
+        def moe(x, wr, eg, eu, ed, *rest):
+            bias = rest[-1] if g["select_bias"] else None
             with jax.named_scope("moe_route"):
-                token, w, sized, _, _ = moe_route(x, wr, g)
+                token, w, sized, _, _ = moe_route(x, wr, g, bias)
                 rows = x[token]
             with jax.named_scope("moe_experts"):
                 y = _grouped(_swiglu(_grouped(rows, eg, sized, path),
@@ -452,6 +575,9 @@ class MixtureOfExpertsLayer(LayerImpl):
             with jax.named_scope("moe_route"):
                 y = y.astype(jnp.float32) * w[:, None]
                 routed = jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+            if not g["shared"]:
+                return routed.astype(x.dtype)
+            sg, su, sd = rest[:3]
             return (routed + (_swiglu(x @ sg, x @ su) @ sd)).astype(x.dtype)
 
         out = jax.checkpoint(moe)(bottoms[0].reshape(-1, shape[-1]), *params)
@@ -471,10 +597,11 @@ def moe_load(net, params, inputs) -> dict:
         blobs = net.apply_all(params, one, train=True)
         out = {}
         for n in nodes:
-            x = blobs[n.bottoms[0]]
+            x, g, own = blobs[n.bottoms[0]], moe_geometry(n.lp), params[
+                n.lp.name]
             _, _, _, sent, dropped = moe_route(
-                x.reshape(-1, x.shape[-1]),
-                params[n.lp.name][0].astype(x.dtype), moe_geometry(n.lp))
+                x.reshape(-1, x.shape[-1]), own[0].astype(x.dtype), g,
+                own[-1] if g["select_bias"] else None)
             out[n.lp.name] = {"rows": sent, "dropped": dropped}
         return out
 
@@ -503,7 +630,9 @@ class LMHeadLossLayer(LayerImpl):
     hidden states and the token ids; the loss is the mean softmax
     cross-entropy of position t's logits (``x W``, float32 out of the
     product on) against token t+1.  A second top, if named, is the logits.
-    Blob: W ``[hidden, vocab]``, no bias.
+    Blob: W ``[hidden, vocab]``, no bias; with ``lm_head_param.transposed``
+    it is stored ``[vocab, hidden]``, the shape of an ``Embed`` table, so
+    that the two can be one blob (``ParamSpec.name``: a tied head).
 
     The product runs in the net's compute dtype like any other layer's and
     the softmax in float32 here, so the layer does not ask for the loss
@@ -526,13 +655,17 @@ class LMHeadLossLayer(LayerImpl):
 
     def init(self, rng, lp, bottom_shapes):
         p = lp.sub("lm_head_param")
-        return [fill(rng, _filler(p),
-                     (bottom_shapes[0][-1], int(p.get("vocab", 0))))]
+        shape = (bottom_shapes[0][-1], int(p.get("vocab", 0)))
+        if p.get("transposed", False):
+            shape = shape[::-1]
+        return [fill(rng, _filler(p), shape)]
 
     def apply(self, lp, params, bottoms, train, rng):
         (w,) = params
         hidden, tokens = bottoms
         want_logits = len(lp.top) > 1
+        if lp.sub("lm_head_param").get("transposed", False):
+            w = w.T
 
         def one(x, ids):
             logits = jnp.dot(x, w, preferred_element_type=jnp.float32)

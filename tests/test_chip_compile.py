@@ -313,3 +313,80 @@ def test_expert_layer_compiles_at_real_widths(one_chip, chip_branch):
         bf16(jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16))
     ).compile().as_text()
     assert text.count("tpu_custom_call") >= 9      # 3 products x 3 passes
+
+
+# -- what LFM2-24B-A2B adds, at its published widths ---------------------------
+
+def _lfm2_layer(name, sequences=1):
+    from sparknet_tpu import models
+    from sparknet_tpu.ops import get_layer_impl
+    net = models.lfm2(sequences, 1, layers_kept=[2, 3], vocab=128,
+                      experts_held=(0, 8))
+    lp = next(l for l in net.layer if l.name == name)
+    impl = get_layer_impl(lp.type)
+    shapes = jax.eval_shape(
+        lambda r: impl.init(r, lp, [(sequences, 8192, 2048)]),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return lp, impl, shapes
+
+
+def _layer_gradient(one_chip, lp, impl, shapes, sequences=1):
+    bf16 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                          sharding=one_chip)
+
+    def loss(params, x):
+        return jnp.sum(impl.apply(lp, params, [x], True, None)[0]
+                       .astype(jnp.float32))
+
+    return jax.jit(jax.grad(loss, (0, 1))).lower(
+        [bf16(s) for s in shapes],
+        bf16(jax.ShapeDtypeStruct((sequences, 8192, 2048), jnp.bfloat16))
+    ).compile()
+
+
+def test_attention_at_head_dim_64_takes_the_flash_kernels(one_chip,
+                                                          chip_branch):
+    """32 query heads over 8 key/value heads of 64, q and k normalised, no
+    gate: Mosaic takes JAX's flash kernels at half a lane row a head,
+    forward and both backward ones, and no score matrix is in the program
+    (one sequence's would be 8.6 GB)."""
+    lp, impl, shapes = _lfm2_layer("L2/attn")
+    assert [s.shape for s in shapes] == [
+        (2048, 2048), (2048, 512), (2048, 512), (2048, 2048), (64,), (64,)]
+    compiled = _layer_gradient(one_chip, lp, impl, shapes)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_short_conv_cuts_no_third_out_of_the_lanes(one_chip, chip_branch,
+                                                   lowered_text):
+    """The gated short convolution's backward pass of one sequence: the
+    three thirds of ``h W_in`` are one product's output ``[3, positions,
+    hidden]`` with hidden on the lanes, nothing slices or concatenates a
+    ``[positions, 3 x hidden]`` array, and what is written outside the
+    products stays under 0.7 GB a sequence (the least is 0.10 GB: XLA
+    keeps float32 intermediates between its three fusions)."""
+    lp, impl, shapes = _lfm2_layer("L3/conv")
+    assert [s.shape for s in shapes] == [(2048, 3, 2048), (2048, 3),
+                                         (2048, 2048)]
+    text = _layer_gradient(one_chip, lp, impl, shapes).as_text()
+    written, ops = lowered_text.written_bytes(text)
+    assert written["product"] > 0 and written["other"] <= 0.7e9
+    assert "bf16[3,8192,2048]{2,1,0" in text
+    wide = [o["name"] for o in ops if "8192,6144" in o["shape"]]
+    assert wide == []
+
+
+def test_expert_layer_of_lfm2_compiles_at_real_widths(one_chip,
+                                                      chip_branch):
+    """32,768 tokens through a layer holding 8 of 64 experts of 1536, top-4
+    by score plus bias, no shared expert: grouped kernels in tiles that
+    divide 1536, sized for a quarter over the even 16,384 rows."""
+    from sparknet_tpu.ops import sequence
+    lp, impl, shapes = _lfm2_layer("L2/moe", sequences=4)
+    assert sequence.moe_row_bound(32768, sequence.moe_geometry(lp)) == 20480
+    assert [s.shape for s in shapes] == [
+        (2048, 64), (8, 2048, 1536), (8, 2048, 1536), (8, 1536, 2048),
+        (64,)]
+    text = _layer_gradient(one_chip, lp, impl, shapes, 4).as_text()
+    assert text.count("tpu_custom_call") >= 9      # 3 products x 3 passes

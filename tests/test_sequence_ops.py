@@ -397,3 +397,244 @@ def test_head_loss_against_reference(with_logits):
     want = highest(jax.grad(reference, (0, 1)))(w, x)
     close(got[0], want[0], 1e-4)
     close(got[1], want[1], 1e-4)
+
+
+# -- what LFM2 adds (reference: benchmark/lib/reference_lfm2.py) -----------------
+
+from benchmark.lib import reference_lfm2 as ref2  # noqa: E402
+
+
+def short_conv_lp(kernel=3):
+    return layer("conv", "ShortConv", ["x"], ["y"], short_conv_param={
+        "kernel": kernel, "weight_filler": _GAUSS, "kernel_filler": _GAUSS})
+
+
+@pytest.mark.parametrize("positions", [1, 2, 3, 17])
+def test_short_conv_against_reference(positions):
+    """Forward and gradients, sequences shorter than, as long as and longer
+    than the kernel: the first two positions see zeros before them."""
+    lp = short_conv_lp()
+    impl, params = init_and_apply(lp, (2, positions, HIDDEN))
+    assert [p.shape for p in params] == [(HIDDEN, 3, HIDDEN), (HIDDEN, 3),
+                                         (HIDDEN, HIDDEN)]
+    x = jax.random.normal(jax.random.PRNGKey(30), (2, positions, HIDDEN))
+    system = lambda p, x: impl.apply(lp, p, [x], True, None)[0]
+    reference = lambda p, x: jnp.stack([ref2.short_conv(xi, p) for xi in x])
+    close(system(params, x), highest(reference)(params, x))
+    cot = jax.random.normal(jax.random.PRNGKey(31), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   (0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) * cot),
+                            (0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 1e-4)
+
+
+@pytest.mark.parametrize("kernel", [3, 4])
+def test_short_conv_taps_against_a_loop(kernel):
+    """The order of the thirds (B, C, x) and of the taps, against a loop
+    over positions written from the equations: tap ``kernel - 1`` on the
+    position itself, tap 0 on the one ``kernel - 1`` before, zeros before
+    the sequence."""
+    lp = short_conv_lp(kernel)
+    impl, (w_in, taps, w_out) = init_and_apply(lp, (1, 6, HIDDEN))
+    x = jax.random.normal(jax.random.PRNGKey(32), (1, 6, HIDDEN))
+    got = np.asarray(impl.apply(lp, [w_in, taps, w_out], [x], True,
+                                None)[0][0])
+    h, wi, t, wo = (np.asarray(a, np.float64) for a in
+                    (x[0], w_in, taps, w_out))
+    thirds = h @ wi.reshape(HIDDEN, 3 * HIDDEN)
+    b, c, z = (thirds[:, i * HIDDEN:(i + 1) * HIDDEN] for i in range(3))
+    u = b * z
+    want = np.zeros((6, HIDDEN))
+    for pos in range(6):
+        conv = np.zeros(HIDDEN)
+        for back in range(kernel):
+            if pos - back >= 0:
+                conv += t[:, kernel - 1 - back] * u[pos - back]
+        want[pos] = (c[pos] * conv) @ wo
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def plain_attention_lp(heads, kv, d):
+    return layer("attn", "Attention", ["x"], ["y"], attention_param={
+        "num_heads": heads, "num_kv_heads": kv, "head_dim": d,
+        "rope_theta": 1e6, "gate": False, "qk_norm": True,
+        "qk_norm_eps": 1e-5, "weight_filler": _GAUSS})
+
+
+@pytest.mark.parametrize("heads,kv,d,positions", [(4, 2, 64, 12),
+                                                  (8, 2, 64, 300),
+                                                  (4, 4, 16, 9)])
+def test_ungated_normalised_attention_against_reference(heads, kv, d,
+                                                        positions):
+    """``gate: false`` builds no ``W_g``; ``qk_norm`` normalises each head
+    of q and of k by one weight of ``head_dim`` before rotary; at
+    LFM2's head size of 64 and past one block of the reference's queries."""
+    lp = plain_attention_lp(heads, kv, d)
+    impl, params = init_and_apply(lp, (2, positions, HIDDEN))
+    assert [p.shape for p in params] == [
+        (HIDDEN, heads * d), (HIDDEN, kv * d), (HIDDEN, kv * d),
+        (heads * d, HIDDEN), (d,), (d,)]
+    assert params[4] is not params[5]       # a step donates each buffer
+    kq, kk = jax.random.split(jax.random.PRNGKey(33))
+    params[4] = 1.0 + 0.2 * jax.random.normal(kq, (d,))
+    params[5] = 1.0 + 0.2 * jax.random.normal(kk, (d,))
+    x = jax.random.normal(jax.random.PRNGKey(34), (2, positions, HIDDEN))
+    m = {"heads": heads, "kv_heads": kv, "theta": 1e6, "eps": 1e-5}
+    system = lambda p, x: impl.apply(lp, p, [x], True, None)[0]
+    reference = lambda p, x: jnp.stack([ref2.attention(xi, p, m)
+                                        for xi in x])
+    close(system(params, x), highest(reference)(params, x), 5e-5)
+    cot = jax.random.normal(jax.random.PRNGKey(35), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   (0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) * cot),
+                            (0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 2e-4)
+
+
+@pytest.mark.parametrize("positions,head_dim,heads,want", [
+    (8192, 128, 48, "splash"), (8192, 64, 32, "splash"),
+    (1024, 64, 32, "splash"), (96, 64, 32, "xla"), (8192, 96, 32, None),
+    (8000, 64, 32, None)])
+def test_attention_lowering_on_a_chip(monkeypatch, positions, head_dim,
+                                      heads, want):
+    """On a TPU heads of 64 take the flash kernels like heads of 128, small
+    sequences take the masked scores, and a sequence whose scores would
+    not fit is refused: the masked scores are never taken silently."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if want is None:
+        with pytest.raises(ValueError, match="do not fit the chip"):
+            seq.attn_lowering(positions, head_dim, heads)
+    else:
+        assert seq.attn_lowering(positions, head_dim, heads) == want
+
+
+def test_attention_lowering_off_the_chip_is_the_masked_scores():
+    assert seq.attn_lowering(8192, 64, 32) == "xla"
+
+
+@pytest.mark.parametrize("width,tile", [(2048, 1024), (512, 512),
+                                        (1536, 768), (128, 128)])
+def test_grouped_product_tiles_divide_the_width(width, tile):
+    assert seq._gmm_tile(width) == tile
+
+
+BIAS_GEOM = {"experts": 8, "top_k": 2, "lo": 0, "hi": 8, "scaling": 1.0,
+             "eps": 1e-6}
+
+
+def test_router_chooses_by_score_plus_bias_and_weighs_by_score():
+    """Every token scores expert e at sigmoid(e / 4): without a bias it
+    takes experts 7 and 6; a bias that lifts expert 0 over them makes it
+    take 0 and 7, and the weights are the scores', not score plus bias."""
+    x = jnp.ones((4, HIDDEN))
+    wr = jnp.tile(jnp.arange(8.0)[None, :] / (4 * HIDDEN), (HIDDEN, 1))
+    scores = np.asarray(jax.nn.sigmoid(jnp.arange(8.0) / 4))
+    bias = jnp.zeros(8).at[0].set(0.5)
+    assert scores[0] + 0.5 > scores[7] and scores[0] < scores[6]
+    _, w_plain, _, sent_plain, _ = seq.moe_route(x, wr, BIAS_GEOM)
+    assert np.asarray(sent_plain).tolist() == [0] * 6 + [4, 4]
+    token, w, _, sent, dropped = seq.moe_route(x, wr, BIAS_GEOM, bias)
+    assert np.asarray(sent).tolist() == [4] + [0] * 6 + [4]
+    assert int(dropped) == 0
+    total = scores[0] + scores[7] + 1e-6
+    np.testing.assert_allclose(np.asarray(w)[:4], scores[0] / total,
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(w)[4:], scores[7] / total,
+                               rtol=1e-6)
+    # the reference makes the same choice and gives the same weights
+    weight, chosen = ref2.route(x, wr, bias, {"top_k": 2, "scaling": 1.0})
+    assert np.asarray(chosen).tolist() == [[0, 7]] * 4
+    np.testing.assert_allclose(np.asarray(weight)[0],
+                               [scores[0] / total, scores[7] / total],
+                               rtol=1e-6)
+
+
+def bias_moe_lp(lo, hi, experts=16, top_k=4):
+    return layer("moe", "MixtureOfExperts", ["x"], ["y"], moe_param={
+        "num_experts": experts, "top_k": top_k, "experts_held_lo": lo,
+        "experts_held_hi": hi, "expert_width": 16, "shared_width": 0,
+        "routed_scaling": 1.0, "norm_eps": 1e-6, "select_bias": True,
+        "select_bias_filler": {"type": "gaussian", "std": 0.1},
+        "weight_filler": _GAUSS, "router_filler": _GAUSS,
+        "detach_router": True})
+
+
+@pytest.mark.parametrize("lo,hi", [(0, 4), (4, 8), (0, 16)])
+def test_biased_expert_layer_without_shared_expert(lo, hi):
+    """No shared expert builds no blob for one; the bias is the last blob,
+    changes which experts a token takes (so it is not all zero here) and
+    gets no gradient."""
+    lp = bias_moe_lp(lo, hi)
+    impl, params = init_and_apply(lp, (2, 24, HIDDEN))
+    assert [p.shape for p in params] == [
+        (HIDDEN, 16), (hi - lo, HIDDEN, 16), (hi - lo, HIDDEN, 16),
+        (hi - lo, 16, HIDDEN), (16,)]
+    x = jax.random.normal(jax.random.PRNGKey(36), (2, 24, HIDDEN))
+    m = {"top_k": 4, "held": (lo, hi), "scaling": 1.0,
+         "train_router": False}
+    _, with_bias = ref2.route(x[0], params[0], params[4], m)
+    _, without = ref2.route(x[0], params[0], jnp.zeros(16), m)
+    assert not np.array_equal(np.asarray(with_bias), np.asarray(without))
+    system = lambda p, x: impl.apply(lp, p, [x], True, None)[0]
+    reference = lambda p, x: jnp.stack([ref2.moe(xi, p, m) for xi in x])
+    close(system(params, x), highest(reference)(params, x))
+    cot = jax.random.normal(jax.random.PRNGKey(37), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   (0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) * cot),
+                            (0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 1e-4)
+    assert float(jnp.abs(got[0][4]).max()) == 0.0
+
+
+def test_the_eight_biased_shares_add_up_to_the_uncut_layer():
+    """The share test for a layer that chooses by a bias and has no shared
+    expert: the parts the 8 shares of 2 experts give add up to the uncut
+    reference's layer output."""
+    impl, params = init_and_apply(bias_moe_lp(0, 16), (1, 40, HIDDEN))
+    wr, eg, eu, ed, bias = params
+    x = jax.random.normal(jax.random.PRNGKey(38), (1, 40, HIDDEN))
+    m = {"top_k": 4, "held": (0, 16), "scaling": 1.0}
+    uncut = highest(lambda p, x: ref2.moe(x, p, m))(params, x[0])
+    total = np.zeros_like(np.asarray(uncut))
+    for share in range(8):
+        lo, hi = 2 * share, 2 * share + 2
+        total += np.asarray(impl.apply(
+            bias_moe_lp(lo, hi),
+            [wr, eg[lo:hi], eu[lo:hi], ed[lo:hi], bias], [x], True,
+            None)[0][0])
+    close(total, uncut, 1e-5)
+
+
+def test_transposed_head_is_the_head_on_the_transpose():
+    """``transposed`` stores the head ``[vocab, hidden]``, an ``Embed``
+    table's shape: loss, logits and gradient are the plain head's on the
+    transposed matrix."""
+    vocab = 40
+    param = {"vocab": vocab, "weight_filler": _GAUSS}
+    plain = layer("head", "LMHeadLoss", ["x", "tokens"], ["loss", "logits"],
+                  lm_head_param=param)
+    tied = layer("head", "LMHeadLoss", ["x", "tokens"], ["loss", "logits"],
+                 lm_head_param={**param, "transposed": True})
+    impl = get_layer_impl("LMHeadLoss")
+    (w,) = impl.init(jax.random.PRNGKey(39), tied, [(2, 9, HIDDEN), (2, 9)])
+    assert w.shape == (vocab, HIDDEN)
+    x = jax.random.normal(jax.random.PRNGKey(40), (2, 9, HIDDEN))
+    tokens = jax.random.randint(jax.random.PRNGKey(41), (2, 9), 0, vocab)
+    got = impl.apply(tied, [w], [x, tokens], True, None)
+    want = impl.apply(plain, [w.T], [x, tokens], True, None)
+    close(got[0], want[0], 1e-6)
+    close(got[1], want[1], 1e-6)
+    g_tied = jax.grad(lambda w: impl.apply(tied, [w], [x, tokens], True,
+                                           None)[0])(w)
+    g_plain = jax.grad(lambda w: impl.apply(plain, [w], [x, tokens], True,
+                                            None)[0])(w.T)
+    close(g_tied, g_plain.T, 1e-5)

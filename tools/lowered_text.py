@@ -23,10 +23,10 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   (``benchmark/rehearse.py`` is the model): ``Solver._step`` for
   ``caffenet_train_resident`` (bfloat16, 1,024) and
   ``googlenet_train_resident`` (bfloat16, 256), the trainer's round for
-  ``caffenet_rounds_x4`` (float32, 512 a chip, tau 10), and the token cell's
-  step (``laguna_xs_2_train_8k``: bfloat16, 4 sequences of 8,192 ids) from
-  the shapes of its parameters and Adam's state alone, 11 GB that are never
-  made.  ``--devices real``
+  ``caffenet_rounds_x4`` (float32, 512 a chip, tau 10), and the token cells'
+  steps (``laguna_xs_2_train_8k``, ``lfm2_24b_a2b_train_8k``: bfloat16, 4
+  sequences of 8,192 ids) from the shapes of their parameters and Adam's
+  state alone, 11 and 7.5 GB that are never made.  ``--devices real``
   lowers for the chips JAX holds and leaves out a cell that needs more;
   ``described`` lowers for a v5e:2x2 that is described and not attached, with
   trace-time backend checks steered to the chip's branch, and needs no chip.
@@ -39,10 +39,12 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   ``splash_mqa_dq_no_residuals``): a ``copy`` or ``transpose`` that feeds
   such a custom call, reads its result, or is attributed to its
   ``pallas_call``.  Prints ``{"edges": {program: {"kernel_calls": {...},
-  "copies": [...], "copy_bytes": n, "written": {class: bytes}}}}``
-  (``written``: what the program's operations write by class, see
-  ``written_bytes``) and exits 1 if any program holds a copy; ``--out``
-  keeps the compiled text.
+  "copies": [...], "copy_bytes": n, "written": {class: bytes},
+  "conv_copies": [...]}}}`` (``written``: what the program's operations
+  write by class, see ``written_bytes``; ``conv_copies``: the copies inside
+  a short convolution layer's scope, which has no kernel whose edges could
+  be read) and exits 1 if any program holds a copy at a kernel's edge;
+  ``--out`` keeps the compiled text.
 
 Nothing runs, and for ``nets`` and ``cells`` nothing is compiled: equal text
 is the whole criterion there.
@@ -108,7 +110,8 @@ def net_texts() -> dict[str, tuple[str, str]]:
 
 
 CELLS = ("caffenet_train_resident", "googlenet_train_resident",
-         "caffenet_rounds_x4", "laguna_xs_2_train_8k")
+         "caffenet_rounds_x4", "laguna_xs_2_train_8k",
+         "lfm2_24b_a2b_train_8k")
 KERNELS = ("relu_lrn_fwd", "relu_lrn_bwd", "splash_mqa_fwd_residuals",
            "splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals")
 
@@ -144,7 +147,7 @@ def cell_lowered(described: bool, names) -> dict[str, tuple[object, str]]:
                   f"here: left out", file=sys.stderr)
             continue
         driver = harness.load_driver(cell.mix).Driver(cell)
-        if cell.mix["driver"] == "solver_tokens":
+        if hasattr(driver, "net_for"):      # a token driver, whatever model
             lowereds[f"{name}:Solver._step"] = (
                 _token_step_lowered(driver, devices[0], struct), "off")
             continue
@@ -403,6 +406,18 @@ def written_bytes(hlo: str):
     return dict(by_class), ops
 
 
+_CONV_SCOPE = r"L\[[^\]]*/conv\]"
+
+
+def scope_copies(ops, scope: str):
+    """The ``copy`` and ``transpose`` operations of ``written_bytes``'s
+    list whose ``op_name`` matches ``scope``: what a layer with no kernel
+    of its own (the gated short convolution) copies inside its scope."""
+    return [{"name": o["name"], "shape": o["shape"], "bytes": o["bytes"],
+             "op_name": o["op_name"]} for o in ops
+            if o["opcode"] in _MOVERS and re.search(scope, o["op_name"])]
+
+
 def edges(args) -> int:
     """``--set edges``: compile the cells' programs and list the layout
     copies at the edges of the Pallas kernels; exit 1 if any."""
@@ -417,9 +432,11 @@ def edges(args) -> int:
                     args.out, name.split(":")[0] + ".hlo"), "w") as f:
                 f.write(hlo)
         calls, copies = kernel_edge_copies(hlo)
+        written, ops = written_bytes(hlo)
         report[name] = {"kernel_calls": calls, "copies": copies,
                         "copy_bytes": sum(c["bytes"] for c in copies),
-                        "written": written_bytes(hlo)[0]}
+                        "written": written,
+                        "conv_copies": scope_copies(ops, _CONV_SCOPE)}
     print(json.dumps({"root": args.root, "backend": jax.default_backend(),
                       "devices": args.devices, "edges": report}),
           flush=True)
