@@ -21,14 +21,22 @@ products write and read it as it is (``_heads_major``), rotary turns a head
 by a product and not by slicing it, and float32 lives only inside a fusion.
 
 Lowerings, one an operation, chosen here from shapes and backend and counted
-once a trace (``attn_lowering_total{path}``, ``moe_lowering_total{path}``):
+once a trace (``attn_lowering_total{path, mask, backward, blocks}``,
+``moe_lowering_total{path}``):
 
 - the attention core (scores, mask, softmax, weighted sum; scope
-  ``attn_core``): on a TPU, where positions and head size fit its blocks,
-  JAX's block-sparse flash kernels (``splash_attention``: ``path=splash``),
+  ``attn_core``): on a TPU, where ``flash_blocks`` can tile the positions
+  (whole lane rows of 128) and the head is a multiple of 64, JAX's
+  block-sparse flash kernels (``splash_attention``: ``path=splash``),
   which skip the blocks a causal or window mask empties and never hold a
   score matrix, at heads of 128 and of 64 alike; elsewhere masked scores
-  in XLA (``path=xla``), which a TPU refuses where they would not fit;
+  in XLA (``path=xla``), which a TPU refuses where they would not fit.
+  The kernels' blocks and their backward form are one function of
+  ``(positions, window, head_dim)``, ``flash_blocks``: the block size at
+  which the blocks the mask leaves cost least once a grid step is priced,
+  and the fused ``dkv``/``dq`` kernel where a causal layer can hold its
+  partial ``dq``, the split kernels otherwise and under every window;
+  the counter's sample carries ``mask``, ``backward`` and ``blocks``;
 - the experts' products (scope ``moe_experts``): rows sorted by expert and
   multiplied group by group, on a TPU by JAX's ``megablox`` grouped matrix
   kernels (``path=gmm``), elsewhere by ``jax.lax.ragged_dot``
@@ -39,6 +47,7 @@ from __future__ import annotations
 
 import functools
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +59,6 @@ from ..utils import telemetry
 from .fillers import fill
 from .registry import LayerImpl, register_layer
 
-_SPLASH_BLOCK = 512     # positions a block of the flash kernels holds
 _GMM_ROWS = 512         # rows a tile of the grouped product holds
 
 
@@ -188,22 +196,123 @@ def _attn_core_xla(q, k, v, window: int):
     return jnp.einsum("kgst,ktd->kgsd", p.astype(v.dtype), v)
 
 
-@functools.lru_cache(maxsize=8)
-def _splash_kernel(s: int, group: int, window: int):
+class FlashBlocks(typing.NamedTuple):
+    """What one layer's flash kernels are built with: ``(block_q, block_kv,
+    block_kv_compute)`` of the forward and of the ``dkv`` kernel, and
+    ``(block_q, block_kv)`` of the ``dq`` kernel, or None where the fused
+    backward kernel makes ``dq`` in the ``dkv`` pass."""
+    fwd: tuple
+    dkv: tuple
+    dq: tuple | None
+
+    @property
+    def fused(self) -> bool:
+        return self.dq is None
+
+    def labels(self, window: int) -> dict:
+        """The ``attn_lowering_total`` labels that say what engaged."""
+        x = lambda b: "x".join(map(str, b))
+        return {"mask": "window" if window else "causal",
+                "backward": "fused" if self.fused else "split",
+                "blocks": f"fwd {x(self.fwd)} dkv {x(self.dkv)}"
+                          + ("" if self.fused else f" dq {x(self.dq)}")}
+
+
+# the flash kernels' memory blocks: whole lane rows, and no larger than the
+# compiler's 16 MB of scoped VMEM take (it refuses a forward or ``dq``
+# kernel of 2,048 x 2,048 at a head of 128)
+_FLASH_SIZES = (128, 256, 512, 1024)
+_FLASH_COMPUTE = 512    # score columns computed at once inside a block
+# what a grid step costs before it computes, in score elements of work: on
+# a v5e 0.5-0.8 us a step against 0.9-1.2 us a 512 x 512 block of scores
+_STEP_SCORES = 120_000
+# the fused backward kernel writes one partial dq, the queries' size, a
+# key/value memory block and sums them: taken up to this many, in blocks
+# of at most this many keys (what its VMEM takes round a compute block)
+_FUSED_DQ_COPIES = 4
+_FUSED_KV_MOST = 2048
+_INTERPRET = False      # tests patch this: the kernels in Pallas' interpreter
+
+
+def _blocks_seen(positions: int, window: int, block: int) -> int:
+    """Blocks of ``block`` x ``block`` scores a causal (``window`` 0) or
+    sliding mask leaves something of: the grid steps of a kernel."""
+    seen = 0
+    for first in range(0, positions, block):
+        low = max(0, first - (window - 1)) if window else 0
+        seen += (first + block - 1) // block - low // block + 1
+    return seen
+
+
+def flash_blocks(positions: int, window: int,
+                 head_dim: int) -> FlashBlocks | None:
+    """The blocks and the backward form of the flash kernels for one
+    sequence of ``positions`` under a causal mask (``window`` 0) or a
+    sliding one, or None where they cannot tile it.  One rule:
+
+    - every kernel takes square memory blocks of the size (of
+      ``_FLASH_SIZES``, dividing the positions) at which the blocks the
+      mask leaves cost least, a block costing its scores plus
+      ``_STEP_SCORES`` for the step (a kernel computes a block it visits
+      whole, so a window of 512 wastes half of what it computes at 512
+      and a third at 256, and 512 still wins: 3 smaller steps a row cost
+      more than 2 larger ones), round compute blocks of ``_FLASH_COMPUTE``;
+    - a causal layer takes the fused ``dkv``/``dq`` kernel, 5 products and
+      one exponential a score where the two kernels make 7 and two, where
+      its key/value block can grow (to ``_FUSED_KV_MOST``) until the
+      partial ``dq`` it writes is at most ``_FUSED_DQ_COPIES`` times the
+      queries; a window never does: the fused kernel's grid is not shrunk
+      by the mask and it writes zeros for every block the mask skips.
+
+    ``head_dim`` decides only whether the kernels take the head (whole
+    or half lane rows): on a v5e the soft-max's float32 elementwise work
+    and not the products bounds a block, at 64 as at 128, and the sweep's
+    winners were the same (PERF.md section 6, PR 38)."""
+    sizes = [b for b in _FLASH_SIZES if positions % b == 0]
+    if not sizes or head_dim % 64:
+        return None
+    b = min(sizes, key=lambda b: _blocks_seen(positions, window, b)
+            * (b * b + _STEP_SCORES))
+    compute = min(b, _FLASH_COMPUTE)
+    if not window:
+        kv = b
+        while (positions // kv > _FUSED_DQ_COPIES and kv < _FUSED_KV_MOST
+               and positions % (2 * kv) == 0):
+            kv *= 2
+        if positions // kv <= _FUSED_DQ_COPIES:
+            return FlashBlocks((b, b, compute), (b, kv, compute), None)
+    return FlashBlocks((b, b, compute), (b, b, compute), (b, b))
+
+
+def _block_sizes(blocks: FlashBlocks):
+    """``blocks`` as JAX's ``BlockSizes``."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    q, kv, compute = blocks.fwd
+    q_dkv, kv_dkv, compute_dkv = blocks.dkv
+    q_dq, kv_dq = blocks.dq or (None, None)
+    return sk.BlockSizes(
+        block_q=q, block_kv=kv, block_kv_compute=compute,
+        block_q_dkv=q_dkv, block_kv_dkv=kv_dkv,
+        block_kv_dkv_compute=compute_dkv, block_q_dq=q_dq,
+        block_kv_dq=kv_dq, use_fused_bwd_kernel=blocks.fused)
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_kernel(s: int, group: int, window: int, head_dim: int,
+                   interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     one = (sm.LocalMask((s, s), (window - 1, 0), 0) if window
            else sm.CausalMask((s, s)))
-    b = _SPLASH_BLOCK
-    sizes = sk.BlockSizes(
-        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
-        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    sizes = _block_sizes(flash_blocks(s, window, head_dim))
     # the kernel object holds its block mask as arrays: made concrete
     # here, or a cached one would carry the tracers of the trace that
     # first asked for it into the next
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mqa_single_device(
-            sm.MultiHeadMask([one] * group), block_sizes=sizes)
+            sm.MultiHeadMask([one] * group), block_sizes=sizes,
+            interpret=interpret)
 
 
 # float32 scores the masked-scores lowering may hold for one sequence on a
@@ -211,26 +320,30 @@ def _splash_kernel(s: int, group: int, window: int):
 _XLA_SCORE_BYTES = 1 << 30
 
 
-def attn_lowering(positions: int, head_dim: int, heads: int = 1) -> str:
+def attn_lowering(positions: int, head_dim: int, heads: int = 1,
+                  window: int = 0) -> str:
     """Which lowering the attention core takes at these sizes on this
-    backend; counted in ``attn_lowering_total``.  The flash kernels take a
-    head of 64 as it is (half a lane row a head: the kernels pad their own
-    scratch, nothing is padded here).  On a TPU the masked scores are
-    refused, not taken, where one sequence's would not fit."""
+    backend; counted in ``attn_lowering_total``.  The flash kernels take
+    whatever ``flash_blocks`` can tile, a head of 64 as it is (half a lane
+    row a head: the kernels pad their own scratch, nothing is padded
+    here), and the counter's sample says what it chose: ``mask``,
+    ``backward`` and ``blocks`` beside ``path``.  On a TPU the masked
+    scores are refused, not taken, where one sequence's would not fit."""
     tpu = jax.default_backend() == "tpu"
-    splash = (tpu and positions % _SPLASH_BLOCK == 0 and head_dim % 64 == 0)
-    path = "splash" if splash else "xla"
-    if tpu and not splash and (4 * heads * positions * positions
+    blocks = flash_blocks(positions, window, head_dim) if tpu else None
+    path = "splash" if blocks else "xla"
+    if tpu and not blocks and (4 * heads * positions * positions
                                > _XLA_SCORE_BYTES):
         raise ValueError(
             f"attention over {positions} positions with heads of "
             f"{head_dim} fits no flash kernel (positions in blocks of "
-            f"{_SPLASH_BLOCK}, heads of a multiple of 64), and its masked "
+            f"{_FLASH_SIZES[0]}, heads of a multiple of 64), and its masked "
             f"scores ({heads} x {positions} x {positions} float32) do not "
             f"fit the chip")
     telemetry.get_registry().counter(
         "attn_lowering_total",
-        "traces of the attention core, by lowering").inc(path=path)
+        "traces of the attention core, by lowering").inc(
+            path=path, **(blocks.labels(window) if blocks else {}))
     return path
 
 
@@ -250,7 +363,8 @@ def attn_core(q, k, v, window: int, path: str):
     _, group, s, _ = q.shape
     with jax.named_scope("attn_core"):
         if path == "splash":
-            return jax.vmap(_splash_kernel(s, group, window))(q, k, v)
+            return jax.vmap(_splash_kernel(s, group, window, q.shape[-1],
+                                           _INTERPRET))(q, k, v)
         return _attn_core_xla(q, k, v, window)
 
 
@@ -312,7 +426,7 @@ class AttentionLayer(LayerImpl):
         wo = next(blobs)
         q_norm, k_norm = ((next(blobs), next(blobs)) if g["qk_norm"]
                           else (None, None))
-        path = attn_lowering(bottoms[0].shape[-2], d, heads)
+        path = attn_lowering(bottoms[0].shape[-2], d, heads, g["window"])
 
         hidden, group = wq.shape[0], heads // kv
         # the query heads grouped by their key/value head, as the kernels

@@ -202,20 +202,66 @@ def _attention_gradient(one_chip, kind, heads):
 @pytest.mark.parametrize("kind,heads,window", _ATTENTION,
                          ids=[k for k, _, _ in _ATTENTION])
 def test_attention_layer_compiles_at_real_widths(one_chip, chip_branch,
-                                                 kind, heads, window):
+                                                 lowered_text, kind, heads,
+                                                 window):
     """One sequence of 8,192 positions through an attention layer of the
-    published widths, forward and backward: the flash kernels are in the
-    program, forward and both backward ones, and no score matrix is."""
+    published widths, forward and backward, at the blocks and the backward
+    form ``flash_blocks`` gives: the flash kernels are in the program (the
+    forward one, recomputed for the backward pass; ``dkv``; ``dq`` in the
+    sliding layer alone, the full layer's fused kernel makes it and JAX
+    sums its partials), nothing is copied at their edges but JAX's own
+    log-sum-exp, and no score matrix is held."""
+    from sparknet_tpu.ops import sequence
+    blocks = sequence.flash_blocks(8192, window, 128)
+    assert blocks.fused == (kind == "full")
     compiled = _attention_gradient(one_chip, kind, heads)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    calls, copies = lowered_text.kernel_edge_copies(text)
+    want = {"splash_mqa_fwd_residuals": 1, "splash_mqa_dkv_no_residuals": 1}
+    if not blocks.fused:
+        want["splash_mqa_dq_no_residuals"] = 1
+    assert calls == want
+    assert {c["kernel"] for c in copies} <= {"splash_mqa_fwd_residuals"}
+    sums = lowered_text.partial_dq_sums(lowered_text.written_bytes(text)[1])
+    assert [s["bytes"] for s in sums] == (
+        [8192 * heads * 128 * 2] if blocks.fused else [])
     # a sequence's scores for one head alone would be 268 MB in float32
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
+# shapes the cells do not run: what ``flash_blocks`` accepts, Mosaic takes
+_FLASH_TILINGS = [(1024, 0, 64), (384, 0, 64), (640, 128, 128),
+                  (1280, 256, 64), (4096, 1024, 128), (16384, 0, 128),
+                  (2048, 0, 128)]
+
+
+@pytest.mark.parametrize("positions,window,head_dim", _FLASH_TILINGS,
+                         ids=["x".join(map(str, t)) for t in _FLASH_TILINGS])
+def test_the_chip_takes_what_flash_blocks_can_tile(one_chip, positions,
+                                                   window, head_dim):
+    """The core's gradient for two query heads over one key/value head at
+    the blocks the rule gives: compiled by Mosaic, fused or split as the
+    rule says."""
+    from sparknet_tpu.ops import sequence
+    blocks = sequence.flash_blocks(positions, window, head_dim)
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(sequence.attn_core(q, k, v, window, "splash")
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        arg(1, 2, positions, head_dim), arg(1, positions, head_dim),
+        arg(1, positions, head_dim)).compile().as_text()
+    assert "splash_mqa_dkv_no_residuals" in text
+    assert ("splash_mqa_dq_no_residuals" in text) == (not blocks.fused)
+
+
 # what the compiled gradient of one layer may write a sequence outside
-# matrix-product fusions and Pallas calls: what PR 36's code reaches and a
-# tenth more (the parent of PR 36: 2.75 and 3.72 GB)
+# matrix-product fusions, Pallas calls and JAX's sum of a fused backward
+# kernel's partial dq: what PR 36's code reaches and a tenth more (the
+# parent of PR 36: 2.75 and 3.72 GB)
 _ATTENTION_WRITES = [("full", 48, 0.43e9), ("sliding", 64, 0.54e9)]
 
 
@@ -233,7 +279,8 @@ def test_attention_layer_keeps_one_layout_and_width(
     text = _attention_gradient(one_chip, kind, heads).as_text()
     written, ops = lowered_text.written_bytes(text)
     assert written["kernel"] > 0 and written["product"] > 0
-    assert written["other"] <= most
+    summed = sum(s["bytes"] for s in lowered_text.partial_dq_sums(ops))
+    assert written["other"] - summed <= most
     queries = 8192 * heads * 128 * 4
     assert [o["name"] for o in ops
             if o["opcode"] in ("slice", "concatenate", "copy")
@@ -285,6 +332,40 @@ ENTRY %main (a: bf16[8,4], w: bf16[4,4]) -> bf16[8,4] {
     by_name = {o["name"]: o for o in ops}
     assert by_name["fusion.2"]["fused"] == ["convert", "tuple"]
     assert by_name["copy.1"]["op_name"] == "jit(f)/attn_core/x"
+
+
+def test_partial_dq_sums_are_named_as_the_librarys_own(lowered_text):
+    """The reader itself: the reduction inside JAX's wrapper of the flash
+    kernels that sums a fused backward kernel's partial dq is listed as
+    that, alone or fused, and is no copy at the kernel's edge; a sum
+    elsewhere is not listed."""
+    hlo = """
+HloModule m
+%add (a: bf16[], b: bf16[]) -> bf16[] {
+  %a = bf16[] parameter(0)
+  %b = bf16[] parameter(1)
+  ROOT %s = bf16[] add(%a, %b)
+}
+%fused_sum (p: bf16[4,8,9]) -> bf16[8,9] {
+  %p = bf16[4,8,9]{2,1,0} parameter(0)
+  %z = bf16[] constant(0)
+  ROOT %r = bf16[8,9]{1,0} reduce(%p, %z), dimensions={0}, to_apply=%add
+}
+ENTRY %main (q: bf16[8,9]) -> bf16[8,9] {
+  %q = bf16[8,9]{1,0} parameter(0)
+  %k = (bf16[4,8,9]{2,1,0}, bf16[8,9]{1,0}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/attn_core/vmap(jit(_splash_attention))/splash_mqa_dkv_no_residuals/pallas_call"}
+  %parts = bf16[4,8,9]{2,1,0} get-tuple-element(%k), index=0
+  %zero = bf16[] constant(0)
+  %reduce.1 = bf16[8,9]{1,0} reduce(%parts, %zero), dimensions={0}, to_apply=%add, metadata={op_name="jit(f)/attn_core/vmap(jit(_splash_attention))/reduce_sum"}
+  %fusion.2 = bf16[8,9]{1,0} fusion(%parts), kind=kLoop, calls=%fused_sum, metadata={op_name="jit(g)/attn_core/vmap(jit(_splash_attention))/reduce_sum"}
+  ROOT %reduce.3 = bf16[8,9]{1,0} reduce(%parts, %zero), dimensions={0}, to_apply=%add, metadata={op_name="jit(f)/lm_loss/reduce_sum"}
+}
+"""
+    calls, copies = lowered_text.kernel_edge_copies(hlo)
+    assert calls == {"splash_mqa_dkv_no_residuals": 1} and copies == []
+    sums = lowered_text.partial_dq_sums(lowered_text.written_bytes(hlo)[1])
+    assert [(s["name"], s["bytes"]) for s in sums] == [
+        ("reduce.1", 144), ("fusion.2", 144)]
 
 
 def test_expert_layer_compiles_at_real_widths(one_chip, chip_branch):
@@ -345,16 +426,20 @@ def _layer_gradient(one_chip, lp, impl, shapes, sequences=1):
 
 
 def test_attention_at_head_dim_64_takes_the_flash_kernels(one_chip,
-                                                          chip_branch):
+                                                          chip_branch,
+                                                          lowered_text):
     """32 query heads over 8 key/value heads of 64, q and k normalised, no
-    gate: Mosaic takes JAX's flash kernels at half a lane row a head,
-    forward and both backward ones, and no score matrix is in the program
+    gate: Mosaic takes JAX's flash kernels at half a lane row a head, the
+    forward one and the fused backward one at the blocks ``flash_blocks``
+    gives a causal mask over 8,192, and no score matrix is in the program
     (one sequence's would be 8.6 GB)."""
     lp, impl, shapes = _lfm2_layer("L2/attn")
     assert [s.shape for s in shapes] == [
         (2048, 2048), (2048, 512), (2048, 512), (2048, 2048), (64,), (64,)]
     compiled = _layer_gradient(one_chip, lp, impl, shapes)
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    calls, _ = lowered_text.kernel_edge_copies(compiled.as_text())
+    assert calls == {"splash_mqa_fwd_residuals": 1,
+                     "splash_mqa_dkv_no_residuals": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 

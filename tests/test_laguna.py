@@ -265,4 +265,9 @@ def test_load_and_lowering_counters():
     for name, path in (("attn_lowering_total", "xla"),
                        ("moe_lowering_total", "ragged_dot")):
         assert total(last, name) > total(after, name)
-        assert {s["labels"]["path"] for s in last[name]["samples"]} == {path}
+        # the registry is the process's: only what this apply counted
+        was = {tuple(sorted(s["labels"].items())): s["value"]
+               for s in after.get(name, {}).get("samples", [])}
+        assert {s["labels"]["path"] for s in last[name]["samples"]
+                if s["value"] > was.get(tuple(sorted(s["labels"].items())),
+                                        0)} == {path}

@@ -500,18 +500,183 @@ def test_ungated_normalised_attention_against_reference(heads, kv, d,
 @pytest.mark.parametrize("positions,head_dim,heads,want", [
     (8192, 128, 48, "splash"), (8192, 64, 32, "splash"),
     (1024, 64, 32, "splash"), (96, 64, 32, "xla"), (8192, 96, 32, None),
-    (8000, 64, 32, None)])
+    (8000, 64, 32, None), (1280, 64, 32, "splash"), (8192, 128, 64, "splash")])
 def test_attention_lowering_on_a_chip(monkeypatch, positions, head_dim,
                                       heads, want):
-    """On a TPU heads of 64 take the flash kernels like heads of 128, small
+    """On a TPU heads of 64 take the flash kernels like heads of 128 where
+    ``flash_blocks`` can tile the positions (whole lane rows of 128), small
     sequences take the masked scores, and a sequence whose scores would
     not fit is refused: the masked scores are never taken silently."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    window = 512 if heads == 64 else 0
     if want is None:
+        assert seq.flash_blocks(positions, window, head_dim) is None
         with pytest.raises(ValueError, match="do not fit the chip"):
-            seq.attn_lowering(positions, head_dim, heads)
+            seq.attn_lowering(positions, head_dim, heads, window)
     else:
-        assert seq.attn_lowering(positions, head_dim, heads) == want
+        assert seq.attn_lowering(positions, head_dim, heads, window) == want
+        assert (seq.flash_blocks(positions, window, head_dim) is not None) \
+            == (want == "splash")
+
+
+# the three shapes the token cells run, the test nets' 1,024 positions at
+# heads of 64, shapes the rule was not fitted on, and the edges of its
+# bound on the partial dq: (positions, window, head_dim) -> fused?
+_FLASH_SHAPES = [
+    ((8192, 0, 128), True), ((8192, 512, 128), False),
+    ((8192, 0, 64), True), ((1024, 0, 64), True), ((1024, 512, 64), False),
+    ((4096, 1024, 128), False), ((4096, 0, 64), True),
+    ((16384, 0, 128), False), ((16384, 2048, 128), False),
+    ((384, 0, 64), True), ((640, 0, 128), False), ((1280, 256, 64), False),
+    ((2048, 0, 128), True), ((512, 256, 64), False)]
+
+
+@pytest.mark.parametrize("shape,fused", _FLASH_SHAPES,
+                         ids=["x".join(map(str, s)) for s, _ in _FLASH_SHAPES])
+def test_flash_blocks_follow_the_mask_and_the_positions(shape, fused):
+    """Every block is a multiple of 128 that divides the positions, each
+    compute block divides its memory block, the fused backward kernel is
+    taken exactly where the partial dq it writes stays within the bound
+    on its copies and never under a window, and a fused layer names no dq
+    blocks; JAX's ``BlockSizes`` takes what the rule gives."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    positions, window, head_dim = shape
+    blocks = seq.flash_blocks(*shape)
+    given = blocks.fwd + blocks.dkv + (blocks.dq or ())
+    assert all(b % 128 == 0 and positions % b == 0 for b in given)
+    assert blocks.fwd[1] % blocks.fwd[2] == 0
+    assert blocks.dkv[1] % blocks.dkv[2] == 0
+    copies = positions // blocks.dkv[1]
+    assert blocks.fused == fused == (blocks.dq is None)
+    if window:
+        assert not blocks.fused
+    if blocks.fused:
+        assert copies <= seq._FUSED_DQ_COPIES
+    elif not window:
+        # no key/value block the fused kernel can take brings the copies
+        # under the bound
+        assert all(positions // kv > seq._FUSED_DQ_COPIES
+                   for kv in (128, 256, 512, 1024, 2048)
+                   if kv <= seq._FUSED_KV_MOST and positions % kv == 0)
+    sizes = seq._block_sizes(blocks)
+    assert isinstance(sizes, sk.BlockSizes) and sizes.has_backward_blocks
+    assert sizes.use_fused_bwd_kernel == fused
+    assert (sizes.block_q_dq, sizes.block_kv_dq) == (blocks.dq or (None,
+                                                                   None))
+    labels = blocks.labels(window)
+    assert labels["mask"] == ("window" if window else "causal")
+    assert labels["backward"] == ("fused" if fused else "split")
+    assert ("dq" in labels["blocks"]) == (not fused)
+
+
+def test_flash_blocks_of_the_cells():
+    """What the sweep on the chip found (PERF.md section 6, PR 38): a
+    causal mask over 8,192 wants blocks of 1,024 round compute blocks of
+    512 and the fused backward kernel over key/value blocks of 2,048 (4
+    partial dq); a window of 512 its blocks of 512, split; a head of 64
+    the same as one of 128."""
+    causal = seq.FlashBlocks((1024, 1024, 512), (1024, 2048, 512), None)
+    assert seq.flash_blocks(8192, 0, 128) == causal
+    assert seq.flash_blocks(8192, 0, 64) == causal
+    assert seq.flash_blocks(8192, 512, 128) == seq.FlashBlocks(
+        (512, 512, 512), (512, 512, 512), (512, 512))
+
+
+def test_a_window_wastes_least_at_its_own_size_once_steps_are_priced():
+    """The count the rule prices: a query block of 512 under a window of
+    512 sees two key blocks, one of 256 three, one of 128 five; the
+    causal mask over 8,192 leaves 136 blocks of 512 and 36 of 1,024."""
+    rows = lambda b: seq._blocks_seen(8192, 512, b) / (8192 // b)
+    assert [round(rows(b), 2) for b in (128, 256, 512, 1024)] == [
+        4.84, 2.91, 1.94, 1.88]
+    assert seq._blocks_seen(8192, 0, 512) == 136
+    assert seq._blocks_seen(8192, 0, 1024) == 36
+
+
+def test_the_kernel_cache_tells_heads_and_masks_apart(monkeypatch):
+    """One kernel object a (positions, group, window, head_dim): a head of
+    64 and one of 128 at the same three do not share one."""
+    made = []
+    monkeypatch.setattr(seq, "_block_sizes",
+                        lambda blocks: made.append(blocks) or None)
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    monkeypatch.setattr(sk, "make_splash_mqa_single_device",
+                        lambda mask, block_sizes, interpret: object())
+    seq._splash_kernel.cache_clear()
+    try:
+        a = seq._splash_kernel(1024, 2, 0, 64, False)
+        assert seq._splash_kernel(1024, 2, 0, 64, False) is a
+        assert seq._splash_kernel(1024, 2, 0, 128, False) is not a
+        assert seq._splash_kernel(1024, 2, 512, 64, False) is not a
+        assert seq._splash_kernel(1024, 2, 0, 64, True) is not a
+        assert len(made) == 4
+    finally:
+        seq._splash_kernel.cache_clear()
+
+
+def test_the_lowering_counter_says_what_engaged(monkeypatch):
+    """``attn_lowering_total``'s sample of a splash trace carries the mask
+    kind, the backward form and the blocks beside the path; an ``xla``
+    trace carries the path alone."""
+    from sparknet_tpu.utils import telemetry
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq.attn_lowering(8192, 128, 48, 0)
+    seq.attn_lowering(8192, 128, 64, 512)
+    seq.attn_lowering(96, 64, 32, 0)
+    samples = [s["labels"] for s in telemetry.get_registry().snapshot()[
+        "attn_lowering_total"]["samples"]]
+    assert {"path": "splash", "mask": "causal", "backward": "fused",
+            "blocks": "fwd 1024x1024x512 dkv 1024x2048x512"} in samples
+    assert {"path": "splash", "mask": "window", "backward": "split",
+            "blocks": "fwd 512x512x512 dkv 512x512x512 dq 512x512"
+            } in samples
+    assert {"path": "xla"} in samples
+
+
+# (positions, window, head_dim, kv heads, group): both head sizes, both
+# masks, both backward forms, memory blocks of one and of two compute
+# blocks, several blocks a side, several partial dq
+_FLASH_NUMERICS = [(2048, 0, 64, 1, 1), (2048, 1536, 128, 1, 1),
+                   (1024, 0, 64, 1, 2), (1024, 512, 128, 1, 2),
+                   (512, 0, 128, 2, 2), (512, 256, 64, 2, 1),
+                   (384, 0, 64, 1, 2), (640, 128, 128, 1, 1)]
+
+
+@pytest.mark.parametrize(
+    "positions,window,head_dim,kv,group", _FLASH_NUMERICS,
+    ids=["x".join(map(str, c)) for c in _FLASH_NUMERICS])
+def test_flash_kernels_at_the_rules_blocks_against_the_masked_scores(
+        monkeypatch, positions, window, head_dim, kv, group):
+    """``attn_core(path="splash")`` with the ``BlockSizes`` the rule gives,
+    in Pallas' interpreter, against the masked scores: the output and the
+    three gradients, bfloat16 operands as the cells feed them.  The fused
+    kernel rounds each partial dq to bfloat16 before their sum; that
+    stays within what bfloat16 storage of dq already is."""
+    monkeypatch.setattr(seq, "_INTERPRET", True)
+    blocks = seq.flash_blocks(positions, window, head_dim)
+    assert blocks.fused == (not window)
+    r = jax.random.split(jax.random.PRNGKey(positions + head_dim), 4)
+    q = (jax.random.normal(r[0], (kv, group, positions, head_dim))
+         * head_dim ** -0.5).astype(jnp.bfloat16)
+    k = jax.random.normal(r[1], (kv, positions, head_dim)).astype(
+        jnp.bfloat16)
+    v = jax.random.normal(r[2], (kv, positions, head_dim)).astype(
+        jnp.bfloat16)
+    cot = jax.random.normal(r[3], q.shape).astype(jnp.bfloat16)
+
+    def both(path):
+        return jax.jit(lambda q, k, v: jax.vjp(
+            lambda *a: seq.attn_core(*a, window, path), q, k, v)[1](cot) + (
+                seq.attn_core(q, k, v, window, path),))(q, k, v)
+
+    for name, got, want in zip(("dq", "dk", "dv", "out"), both("splash"),
+                               both("xla")):
+        got, want = (np.asarray(t, np.float32) for t in (got, want))
+        assert got.shape == want.shape
+        err = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert err < 8e-3, (name, err)
 
 
 def test_attention_lowering_off_the_chip_is_the_masked_scores():

@@ -35,16 +35,21 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   described``: by the TPU's compiler for the described chip, about a minute a
   cell), and read for layout copies at the edges of the Pallas kernels (the
   LRN epilogue's ``relu_lrn_fwd``/``relu_lrn_bwd``, the attention's
-  ``splash_mqa_fwd_residuals``/``splash_mqa_dkv_no_residuals``/
-  ``splash_mqa_dq_no_residuals``): a ``copy`` or ``transpose`` that feeds
+  ``splash_mqa_fwd_residuals``/``splash_mqa_dkv_no_residuals`` and, in a
+  layer whose backward pass is split, ``splash_mqa_dq_no_residuals``: a
+  layer that takes the fused backward kernel has no ``dq`` call, its
+  ``dkv`` call makes ``dq`` too): a ``copy`` or ``transpose`` that feeds
   such a custom call, reads its result, or is attributed to its
   ``pallas_call``.  Prints ``{"edges": {program: {"kernel_calls": {...},
   "copies": [...], "copy_bytes": n, "written": {class: bytes},
-  "conv_copies": [...]}}}`` (``written``: what the program's operations
-  write by class, see ``written_bytes``; ``conv_copies``: the copies inside
-  a short convolution layer's scope, which has no kernel whose edges could
-  be read) and exits 1 if any program holds a copy at a kernel's edge;
-  ``--out`` keeps the compiled text.
+  "conv_copies": [...], "dq_sums": [...]}}}`` (``written``: what the
+  program's operations write by class, see ``written_bytes``;
+  ``conv_copies``: the copies inside a short convolution layer's scope,
+  which has no kernel whose edges could be read; ``dq_sums``: the sums over
+  key/value blocks of the partial ``dq`` a fused backward kernel writes,
+  JAX's own inside its wrapper of the kernels and no copy) and exits 1 if
+  any program holds a copy at a kernel's edge; ``--out`` keeps the compiled
+  text.
 
 Nothing runs, and for ``nets`` and ``cells`` nothing is compiled: equal text
 is the whole criterion there.
@@ -112,6 +117,8 @@ def net_texts() -> dict[str, tuple[str, str]]:
 CELLS = ("caffenet_train_resident", "googlenet_train_resident",
          "caffenet_rounds_x4", "laguna_xs_2_train_8k",
          "lfm2_24b_a2b_train_8k")
+# a layer of split backward kernels calls all three splash kernels, a fused
+# one the first two: its dkv kernel makes dq as well
 KERNELS = ("relu_lrn_fwd", "relu_lrn_bwd", "splash_mqa_fwd_residuals",
            "splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals")
 
@@ -409,13 +416,30 @@ def written_bytes(hlo: str):
 _CONV_SCOPE = r"L\[[^\]]*/conv\]"
 
 
+def _listed(op) -> dict:
+    """One of ``written_bytes``'s operations as the edge report lists it."""
+    return {k: op[k] for k in ("name", "shape", "bytes", "op_name")}
+
+
 def scope_copies(ops, scope: str):
     """The ``copy`` and ``transpose`` operations of ``written_bytes``'s
     list whose ``op_name`` matches ``scope``: what a layer with no kernel
     of its own (the gated short convolution) copies inside its scope."""
-    return [{"name": o["name"], "shape": o["shape"], "bytes": o["bytes"],
-             "op_name": o["op_name"]} for o in ops
+    return [_listed(o) for o in ops
             if o["opcode"] in _MOVERS and re.search(scope, o["op_name"])]
+
+
+_DQ_SUM = r"attn_core/vmap\(jit\(_splash_attention\)\)/reduce_sum$"
+
+
+def partial_dq_sums(ops):
+    """The reductions of ``written_bytes``'s list that sum a fused backward
+    kernel's partial ``dq`` over its key/value blocks: JAX's own, inside
+    its wrapper of the kernels (``dq_unreduced.sum(axis=0)``), one a fused
+    layer a sequence.  ``bytes`` is the sum's result, the queries' size."""
+    return [_listed(o) for o in ops
+            if (o["opcode"] == "reduce" or "reduce" in o["fused"])
+            and re.search(_DQ_SUM, o["op_name"])]
 
 
 def edges(args) -> int:
@@ -436,7 +460,8 @@ def edges(args) -> int:
         report[name] = {"kernel_calls": calls, "copies": copies,
                         "copy_bytes": sum(c["bytes"] for c in copies),
                         "written": written,
-                        "conv_copies": scope_copies(ops, _CONV_SCOPE)}
+                        "conv_copies": scope_copies(ops, _CONV_SCOPE),
+                        "dq_sums": partial_dq_sums(ops)}
     print(json.dumps({"root": args.root, "backend": jax.default_backend(),
                       "devices": args.devices, "edges": report}),
           flush=True)
