@@ -41,6 +41,18 @@ from ..proto.caffe_pb import (
 # a pytree alias; elementwise add / scalarDivide are jax.tree_util one-liners.
 WeightCollection = dict[str, list[jax.Array]]
 
+# The sub-scope of a layer's casts to and from the compute dtype, inside the
+# layer's own scope: ``L[conv1]/cast`` forward, ``transpose(jvp(L[conv1]))/
+# cast`` backward.
+CAST_SCOPE = "cast"
+
+
+def layer_scope(name: str):
+    """The scope every operation of layer (or step phase) ``name`` is traced
+    under.  Debug information only: it lives in source locations, and a
+    profiler trace names device time by its innermost ``L[...]``."""
+    return jax.named_scope(f"L[{name}]")
+
 
 @dataclasses.dataclass
 class NetOutputs:
@@ -506,10 +518,12 @@ class Net:
 
     def _cast(self, arrs, dtype):
         """Cast floating arrays for mixed-precision compute; ints (labels,
-        indices) pass through."""
-        return [a.astype(dtype)
-                if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a
-                for a in arrs]
+        indices) pass through.  Called inside the layer's ``L[...]`` scope:
+        a fusion rooted in a cast reads ``L[<layer>]/cast``."""
+        with jax.named_scope(CAST_SCOPE):
+            return [a.astype(dtype)
+                    if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating)
+                    else a for a in arrs]
 
     def _run(self, params, inputs, train, rng, upto: str | None = None,
              eps: Mapping[str, jax.Array] | None = None,
@@ -607,13 +621,6 @@ class Net:
                     f"layer {node.lp.name!r} needs blobs {missing}; with "
                     f"start={start!r} every bottom produced before the "
                     f"start layer must be fed in inputs")
-            layer_rng = None
-            if rng is not None and node.impl.needs_rng(node.lp, train):
-                # per-node identity fold, NOT sequential splits: a ranged
-                # run (start=/upto=) must give each layer the same stream
-                # the full forward gave it, so ranged backward replays the
-                # masks its forward actually used
-                layer_rng = jax.random.fold_in(rng, ni)
             stateful = getattr(node.impl, "has_state", False)
             if vfuse_on and node.lp.name in self._vfuse_head:
                 ch = self._vfuse_head[node.lp.name]
@@ -647,20 +654,19 @@ class Net:
                     f"stateful/rng layer; fix _detect_hfuse_groups")
                 mp = [self.node_params(new_params, m) for m in members]
                 sizes = [p0[0].shape[0] for p0 in mp]
-                fused = [jnp.concatenate([p0[0] for p0 in mp], axis=0)]
-                if len(mp[0]) > 1:  # bias_term (uniform within a group)
-                    fused.append(jnp.concatenate([p0[1] for p0 in mp],
-                                                 axis=0))
-                bots = [blobs[node.bottoms[0]]]
-                if cd is not None:
-                    bots = self._cast(bots, cd)
-                    fused = self._cast(fused, cd)
                 cuts, acc = [], 0
                 for s in sizes[:-1]:
                     acc += s
                     cuts.append(acc)
-                scope = "+".join(m.lp.name for m in members)
-                with jax.named_scope(f"L[{scope}]"):
+                bots = [blobs[node.bottoms[0]]]
+                with layer_scope("+".join(m.lp.name for m in members)):
+                    fused = [jnp.concatenate([p0[0] for p0 in mp], axis=0)]
+                    if len(mp[0]) > 1:  # bias_term (uniform within a group)
+                        fused.append(jnp.concatenate([p0[1] for p0 in mp],
+                                                     axis=0))
+                    if cd is not None:
+                        bots = self._cast(bots, cd)
+                        fused = self._cast(fused, cd)
                     (y,) = node.impl.apply(node.lp, fused, bots, train,
                                            None)
                     parts = jnp.split(y, cuts, axis=1)
@@ -670,22 +676,32 @@ class Net:
             else:
                 p = self.node_params(new_params, node)
                 bots = [blobs[b] for b in node.bottoms]
-                if cd is not None:
-                    if (node.impl.is_loss() or node.lp.type == "Accuracy"
-                            or stateful):
-                        # numerics-critical: losses, accuracy, BN batch
-                        # stats
-                        bots = self._cast(bots, jnp.float32)
-                    else:
-                        bots = self._cast(bots, cd)
-                        p = self._cast(p, cd)
                 # named scope: XLA op metadata carries "L[<layer>]"
-                # through fwd AND the AD transpose, so profiler traces
-                # attribute device time per layer (tools/profile_step.py
-                # --by-layer — the `caffe time` per-layer view, reference:
-                # caffe/tools/caffe.cpp:290-376, but post-fusion
-                # on-device)
-                with jax.named_scope(f"L[{node.lp.name}]"):
+                # through fwd AND the AD transpose, so a profiler trace
+                # attributes device time per layer (the `caffe time`
+                # per-layer view, reference: caffe/tools/caffe.cpp:290-376,
+                # but post-fusion on-device; benchmark/lib/trace.py and
+                # utils/xplane.py read it).  The casts sit inside it: they
+                # are the layer's cost
+                with layer_scope(node.lp.name):
+                    layer_rng = None
+                    if rng is not None and node.impl.needs_rng(node.lp,
+                                                               train):
+                        # per-node identity fold, NOT sequential splits: a
+                        # ranged run (start=/upto=) must give each layer
+                        # the same stream the full forward gave it, so
+                        # ranged backward replays the masks its forward
+                        # actually used
+                        layer_rng = jax.random.fold_in(rng, ni)
+                    if cd is not None:
+                        if (node.impl.is_loss()
+                                or node.lp.type == "Accuracy" or stateful):
+                            # numerics-critical: losses, accuracy, BN
+                            # batch stats
+                            bots = self._cast(bots, jnp.float32)
+                        else:
+                            bots = self._cast(bots, cd)
+                            p = self._cast(p, cd)
                     result = node.impl.apply(node.lp, p, bots, train,
                                              layer_rng)
                 if stateful:
@@ -693,19 +709,22 @@ class Net:
                     self._scatter_node_params(new_params, node, updated)
                 else:
                     tops = result
-            if eps:
-                tops = [v + eps[t]
-                        if last_producer.get(t) == node.lp.name else v
-                        for t, v in zip(node.tops, tops)]
-            for t, v in zip(node.tops, tops):
-                blobs[t] = v
-            # loss accumulation (reference: Layer::SetLossWeights +
-            # Net::Forward summing weighted tops)
-            for w, v in zip(node.loss_weights(), tops):
-                if w:
-                    # f32 accumulation even when the top was computed in a
-                    # reduced compute_dtype (loss_weight on non-loss layers)
-                    loss = loss + w * jnp.sum(v.astype(jnp.float32))
+            # what the run does to a layer's tops is the layer's too
+            with layer_scope(node.lp.name):
+                if eps:
+                    tops = [v + eps[t]
+                            if last_producer.get(t) == node.lp.name else v
+                            for t, v in zip(node.tops, tops)]
+                for t, v in zip(node.tops, tops):
+                    blobs[t] = v
+                # loss accumulation (reference: Layer::SetLossWeights +
+                # Net::Forward summing weighted tops)
+                for w, v in zip(node.loss_weights(), tops):
+                    if w:
+                        # f32 accumulation even when the top was computed
+                        # in a reduced compute_dtype (loss_weight on
+                        # non-loss layers)
+                        loss = loss + w * jnp.sum(v.astype(jnp.float32))
             if upto is not None and node.lp.name == upto:
                 break
         return blobs, loss, new_params
@@ -726,10 +745,10 @@ class Net:
         head = members[0]
         x = blobs[head.bottoms[0]]
         p = self.node_params(params, head)
-        if cd is not None:
-            x = self._cast([x], cd)[0]
-            p = self._cast(p, cd)
-        with jax.named_scope(f"L[{ch.scope()}]"):
+        with layer_scope(ch.scope()):
+            if cd is not None:
+                x = self._cast([x], cd)[0]
+                p = self._cast(p, cd)
             (y,) = head.impl.apply(head.lp, p, [x], train, None)
             # between head and tail: a ReLU, a pool; neither has blobs
             for m in members[1:-2 if folds else -1]:

@@ -60,9 +60,8 @@ from ..graph.net import Net, WeightCollection
 from ..ops import augment
 from ..proto.caffe_pb import NetState, Phase, SolverParameter
 from ..utils import telemetry
-from ..solvers.lr_policies import learning_rate
-from ..solvers.step import make_step_fns
-from ..solvers.update_rules import make_update_rule, preprocess_grads
+from ..solvers.step import INPUT_SCOPE, apply_update, make_step_fns
+from ..solvers.update_rules import make_update_rule
 from .mesh import (
     CHIP_AXIS, DATA_AXIS, HOST_AXIS, make_mesh, make_pod_mesh,
     put_global_tree, replicated, stage_local,
@@ -210,6 +209,14 @@ class TrainerConfig:
     # logical leaves, so a checkpoint written at world N re-tiles onto
     # world M bit-exactly (the elastic contract survives sharding).
     shard_checkpoint: bool = False
+
+
+# The round's own phases in a profiler trace, beside the layers'
+# ``L[<layer>]`` (graph/net.py) and the step's (solvers/step.py): the
+# boundary average of the weights and the loss, and the ``sync`` strategy's
+# exchange of gradients at every step.
+AVERAGE_SCOPE = "L[round.average]"
+SYNC_SCOPE = "L[round.sync]"
 
 
 class TrainingDivergedError(RuntimeError):
@@ -547,41 +554,46 @@ class DistributedTrainer:
             axis) and "hierarchical" (over the chip axis within a host)."""
             def step(carry, micro):
                 params, state, it, rng = carry
-                rng, sub, pre_rng = jax.random.split(rng, 3)
-                ai = lax.axis_index(axis)
-                sub = jax.random.fold_in(sub, ai)
-                micro = maybe_preprocess(
-                    micro, jax.random.fold_in(pre_rng, ai))
+                with jax.named_scope(INPUT_SCOPE):
+                    rng, sub, pre_rng = jax.random.split(rng, 3)
+                    ai = lax.axis_index(axis)
+                    sub = jax.random.fold_in(sub, ai)
+                    micro = maybe_preprocess(
+                        micro, jax.random.fold_in(pre_rng, ai))
                 loss, params, grads = accum_grads(params, micro, sub)
-                grads = lax.pmean(grads, axis)
-                loss = lax.pmean(loss, axis)
-                if state_keys:
-                    # BN running stats diverge per shard; re-average those
-                    # blobs (and only those) so the replication the
-                    # out_spec claims over ``axis`` stays truthful
-                    params = {
-                        k: (lax.pmean(v, axis) if k in state_keys else v)
-                        for k, v in params.items()}
-                grads = preprocess_grads(sp, params, grads, lr_mults,
-                                         decay_mults)
-                rate = learning_rate(sp, it) * lr_scale
-                params, state = rule.apply(params, grads, state, rate, it,
-                                           lr_mults=lr_mults)
-                return (params, state, it + 1, rng), loss
+                with jax.named_scope(SYNC_SCOPE):
+                    grads = lax.pmean(grads, axis)
+                    loss = lax.pmean(loss, axis)
+                    if state_keys:
+                        # BN running stats diverge per shard; re-average
+                        # those blobs (and only those) so the replication
+                        # the out_spec claims over ``axis`` stays truthful
+                        params = {
+                            k: (lax.pmean(v, axis) if k in state_keys else v)
+                            for k, v in params.items()}
+                params, state = apply_update(sp, rule, params, grads, state,
+                                             it, lr_scale, lr_mults,
+                                             decay_mults)
+                with jax.named_scope(INPUT_SCOPE):
+                    it = it + 1
+                return (params, state, it, rng), loss
             return step
 
         def sync_body(params, state, it, batches, rng, lr_scale):
             """Per-step grad pmean (P2PSync semantics)."""
-            params = maybe_gather(params)
+            with jax.named_scope(INPUT_SCOPE):
+                params = maybe_gather(params)
+                micro = split_micro(batches)
             (params, state, it, _), losses = lax.scan(
                 make_psum_step(DATA_AXIS, lr_scale),
-                (params, state, it, rng), split_micro(batches))
-            if plan is not None:
-                # every position computed the same full update (per-step
-                # grad pmean); each keeps only its resident shard — a
-                # slice, zero communication, exact
-                params = plan.take_shard(params, DATA_AXIS)
-            return params, state, jnp.mean(losses)
+                (params, state, it, rng), micro)
+            with jax.named_scope(SYNC_SCOPE):
+                if plan is not None:
+                    # every position computed the same full update
+                    # (per-step grad pmean); each keeps only its resident
+                    # shard — a slice, zero communication, exact
+                    params = plan.take_shard(params, DATA_AXIS)
+                return params, state, jnp.mean(losses)
 
         # compressed exchange (comm_codec != "none"): the τ-boundary
         # weight pmean LEAVES the compiled round — the body returns each
@@ -623,36 +635,45 @@ class DistributedTrainer:
 
         def local_sgd_body(params, state, it, batches, rng, lr_scale):
             """τ local steps, then weight averaging (SparkNet semantics)."""
-            params = maybe_gather(params)
-            state = jax.tree_util.tree_map(lambda x: x[0], state)
-            rng = jax.random.fold_in(rng, lax.axis_index(DATA_AXIS))
-
             def step(carry, micro):
                 params, state, it, rng = carry
-                rng, sub, pre_rng = jax.random.split(rng, 3)
-                micro = maybe_preprocess(micro, pre_rng)
+                with jax.named_scope(INPUT_SCOPE):
+                    rng, sub, pre_rng = jax.random.split(rng, 3)
+                    micro = maybe_preprocess(micro, pre_rng)
                 params, state, loss = local_update(params, state, it, micro,
                                                    sub, lr_scale)
-                return (params, state, it + 1, rng), loss
+                with jax.named_scope(INPUT_SCOPE):
+                    it = it + 1
+                return (params, state, it, rng), loss
 
+            # what enters the scan reads as the steps' input.  The scan
+            # itself stays under no scope: the compiler names what it makes
+            # inside a loop after the loop, and that is not the program's
+            with jax.named_scope(INPUT_SCOPE):
+                params = maybe_gather(params)
+                state = jax.tree_util.tree_map(lambda x: x[0], state)
+                rng = jax.random.fold_in(rng, lax.axis_index(DATA_AXIS))
+                micro = split_micro(batches)
             (params, state, it, _), losses = lax.scan(
-                step, (params, state, it, rng), split_micro(batches))
-            # the scalar loss is not part of the compressed exchange (3
-            # bytes saved would not buy the lost logging fidelity), so it
-            # is pmean'd here on either path
-            loss = lax.pmean(jnp.mean(losses), DATA_AXIS)
-            if not compressed:
-                # the broadcast → reduce → scalarDivide of the reference's
-                # outer loop (ImageNetApp.scala:102,178-179), as one ICI
-                # collective:
-                if plan is None:
-                    params = lax.pmean(params, DATA_AXIS)
+                step, (params, state, it, rng), micro)
+            with jax.named_scope(AVERAGE_SCOPE):
+                # the scalar loss is not part of the compressed exchange (3
+                # bytes saved would not buy the lost logging fidelity), so
+                # it is pmean'd here on either path
+                loss = lax.pmean(jnp.mean(losses), DATA_AXIS)
+                if not compressed:
+                    # the broadcast → reduce → scalarDivide of the
+                    # reference's outer loop (ImageNetApp.scala:102,
+                    # 178-179), as one ICI collective:
+                    if plan is None:
+                        params = lax.pmean(params, DATA_AXIS)
+                    else:
+                        params = shard_boundary_mean(params, DATA_AXIS)
                 else:
-                    params = shard_boundary_mean(params, DATA_AXIS)
-            else:
-                params = jax.tree_util.tree_map(lambda x: x[None], params)
-            state = jax.tree_util.tree_map(lambda x: x[None], state)
-            return params, state, loss
+                    params = jax.tree_util.tree_map(lambda x: x[None],
+                                                    params)
+                state = jax.tree_util.tree_map(lambda x: x[None], state)
+                return params, state, loss
 
         def hierarchical_body(params, state, it, batches, rng, lr_scale):
             """Per-step grad pmean over chips (the P2PSync step over the
@@ -662,32 +683,38 @@ class DistributedTrainer:
             semantics: re-averaged per step over chips inside the psum
             step, averaged with the weights at the τ boundary over
             hosts."""
-            params = maybe_gather(params)
-            state = jax.tree_util.tree_map(lambda x: x[0], state)
-            rng = jax.random.fold_in(rng, lax.axis_index(HOST_AXIS))
+            with jax.named_scope(INPUT_SCOPE):
+                params = maybe_gather(params)
+                state = jax.tree_util.tree_map(lambda x: x[0], state)
+                rng = jax.random.fold_in(rng, lax.axis_index(HOST_AXIS))
+                micro = split_micro(batches)
             (params, state, it, _), losses = lax.scan(
                 make_psum_step(CHIP_AXIS, lr_scale),
-                (params, state, it, rng), split_micro(batches))
-            loss = lax.pmean(jnp.mean(losses), HOST_AXIS)
-            if not compressed:
-                # the cross-host averaging rides DCN once per τ steps —
-                # the broadcast → reduce → scalarDivide of the reference's
-                # outer loop (ImageNetApp.scala:102,178-179)
-                if plan is None:
-                    params = lax.pmean(params, HOST_AXIS)
+                (params, state, it, rng), micro)
+            with jax.named_scope(AVERAGE_SCOPE):
+                loss = lax.pmean(jnp.mean(losses), HOST_AXIS)
+                if not compressed:
+                    # the cross-host averaging rides DCN once per τ steps
+                    # — the broadcast → reduce → scalarDivide of the
+                    # reference's outer loop (ImageNetApp.scala:102,
+                    # 178-179)
+                    if plan is None:
+                        params = lax.pmean(params, HOST_AXIS)
+                    else:
+                        # slice the resident chip shard FIRST, then
+                        # average over hosts: the DCN collective moves
+                        # only shard bytes, and slice-then-mean ==
+                        # mean-then-slice elementwise, so parity holds
+                        params = plan.take_shard(params, CHIP_AXIS)
+                        params = lax.pmean(params, HOST_AXIS)
                 else:
-                    # slice the resident chip shard FIRST, then average
-                    # over hosts: the DCN collective moves only shard
-                    # bytes, and slice-then-mean == mean-then-slice
-                    # elementwise, so parity holds
-                    params = plan.take_shard(params, CHIP_AXIS)
-                    params = lax.pmean(params, HOST_AXIS)
-            else:
-                # chips within a host already agree (per-step chip psum);
-                # stack one copy per HOST for the compressed DCN exchange
-                params = jax.tree_util.tree_map(lambda x: x[None], params)
-            state = jax.tree_util.tree_map(lambda x: x[None], state)
-            return params, state, loss
+                    # chips within a host already agree (per-step chip
+                    # psum); stack one copy per HOST for the compressed
+                    # DCN exchange
+                    params = jax.tree_util.tree_map(lambda x: x[None],
+                                                    params)
+                state = jax.tree_util.tree_map(lambda x: x[None], state)
+                return params, state, loss
 
         bodies = {"local_sgd": local_sgd_body, "sync": sync_body,
                   "hierarchical": hierarchical_body}

@@ -152,6 +152,7 @@ class Solver:
         ``spec=None`` to remove augmentation again."""
         from ..ops.augment import augment_batch
         from ..utils import knobs
+        from .step import INPUT_SCOPE
         if device is None:
             device = knobs.get_bool("SPARKNET_AUG_DEVICE", True)
         self._augment_spec = spec
@@ -162,13 +163,15 @@ class Solver:
             spec_ = spec
 
             def step(params, state, it, batches, rng):
-                aug_rng, rng = jax.random.split(rng)
-                data = batches[blob]
-                i, n = data.shape[0], data.shape[1]
-                flat = data.reshape((i * n,) + data.shape[2:])
-                out = augment_batch(flat, aug_rng, spec_)
-                batches = dict(batches)
-                batches[blob] = out.reshape((i, n) + out.shape[1:])
+                # the glue round ``augment_batch``'s own ``L[augment]``
+                with jax.named_scope(INPUT_SCOPE):
+                    aug_rng, rng = jax.random.split(rng)
+                    data = batches[blob]
+                    i, n = data.shape[0], data.shape[1]
+                    flat = data.reshape((i * n,) + data.shape[2:])
+                    out = augment_batch(flat, aug_rng, spec_)
+                    batches = dict(batches)
+                    batches[blob] = out.reshape((i, n) + out.shape[1:])
                 return base(params, state, it, batches, rng)
         else:
             step = base

@@ -17,6 +17,25 @@ from ..proto.caffe_pb import SolverParameter
 from .lr_policies import learning_rate
 from .update_rules import SolverUpdate, preprocess_grads
 
+# The step's phases beside the layers', in the one namespace a profiler
+# trace is read by (``L[<layer>]``, graph/net.py): what the step does to its
+# input before the net sees it and to the micro-batches' sum; the gradient
+# preparation; the rate and the rule's update.
+INPUT_SCOPE = "L[step.input]"
+GRADS_SCOPE = "L[step.grads]"
+UPDATE_SCOPE = "L[step.update]"
+
+
+def apply_update(sp: SolverParameter, rule: SolverUpdate, params, grads,
+                 state, it, lr_scale, lr_mults, decay_mults):
+    """ClipGradients → Normalize → Regularize → the rule's update at the
+    policy's rate times ``lr_scale``: ``(params, state)``."""
+    with jax.named_scope(GRADS_SCOPE):
+        grads = preprocess_grads(sp, params, grads, lr_mults, decay_mults)
+    with jax.named_scope(UPDATE_SCOPE):
+        rate = learning_rate(sp, it) * lr_scale
+        return rule.apply(params, grads, state, rate, it, lr_mults=lr_mults)
+
 
 def make_step_fns(sp: SolverParameter, net: Net, rule: SolverUpdate,
                   lr_mults, decay_mults, remat: bool = False,
@@ -61,28 +80,34 @@ def make_step_fns(sp: SolverParameter, net: Net, rule: SolverUpdate,
     def accum_loss_and_grads(params, batches, rng):
         """``batches`` leaves carry a leading iter_size axis."""
         if sp.iter_size == 1:
-            batch = jax.tree_util.tree_map(lambda x: x[0], batches)
+            with jax.named_scope(INPUT_SCOPE):
+                batch = jax.tree_util.tree_map(lambda x: x[0], batches)
             return loss_and_grads(params, batch, rng)
 
+        # the scan's carry and the sum over micro-batches are the step's;
+        # the scan itself stays under no scope (the compiler names what it
+        # makes inside a loop after the loop)
         def body(carry, batch):
             params, acc, rng = carry
-            rng, sub = jax.random.split(rng)
+            with jax.named_scope(INPUT_SCOPE):
+                rng, sub = jax.random.split(rng)
             (loss, params), g = jax.value_and_grad(
                 fwd_in_scan, has_aux=True)(params, batch, sub)
-            acc = jax.tree_util.tree_map(jnp.add, acc, g)
+            with jax.named_scope(INPUT_SCOPE):
+                acc = jax.tree_util.tree_map(jnp.add, acc, g)
             return (params, acc, rng), loss
 
-        zero = jax.tree_util.tree_map(jnp.zeros_like, params)
+        with jax.named_scope(INPUT_SCOPE):
+            zero = jax.tree_util.tree_map(jnp.zeros_like, params)
         (params, grads, _), losses = jax.lax.scan(
             body, (params, zero, rng), batches)
-        return jnp.mean(losses), params, grads
+        with jax.named_scope(INPUT_SCOPE):
+            return jnp.mean(losses), params, grads
 
     def local_update(params, state, it, batches, rng, lr_scale=1.0):
         loss, params, grads = accum_loss_and_grads(params, batches, rng)
-        grads = preprocess_grads(sp, params, grads, lr_mults, decay_mults)
-        rate = learning_rate(sp, it) * lr_scale
-        params, state = rule.apply(params, grads, state, rate, it,
-                                   lr_mults=lr_mults)
+        params, state = apply_update(sp, rule, params, grads, state, it,
+                                     lr_scale, lr_mults, decay_mults)
         return params, state, loss
 
     return loss_and_grads, local_update, accum_loss_and_grads

@@ -47,7 +47,10 @@ def main(argv=None) -> None:
                     help="profile the FUSED fwd+bwd program and print the "
                          "per-layer device-time partition (L[...] scopes "
                          "via jax.profiler; the `caffe time` view that is "
-                         "actually true post-fusion)")
+                         "actually true post-fusion; a layer's row holds "
+                         "its casts, and in a trace of a whole step or "
+                         "round the step.* and round.* rows are phases, "
+                         "not layers)")
     ap.add_argument("--trace-dir", default=None,
                     help="keep the profiler trace here (default: temp)")
     args = ap.parse_args(argv)

@@ -322,7 +322,9 @@ def op_tables(log_dir: str, *, top: int = 30,
     # per-layer attribution when the program was built with the net
     # executor's L[...] named scopes (fused ops are attributed to the
     # fusion root's scope — post-fusion reality, unlike `caffe time`'s
-    # pre-fusion per-layer timers)
+    # pre-fusion per-layer timers).  Rows ``step.*`` and ``round.*`` are
+    # phases of the step and of the round, not layers: solvers/step.py
+    # and parallel/trainer.py open them
     if any(e.meta.layer() for e in leaf):
         out["by_layer"] = agg(lambda m: m.layer() or "(outside layers)")
     return out
