@@ -41,6 +41,19 @@ once a trace (``attn_lowering_total{path, mask, backward, blocks}``,
   multiplied group by group, on a TPU by JAX's ``megablox`` grouped matrix
   kernels (``path=gmm``), elsewhere by ``jax.lax.ragged_dot``
   (``path=ragged_dot``).
+
+The route of an expert layer (scope ``moe_route``; one path on every
+backend) is made once a step and kept: ``moe_route`` runs outside what the
+layer's backward pass computes again, and what it keeps is the tokens'
+picks and chosen scores, the rows' token, weight and slot and the groups'
+sizes, a few MB a layer (the ``[tokens, experts]`` scores are not kept:
+their product runs again for the sigmoid's derivative).  Whatever is as
+many as ``tokens * top_k`` is counted and selected densely on the vector
+unit (a comparison against ``arange``, never a scatter or a gather of
+scalars, which a TPU prices by the element); one sort carries the slots and
+the weights with the keys; and rows of the hidden width are the only thing a
+gather or a scatter moves: ``x[token]`` in, the weighted outputs added back
+in float32, and each one's transpose in the backward pass.
 """
 
 from __future__ import annotations
@@ -557,6 +570,86 @@ def moe_row_bound(tokens: int, g: dict) -> int:
     return min(most, -(-math.ceil(1.25 * even) // _GMM_ROWS) * _GMM_ROWS)
 
 
+def _router_scores(x, w_router):
+    """``sigmoid(x W_r)`` in float32, the product at ``HIGHEST``; run again
+    in the backward pass for the sigmoid's derivative, so that what is kept
+    of it is ``x`` as it came."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return jax.nn.sigmoid(logits)
+
+
+def _chosen(scores, top_i):
+    """``scores[t, top_i[t, j]]`` as a dense selection over ``[tokens,
+    top_k, experts]``, never in memory: one fusion on the vector unit whose
+    transpose is the same selection, where a gather's would be a scatter of
+    ``tokens * top_k`` scalars."""
+    hit = top_i[..., None] == jnp.arange(scores.shape[-1], dtype=top_i.dtype)
+    return jnp.sum(jnp.where(hit, scores[:, None, :], 0.0), axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _by_expert(key, weight, rows: int):
+    """The first ``rows`` of ``(key, slot, weight)`` sorted by ``key``, the
+    lower slot first among equal keys: one sort that carries its payload.
+    ``key`` and ``weight`` are ``[tokens, top_k]`` and a slot is a position
+    in them flattened."""
+    flat = key.reshape(-1)
+    slot = jax.lax.iota(jnp.int32, flat.shape[0])
+    skey, slot, weight = jax.lax.sort((flat, slot, weight.reshape(-1)),
+                                      num_keys=1, is_stable=True)
+    return skey[:rows], slot[:rows], weight[:rows]
+
+
+def _by_expert_fwd(key, weight, rows):
+    out = _by_expert(key, weight, rows)
+    return out, (out[1], key.shape)
+
+
+def _by_expert_bwd(rows, saved, cot):
+    # the weights go back to where they were taken from, ``rows`` of them
+    # and a token's ``top_k`` at a time: the sort's own transpose would
+    # scatter ``tokens * top_k`` scalars
+    slot, (tokens, k) = saved
+    mine = (slot % k)[:, None] == jnp.arange(k, dtype=slot.dtype)
+    back = jnp.zeros((tokens, k), cot[2].dtype).at[slot // k].add(
+        jnp.where(mine, cot[2][:, None], 0.0))
+    return None, back
+
+
+_by_expert.defvjp(_by_expert_fwd, _by_expert_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(y, w, token, shared, tokens: int):
+    """``shared + sum over a token's rows of w * y`` in float32, in
+    ``y``'s dtype: the rows' way back to their tokens (``shared`` is the
+    shared expert's output ``[tokens, hidden]``, or None)."""
+    y32 = y.astype(jnp.float32) * w[:, None]
+    routed = jnp.zeros((tokens, y.shape[-1]), jnp.float32).at[token].add(y32)
+    if shared is not None:
+        routed = routed + shared
+    return routed.astype(y.dtype)
+
+
+def _combine_fwd(y, w, token, shared, tokens):
+    return _combine(y, w, token, shared, tokens), (y, w, token,
+                                                   shared is None)
+
+
+def _combine_bwd(tokens, saved, cot):
+    # the cotangent's rows are taken as they come, in the compute dtype:
+    # no float32 copy of every token's row is made to gather from
+    y, w, token, alone = saved
+    g = cot[token].astype(jnp.float32)
+    dy = (g * w[:, None]).astype(y.dtype)
+    dw = jnp.sum(g * y.astype(jnp.float32), axis=-1)
+    return dy, dw, None, None if alone else cot
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def moe_route(x, w_router, g: dict, bias=None):
     """Route ``x [tokens, hidden]`` over all the experts and list the rows
     the held ones compute.  Scores are ``sigmoid(x W_r)`` in float32; a
@@ -566,34 +659,40 @@ def moe_route(x, w_router, g: dict, bias=None):
     over their sum plus ``g["eps"]``, times ``scaling``.  Returns (token
     index, weight, rows of each held expert as sized for the products) of
     the ``moe_row_bound`` rows sorted by expert, then (rows each held
-    expert was sent, rows left out because the bound bound)."""
+    expert was sent, rows left out because the bound bound).
+
+    Differentiable in ``x`` and ``w_router`` through the weights; the picks
+    are constants of the backward pass.  Kept for it: ``x`` and
+    ``w_router`` as they came (the scores' product runs again), the picks
+    and their scores ``[tokens, top_k]``, each token's sum, and the rows'
+    slots; nothing of size ``[tokens, experts]``."""
     tokens, held, k = x.shape[0], g["hi"] - g["lo"], g["top_k"]
     if g.get("detached"):
         x = jax.lax.stop_gradient(x)
-    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    if bias is None:
-        top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), k)
-    else:
-        scores = jax.nn.sigmoid(logits)
-        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
-        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    scores = jax.checkpoint(_router_scores)(x, w_router)
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, top_i = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
+    top_s = _chosen(scores, top_i)
     total = jnp.sum(top_s, axis=-1, keepdims=True)
     if g.get("eps"):
         total = total + g["eps"]
     weight = top_s / total * g["scaling"]
     here = (top_i >= g["lo"]) & (top_i < g["hi"])
-    key = jnp.where(here, top_i - g["lo"], held).reshape(-1)
+    key = jnp.where(here, top_i - g["lo"], held)
     rows = moe_row_bound(tokens, g)
-    order = jnp.argsort(key, stable=True)[:rows]
-    sent = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    skey, slot, w = _by_expert(key, weight, rows)
+    # counted by comparison on the vector unit, not by a scatter of ones
+    sent = jnp.sum(key[..., None] == jnp.arange(held, dtype=key.dtype),
+                   axis=(0, 1), dtype=jnp.int32)
     ends = jnp.minimum(jnp.cumsum(sent), rows)
     sized = jnp.diff(ends, prepend=0)
     # the rows past the last one sent go to the last expert with weight 0,
-    # so the products do the same work whatever the router chose
-    sized = sized.at[-1].add(rows - ends[-1])
-    w = jnp.where(key[order] < held, weight.reshape(-1)[order], 0.0)
-    return order // k, w, sized, sent, jnp.sum(sent) - ends[-1]
+    # so the products do the same work whatever the router chose (added by
+    # a mask: ``.at[-1].add`` is one more scatter of a scalar)
+    sized = sized + jnp.where(jnp.arange(held) == held - 1,
+                              rows - ends[-1], 0)
+    w = jnp.where(skey < held, w, 0.0)
+    return slot // k, w, sized, sent, jnp.sum(sent) - ends[-1]
 
 
 def moe_lowering(rows: int, hidden: int, width: int) -> str:
@@ -676,25 +775,30 @@ class MixtureOfExpertsLayer(LayerImpl):
         shape = bottoms[0].shape
         path = moe_lowering(moe_row_bound(math.prod(shape[:-1]), g),
                             shape[-1], params[1].shape[-1])
+        x = bottoms[0].reshape(-1, shape[-1])
+        wr, eg, eu, ed, *rest = params
+        bias = rest[-1] if g["select_bias"] else None
+        # routed once a step: the route is outside what the backward pass
+        # computes again, and its few MB are what is kept of it
+        with jax.named_scope("moe_route"):
+            token, w, sized, _, _ = moe_route(x, wr, g, bias)
 
-        def moe(x, wr, eg, eu, ed, *rest):
-            bias = rest[-1] if g["select_bias"] else None
+        def experts(x, token, w, sized, eg, eu, ed, *shared):
             with jax.named_scope("moe_route"):
-                token, w, sized, _, _ = moe_route(x, wr, g, bias)
                 rows = x[token]
             with jax.named_scope("moe_experts"):
                 y = _grouped(_swiglu(_grouped(rows, eg, sized, path),
                                      _grouped(rows, eu, sized, path)),
                              ed, sized, path)
+            also = None
+            if shared:
+                sg, su, sd = shared
+                also = _swiglu(x @ sg, x @ su) @ sd
             with jax.named_scope("moe_route"):
-                y = y.astype(jnp.float32) * w[:, None]
-                routed = jnp.zeros(x.shape, jnp.float32).at[token].add(y)
-            if not g["shared"]:
-                return routed.astype(x.dtype)
-            sg, su, sd = rest[:3]
-            return (routed + (_swiglu(x @ sg, x @ su) @ sd)).astype(x.dtype)
+                return _combine(y, w, token, also, x.shape[0])
 
-        out = jax.checkpoint(moe)(bottoms[0].reshape(-1, shape[-1]), *params)
+        out = jax.checkpoint(experts)(x, token, w, sized, eg, eu, ed,
+                                      *(rest[:3] if g["shared"] else ()))
         return [out.reshape(shape)]
 
 

@@ -779,6 +779,139 @@ def test_the_eight_biased_shares_add_up_to_the_uncut_layer():
     close(total, uncut, 1e-5)
 
 
+# -- the route and the layer against the form they had ----------------------
+
+def oracle_route(x, w_router, g, bias=None):
+    """``moe_route`` as it stood before PR 40: an ``argsort``, a
+    ``bincount``, ``top_k``'s own values (``take_along_axis`` under a
+    bias) and scalar gathers by ``order``."""
+    tokens, held, k = x.shape[0], g["hi"] - g["lo"], g["top_k"]
+    if g.get("detached"):
+        x = jax.lax.stop_gradient(x)
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    total = jnp.sum(top_s, axis=-1, keepdims=True)
+    if g.get("eps"):
+        total = total + g["eps"]
+    weight = top_s / total * g["scaling"]
+    here = (top_i >= g["lo"]) & (top_i < g["hi"])
+    key = jnp.where(here, top_i - g["lo"], held).reshape(-1)
+    rows = seq.moe_row_bound(tokens, g)
+    order = jnp.argsort(key, stable=True)[:rows]
+    sent = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(sent), rows)
+    sized = jnp.diff(ends, prepend=0)
+    sized = sized.at[-1].add(rows - ends[-1])
+    w = jnp.where(key[order] < held, weight.reshape(-1)[order], 0.0)
+    return order // k, w, sized, sent, jnp.sum(sent) - ends[-1]
+
+
+def oracle_layer(g, params, x):
+    """The whole layer in that form: ``x[token]``, the grouped products,
+    ``zeros.at[token].add``, the shared expert added last."""
+    wr, eg, eu, ed, *rest = params
+    flat = x.reshape(-1, x.shape[-1])
+    token, w, sized, _, _ = oracle_route(
+        flat, wr, g, rest[-1] if g["select_bias"] else None)
+    rows = flat[token]
+    y = jax.lax.ragged_dot(
+        seq._swiglu(jax.lax.ragged_dot(rows, eg, sized),
+                    jax.lax.ragged_dot(rows, eu, sized)), ed, sized)
+    routed = jnp.zeros(flat.shape, jnp.float32).at[token].add(
+        y.astype(jnp.float32) * w[:, None])
+    if g["shared"]:
+        sg, su, sd = rest[:3]
+        routed = routed + (seq._swiglu(flat @ sg, flat @ su) @ sd)
+    return routed.astype(x.dtype).reshape(x.shape)
+
+
+def _route_case(name):
+    """(moe_param, tokens, what to do to the filled router) of a case."""
+    laguna = {"num_experts": 256, "top_k": 8, "experts_held_lo": 0,
+              "experts_held_hi": 32, "expert_width": 16, "shared_width": 24,
+              "routed_scaling": 2.5}
+    lfm2 = {"num_experts": 64, "top_k": 4, "experts_held_lo": 8,
+            "experts_held_hi": 16, "expert_width": 16, "shared_width": 0,
+            "routed_scaling": 1.0, "norm_eps": 1e-6, "select_bias": True,
+            "select_bias_filler": {"type": "gaussian", "std": 0.1}}
+    small = {"num_experts": 16, "top_k": 4, "experts_held_lo": 0,
+             "experts_held_hi": 4, "expert_width": 16, "shared_width": 24,
+             "routed_scaling": 1.0}
+    ones = 0.02 * jnp.ones((HIDDEN, 1))     # logits of about a half
+    return {
+        "laguna": (laguna, 96, lambda wr: wr),
+        "lfm2": (lfm2, 96, lambda wr: wr),
+        # every column the same: every score ties, the lower index wins
+        "ties": (laguna, 48, lambda wr: jnp.tile(wr[:, :1], (1, 256))),
+        # every token takes all four held experts: 2,048 picks, 1,024 rows
+        "bound": (small, 512, lambda wr: jnp.concatenate(
+            [jnp.tile(ones, (1, 4)), -jnp.tile(ones, (1, 12))], 1)),
+        # of the held experts only number 2 is ever taken
+        "collapsed": (small, 64, lambda wr: jnp.concatenate(
+            [-ones, -ones, ones, -ones, jnp.tile(ones, (1, 3)),
+             -jnp.tile(ones, (1, 9))], 1)),
+    }[name]
+
+
+@pytest.mark.parametrize("detach", [False, True],
+                         ids=["attached", "detached"])
+@pytest.mark.parametrize("case", ["laguna", "lfm2", "ties", "bound",
+                                  "collapsed"])
+def test_route_and_layer_are_what_they_were(case, detach):
+    """``moe_route`` and the whole layer against the oracle above: rows,
+    counts and what the bound leaves out exactly, weights, output and every
+    gradient to float32 rounding; the bias gets none."""
+    param, tokens, edit = _route_case(case)
+    lp = layer("moe", "MixtureOfExperts", ["x"], ["y"], moe_param={
+        **param, "weight_filler": _GAUSS, "router_filler": _GAUSS,
+        "detach_router": detach})
+    g = seq.moe_geometry(lp)
+    impl, params = init_and_apply(lp, (2, tokens // 2, HIDDEN), key=3)
+    params[0] = edit(params[0])
+    bias = params[-1] if g["select_bias"] else None
+    x = jax.random.normal(jax.random.PRNGKey(50), (2, tokens // 2, HIDDEN))
+    if case in ("bound", "collapsed"):
+        x = jnp.abs(x)                # the sign of x . 1 is the column's
+    flat = x.reshape(-1, HIDDEN)
+
+    got = seq.moe_route(flat, params[0], g, bias)
+    want = oracle_route(flat, params[0], g, bias)
+    for name, a, b in zip(("token", "w", "sized", "sent", "dropped"), got,
+                          want):
+        if name == "w":
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=0)
+        else:
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    dropped, sent = int(got[4]), np.asarray(got[3])
+    assert (dropped > 0) == (case == "bound")
+    if case == "collapsed":
+        assert sent.tolist() == [0, 0, tokens, 0]
+    if case == "ties":
+        assert sent.tolist() == [tokens] * 8 + [0] * 24
+
+    system = lambda p, x: impl.apply(lp, p, [x], True, None)[0]
+    oracle = lambda p, x: oracle_layer(g, p, x)
+    close(system(params, x), oracle(params, x), 1e-6)
+    cot = jax.random.normal(jax.random.PRNGKey(51), x.shape)
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   (0, 1))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(oracle(p, x) * cot),
+                    (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(a, b, 1e-5)
+    assert float(jnp.abs(got[0][0]).max()) > 0      # the router's gradient
+    if bias is not None:
+        assert float(jnp.abs(got[0][-1]).max()) == 0.0
+
+
 def test_transposed_head_is_the_head_on_the_transpose():
     """``transposed`` stores the head ``[vocab, hidden]``, an ``Embed``
     table's shape: loss, logits and gradient are the plain head's on the

@@ -17,6 +17,7 @@ legalisation of bfloat16 products, broadcasts, copies) and the reducers of a
 
 import contextlib
 import functools
+import importlib.util
 import json
 import os
 import re
@@ -135,6 +136,19 @@ def token_solver():
     return solver, {"tokens": jnp.zeros((1, 2, 16), jnp.int32)}
 
 
+def laguna_solver():
+    """Laguna's builder at toy widths: expert layers with a shared expert
+    and no selection bias, where ``token_solver``'s have a bias and none."""
+    with open(os.path.join(REPO, "benchmark", "tests", "data",
+                           "laguna_tiny.json")) as f:
+        args = json.load(f)["builder_args"]
+    sp = load_solver_prototxt_with_net(
+        ADAM + "clip_gradients: 1.0\n",
+        models.laguna(2, 1, seq_len=16, **args))
+    solver = Solver(sp, seed=0, compute_dtype=jnp.bfloat16)
+    return solver, {"tokens": jnp.zeros((1, 2, 16), jnp.int32)}
+
+
 def lower_step(solver, batch):
     return solver._step.lower(solver.params, solver.state, 0, batch,
                               jax.random.PRNGKey(0))
@@ -166,14 +180,24 @@ PROGRAMS = {
     "round_local_sgd": lambda: lower_round("local_sgd"),
     "round_sync": lambda: lower_round("sync"),
 }
+# a second token step for the expert layers' test alone: what the tests over
+# ``PROGRAMS`` expect of a token step is written for the first
+LAGUNA = {"tokens_laguna": lambda: lower_step(*laguna_solver())}
+
+
 @functools.cache
 def lowered(program: str):
-    return PROGRAMS[program]()
+    return (PROGRAMS | LAGUNA)[program]()
+
+
+@functools.cache
+def compiled_text(program: str) -> str:
+    return lowered(program).compile().as_text()
 
 
 @functools.cache
 def paths_of(program: str) -> list[str]:
-    return traced_paths(lowered(program).compile().as_text())
+    return traced_paths(compiled_text(program))
 
 
 # JAX moves what a scan's body computes from the scan's constants alone out
@@ -320,3 +344,65 @@ def test_without_locations_the_lowered_text_is_the_same_without_scopes(
     bare = PROGRAMS[program]()
     assert "L[" not in bare.as_text(debug_info=True).replace("L[augment]", "")
     assert bare.as_text() == scoped.as_text()
+
+
+# -- what an expert layer moves, and how often ----------------------------------
+
+@functools.cache
+def lowered_text():
+    spec = importlib.util.spec_from_file_location(
+        "lowered_text", os.path.join(REPO, "tools", "lowered_text.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("program", ["tokens", "tokens_laguna"])
+def test_an_expert_layer_routes_once_and_moves_rows(program):
+    """``tools/lowered_text.py``'s ``moe_moves`` on the compiled step: under
+    ``moe_route`` no scatter moves a scalar at a time, the backward pass
+    makes no route again (no ``sort`` and no ``top_k`` in a recomputed
+    forward), and a layer adds rows of the hidden width into place twice a
+    step: the experts' outputs into their tokens, the rows' gradient into
+    the input's."""
+    moves = lowered_text().moe_moves(compiled_text(program))
+    layers = sorted({m["layer"] for m in moves})
+    assert len(layers) == 4 and all(n.endswith("/moe") for n in layers)
+    for name in layers:
+        mine = [m for m in moves if m["layer"] == name
+                and m["scope"] == "moe_route"]
+        scatters = [m for m in mine if m["opcode"] == "scatter"]
+        assert [m for m in scatters if not m["updates"]] == []
+        assert [m for m in mine if m["pass"] == "remat"
+                and m["opcode"] in ("sort", "topk")] == []
+        # the route is there, once: its top_k and its one sort
+        assert [m["opcode"] for m in mine if m["pass"] == "fwd"
+                and m["opcode"] in ("sort", "topk")
+                and m["primitive"] in ("sort", "top_k")] == ["topk", "sort"]
+        rows = [m for m in scatters if m["updates"] == [32]]    # hidden
+        assert sorted(m["pass"] for m in rows) == ["bwd", "fwd"]
+        # what else is scattered is the weights' way back: top_k at a time
+        assert all(m["pass"] == "bwd" for m in scatters if m not in rows)
+
+
+def test_moe_moves_reads_a_scalar_scatter_and_a_recomputed_sort():
+    """The reader on text of the form the expert layer had: it finds the
+    count's scatter of scalars and the sort inside the recomputation."""
+    hlo = """
+ENTRY %main (p: s32[64]) -> s32[9] {
+  %p = s32[64]{0} parameter(0)
+  %ones = s32[64]{0} constant({...})
+  %zeros = s32[9]{0} constant({...})
+  %scatter-add.1 = s32[9]{0} scatter(%zeros, %p, %ones), update_window_dims={}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%add, metadata={op_name="jit(step)/transpose(jvp(L[L1/moe]))/checkpoint/rematted_computation/moe_route/scatter-add"}
+  %sort.2 = (s32[64]{0}, s32[64]{0}) sort(%p, %ones), dimensions={0}, is_stable=true, to_apply=%lt, metadata={op_name="jit(step)/transpose(jvp(L[L1/moe]))/checkpoint/rematted_computation/moe_route/jit(argsort)/sort"}
+  %gather.3 = bf16[64,32]{1,0} gather(%x, %p), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,32}, metadata={op_name="jit(step)/jvp(L[L1/moe])/moe_route/gather"}
+  %sort.4 = (s32[64]{0}, s32[64]{0}) sort(%p, %ones), dimensions={0}, to_apply=%lt, metadata={op_name="jit(step)/jvp(L[L2/attn])/sort"}
+}
+"""
+    moves = lowered_text().moe_moves(hlo)
+    assert [(m["layer"], m["pass"], m["opcode"], m["primitive"],
+             m["updates"]) for m in moves] == [
+        ("L1/moe", "remat", "scatter", "scatter-add", []),
+        ("L1/moe", "remat", "sort", "sort", None),
+        ("L1/moe", "fwd", "gather", "gather", [32])]
+

@@ -442,6 +442,57 @@ def partial_dq_sums(ops):
             and re.search(_DQ_SUM, o["op_name"])]
 
 
+_MOE_SCOPE = re.compile(r"L\[([^\]]+)\].*?/(moe_route|moe_experts)(/|$)")
+_MOVES = {"scatter", "gather", "sort", "topk"}
+
+
+def moe_moves(hlo: str):
+    """The ``scatter``, ``gather``, ``sort`` and ``topk`` instructions of a
+    compiled module's text under an expert layer's sub-scopes
+    (``moe_route``, ``moe_experts``), inside fusions too: ``{"layer",
+    "scope", "pass" (fwd, remat, bwd), "opcode", "primitive" (the JAX
+    primitive the instruction came from: the compiler's own sort of a
+    scatter's indices reads ``scatter-add``), "name", "shape", "updates"}``
+    each, ``updates`` the shape of one update of a scatter (``[]``: a
+    scalar) or of one slice of a gather.  What moves a scalar at a time on
+    a TPU costs by the element, whatever the bytes."""
+    shapes, out = {}, []
+    for line in _one_line_each(hlo).splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, opcode, rest = m.groups()
+        shapes[name] = shape        # operands are printed before their users
+        if opcode == "custom-call" and "TopK" in rest:
+            opcode = "topk"
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        scope = _MOE_SCOPE.search(op_name.group(1)) if op_name else None
+        if opcode not in _MOVES or not scope:
+            continue
+        op_name, updates = op_name.group(1), None
+        if opcode == "scatter":
+            operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+            window = re.search(r"update_window_dims=\{([\d,]*)\}", rest)
+            dims = _ARRAY.search(shapes.get(operands[-1], ""))
+            if window and dims:
+                sizes = [int(d) for d in dims.group(2).split(",") if d]
+                updates = [sizes[int(i)] for i in
+                           filter(None, window.group(1).split(","))
+                           if sizes[int(i)] > 1]
+        elif opcode == "gather":
+            sizes = re.search(r"slice_sizes=\{([\d,]*)\}", rest)
+            updates = [int(d) for d in sizes.group(1).split(",")
+                       if d and int(d) > 1] if sizes else None
+        out.append({
+            "layer": scope.group(1), "scope": scope.group(2),
+            "pass": ("remat" if "rematted_computation" in op_name else
+                     "bwd" if "transpose(" in op_name else "fwd"),
+            "opcode": opcode,
+            "primitive": op_name.rstrip("/").rsplit("/", 1)[-1],
+            "name": name, "shape": shape, "updates": updates})
+    return out
+
+
 def edges(args) -> int:
     """``--set edges``: compile the cells' programs and list the layout
     copies at the edges of the Pallas kernels; exit 1 if any."""
@@ -461,7 +512,8 @@ def edges(args) -> int:
                         "copy_bytes": sum(c["bytes"] for c in copies),
                         "written": written,
                         "conv_copies": scope_copies(ops, _CONV_SCOPE),
-                        "dq_sums": partial_dq_sums(ops)}
+                        "dq_sums": partial_dq_sums(ops),
+                        "moe_moves": moe_moves(hlo)}
     print(json.dumps({"root": args.root, "backend": jax.default_backend(),
                       "devices": args.devices, "edges": report}),
           flush=True)
