@@ -22,3 +22,4 @@ from .googlenet import googlenet
 from .vgg import vgg16
 from .laguna import laguna
 from .lfm2 import lfm2
+from .deepseek_v2 import deepseek_v2

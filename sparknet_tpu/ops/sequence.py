@@ -1,5 +1,5 @@
-"""Sequence layers: RMSNorm, Attention, ShortConv, GatedMLP,
-MixtureOfExperts, LMHeadLoss.
+"""Sequence layers: RMSNorm, Attention, LatentAttention, ShortConv,
+GatedMLP, MixtureOfExperts, LMHeadLoss.
 
 The layer types a decoder-only language model needs beside ``Embed`` and
 ``Eltwise`` (ROADMAP R3): blobs are ``[sequences, positions, width]`` and
@@ -13,10 +13,11 @@ run one sequence at a time (``jax.lax.map``), so what a step keeps between
 the passes is the blobs between layers and what it holds at once is one
 sequence's projections, not a batch's.
 
-Layout.  Between its projections and its flash kernels the attention layer
+Layout.  Between its projections and its flash kernels an attention layer
 keeps one shape, one layout and one width: ``[kv heads, query heads a kv
 head, positions, head_dim]`` (keys and values without the second), head_dim
-on the lanes and positions on the sublanes, in the compute dtype.  The
+on the lanes and positions on the sublanes, in the compute dtype (the latent
+layer's heads are key/value heads of one query head each).  The
 products write and read it as it is (``_heads_major``), rotary turns a head
 by a product and not by slicing it, and float32 lives only inside a fusion.
 
@@ -32,11 +33,13 @@ once a trace (``attn_lowering_total{path, mask, backward, blocks}``,
   score matrix, at heads of 128 and of 64 alike; elsewhere masked scores
   in XLA (``path=xla``), which a TPU refuses where they would not fit.
   The kernels' blocks and their backward form are one function of
-  ``(positions, window, head_dim)``, ``flash_blocks``: the block size at
-  which the blocks the mask leaves cost least once a grid step is priced,
-  and the fused ``dkv``/``dq`` kernel where a causal layer can hold its
-  partial ``dq``, the split kernels otherwise and under every window;
-  the counter's sample carries ``mask``, ``backward`` and ``blocks``;
+  ``(positions, window, head_dim, head_dim_v)``, ``flash_blocks``: the
+  block size at which the blocks the mask leaves cost least once a grid
+  step is priced, and the fused ``dkv``/``dq`` kernel where a causal layer
+  can hold its partial ``dq``, its query block as large as its VMEM takes
+  at these heads, the split kernels otherwise and under every window; the
+  counter's sample carries ``mask``, ``backward`` and ``blocks``, and
+  ``head_dim`` and ``v_head_dim`` where the two differ;
 - the experts' products (scope ``moe_experts``): rows sorted by expert and
   multiplied group by group, on a TPU by JAX's ``megablox`` grouped matrix
   kernels (``path=gmm``), elsewhere by ``jax.lax.ragged_dot``
@@ -142,64 +145,72 @@ def rope_inv_freq(rotary_dim: int, theta: float, yarn_factor: float = 0.0,
         + (1.0 / pos_freqs) * keep
 
 
-def _rotate_half_matrix(head_dim: int, half: int, dtype) -> jnp.ndarray:
+def _rotate_half_matrix(head_dim: int, half: int, dtype,
+                        offset: int = 0) -> jnp.ndarray:
     """The ``[head_dim, head_dim]`` matrix of 0 and ±1 with ``x @ R`` =
-    ``[-x2, x1, 0]`` for ``x = [x1, x2, rest]``, x1 and x2 of ``half``."""
+    ``[0, -x2, x1, 0]`` for ``x = [lead, x1, x2, rest]``, x1 and x2 of
+    ``half`` and ``lead`` of ``offset``."""
     r = np.zeros((head_dim, head_dim), np.float32)
-    i = np.arange(half)
+    i = np.arange(half) + offset
     r[i + half, i] = -1.0
     r[i, i + half] = 1.0
     return jnp.asarray(r, dtype)
 
 
-def _rotate(x, inv_freq: tuple, factor: float, scale: float, sign: float):
+def _rotate(x, inv_freq: tuple, factor: float, scale: float, sign: float,
+            offset: int = 0):
     """``(x * cos + rotate_half(x) * sign * sin) * scale`` in float32, cos
     and sin ``[positions, head_dim]`` tables that read 1 and 0 on the
-    dimensions past the rotated ones."""
+    dimensions before ``offset`` and past the rotated ones."""
     s, d = x.shape[-2:]
     half = len(inv_freq)
     pos = jnp.arange(s, dtype=jnp.float32)
     ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
-    rest = d - 2 * half
+    rest = d - 2 * half - offset
     cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * (sign * factor)
     cos = jnp.concatenate([cos, cos, jnp.ones((s, rest), jnp.float32)], -1)
     sin = jnp.concatenate([sin, sin, jnp.zeros((s, rest), jnp.float32)], -1)
+    if offset:
+        cos = jnp.concatenate([jnp.ones((s, offset), jnp.float32), cos], -1)
+        sin = jnp.concatenate([jnp.zeros((s, offset), jnp.float32), sin], -1)
     # one term a column, so the product is exact in any dtype: the half
     # turn stays on the lanes and no head is sliced
-    turned = jnp.matmul(x, _rotate_half_matrix(d, half, x.dtype),
+    turned = jnp.matmul(x, _rotate_half_matrix(d, half, x.dtype, offset),
                         precision=jax.lax.Precision.HIGHEST)
     out = x.astype(jnp.float32) * cos + turned.astype(jnp.float32) * sin
     return (out * scale).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _rope(x, inv_freq, factor, scale):
-    return _rotate(x, inv_freq, factor, scale, 1.0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _rope(x, inv_freq, factor, scale, offset):
+    return _rotate(x, inv_freq, factor, scale, 1.0, offset)
 
 
 # a rotation's transpose is the rotation by the negative angle: the
 # backward pass is the forward's one fusion on the cotangent
 _rope.defvjp(
-    lambda x, inv_freq, factor, scale: (_rope(x, inv_freq, factor, scale),
-                                        None),
-    lambda inv_freq, factor, scale, _, g: (
-        _rotate(g, inv_freq, factor, scale, -1.0),))
+    lambda x, inv_freq, factor, scale, offset: (
+        _rope(x, inv_freq, factor, scale, offset), None),
+    lambda inv_freq, factor, scale, offset, _, g: (
+        _rotate(g, inv_freq, factor, scale, -1.0, offset),))
 
 
-def apply_rope(x, inv_freq: np.ndarray, factor: float, scale: float = 1.0):
-    """Rotate the first ``2 * len(inv_freq)`` of the last axis of
-    ``x [..., positions, head_dim]`` by position, halves paired as
-    ``transformers`` pairs them; ``factor`` multiplies cos and sin.  In
-    float32; ``scale`` (the score scale, on queries) rides along."""
+def apply_rope(x, inv_freq: np.ndarray, factor: float, scale: float = 1.0,
+               offset: int = 0):
+    """Rotate ``2 * len(inv_freq)`` of the last axis of ``x [...,
+    positions, head_dim]``, from ``offset`` on, by position, halves paired
+    as ``transformers`` pairs them; ``factor`` multiplies cos and sin.  In
+    float32; ``scale`` (the score scale, on queries) rides along, over the
+    whole head."""
     return _rope(x, tuple(float(f) for f in inv_freq), float(factor),
-                 float(scale))
+                 float(scale), int(offset))
 
 
 # -- attention ---------------------------------------------------------------
 
 def _attn_core_xla(q, k, v, window: int):
-    """q [kv, group, S, D], k and v [kv, S, D] -> [kv, group, S, D]:
-    masked scores, softmax in float32, weighted sum."""
+    """q [kv, group, S, D], k [kv, S, D], v [kv, S, Dv] -> [kv, group, S,
+    Dv]: masked scores, softmax in float32, weighted sum."""
     s = q.shape[2]
     scores = jnp.einsum("kgsd,ktd->kgst", q, k,
                         preferred_element_type=jnp.float32)
@@ -244,6 +255,11 @@ _STEP_SCORES = 120_000
 # of at most this many keys (what its VMEM takes round a compute block)
 _FUSED_DQ_COPIES = 4
 _FUSED_KV_MOST = 2048
+# what one step of the fused kernel holds in VMEM, in lane-padded elements
+# of its blocks' rows (``_fused_rows``): 1,441,792 at heads of 128 over
+# blocks of 1,024 x 2,048 compile; 2,228,224 at 192/128 are refused (17.9
+# of 16 MB at 16 heads), 1,900,544 over 512 x 2,048 compile
+_FUSED_ROWS_MOST = 2_000_000
 _INTERPRET = False      # tests patch this: the kernels in Pallas' interpreter
 
 
@@ -257,8 +273,18 @@ def _blocks_seen(positions: int, window: int, block: int) -> int:
     return seen
 
 
-def flash_blocks(positions: int, window: int,
-                 head_dim: int) -> FlashBlocks | None:
+def _fused_rows(block_q: int, block_kv: int, head_dim: int,
+                head_dim_v: int) -> int:
+    """Lane-padded elements of the rows one step of the fused ``dkv``/``dq``
+    kernel holds: a query block's q, partial dq and output cotangent, a
+    key/value block's k and v and the gradients it accumulates for them."""
+    lanes = lambda w: -(-w // 128) * 128
+    d, dv = lanes(head_dim), lanes(head_dim_v)
+    return block_q * (2 * d + dv) + 2 * block_kv * (d + dv)
+
+
+def flash_blocks(positions: int, window: int, head_dim: int,
+                 head_dim_v: int | None = None) -> FlashBlocks | None:
     """The blocks and the backward form of the flash kernels for one
     sequence of ``positions`` under a causal mask (``window`` 0) or a
     sliding one, or None where they cannot tile it.  One rule:
@@ -274,15 +300,20 @@ def flash_blocks(positions: int, window: int,
       one exponential a score where the two kernels make 7 and two, where
       its key/value block can grow (to ``_FUSED_KV_MOST``) until the
       partial ``dq`` it writes is at most ``_FUSED_DQ_COPIES`` times the
-      queries; a window never does: the fused kernel's grid is not shrunk
-      by the mask and it writes zeros for every block the mask skips.
+      queries, its query block halved until one step's rows
+      (``_fused_rows``) fit its VMEM (``_FUSED_ROWS_MOST``); a window
+      never does: the fused kernel's grid is not shrunk by the mask and it
+      writes zeros for every block the mask skips.
 
-    ``head_dim`` decides only whether the kernels take the head (whole
-    or half lane rows): on a v5e the soft-max's float32 elementwise work
-    and not the products bounds a block, at 64 as at 128, and the sweep's
-    winners were the same (PERF.md section 6, PR 38)."""
+    ``head_dim`` (of q and k) and ``head_dim_v`` (of v, ``head_dim`` if not
+    given) decide whether the kernels take the heads (whole or half lane
+    rows) and the fused kernel's query block: on a v5e the soft-max's
+    float32 elementwise work and not the products bounds a block, at 64 as
+    at 128 and at 192/128, and a sweep of the blocks found the same
+    winners at each."""
+    head_dim_v = head_dim if head_dim_v is None else head_dim_v
     sizes = [b for b in _FLASH_SIZES if positions % b == 0]
-    if not sizes or head_dim % 64:
+    if not sizes or head_dim % 64 or head_dim_v % 64:
         return None
     b = min(sizes, key=lambda b: _blocks_seen(positions, window, b)
             * (b * b + _STEP_SCORES))
@@ -293,7 +324,13 @@ def flash_blocks(positions: int, window: int,
                and positions % (2 * kv) == 0):
             kv *= 2
         if positions // kv <= _FUSED_DQ_COPIES:
-            return FlashBlocks((b, b, compute), (b, kv, compute), None)
+            q = b
+            while (q > _FLASH_SIZES[0]
+                   and _fused_rows(q, kv, head_dim, head_dim_v)
+                   > _FUSED_ROWS_MOST):
+                q //= 2
+            return FlashBlocks((b, b, compute), (q, kv, min(q, compute)),
+                               None)
     return FlashBlocks((b, b, compute), (b, b, compute), (b, b))
 
 
@@ -313,12 +350,12 @@ def _block_sizes(blocks: FlashBlocks):
 
 @functools.lru_cache(maxsize=16)
 def _splash_kernel(s: int, group: int, window: int, head_dim: int,
-                   interpret: bool):
+                   interpret: bool, head_dim_v: int | None = None):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     one = (sm.LocalMask((s, s), (window - 1, 0), 0) if window
            else sm.CausalMask((s, s)))
-    sizes = _block_sizes(flash_blocks(s, window, head_dim))
+    sizes = _block_sizes(flash_blocks(s, window, head_dim, head_dim_v))
     # the kernel object holds its block mask as arrays: made concrete
     # here, or a cached one would carry the tracers of the trace that
     # first asked for it into the next
@@ -334,7 +371,7 @@ _XLA_SCORE_BYTES = 1 << 30
 
 
 def attn_lowering(positions: int, head_dim: int, heads: int = 1,
-                  window: int = 0) -> str:
+                  window: int = 0, head_dim_v: int | None = None) -> str:
     """Which lowering the attention core takes at these sizes on this
     backend; counted in ``attn_lowering_total``.  The flash kernels take
     whatever ``flash_blocks`` can tile, a head of 64 as it is (half a lane
@@ -343,7 +380,8 @@ def attn_lowering(positions: int, head_dim: int, heads: int = 1,
     ``backward`` and ``blocks`` beside ``path``.  On a TPU the masked
     scores are refused, not taken, where one sequence's would not fit."""
     tpu = jax.default_backend() == "tpu"
-    blocks = flash_blocks(positions, window, head_dim) if tpu else None
+    blocks = (flash_blocks(positions, window, head_dim, head_dim_v) if tpu
+              else None)
     path = "splash" if blocks else "xla"
     if tpu and not blocks and (4 * heads * positions * positions
                                > _XLA_SCORE_BYTES):
@@ -353,10 +391,13 @@ def attn_lowering(positions: int, head_dim: int, heads: int = 1,
             f"{_FLASH_SIZES[0]}, heads of a multiple of 64), and its masked "
             f"scores ({heads} x {positions} x {positions} float32) do not "
             f"fit the chip")
+    heads_unequal = ({} if head_dim_v in (None, head_dim) else
+                     {"head_dim": head_dim, "v_head_dim": head_dim_v})
     telemetry.get_registry().counter(
         "attn_lowering_total",
         "traces of the attention core, by lowering").inc(
-            path=path, **(blocks.labels(window) if blocks else {}))
+            path=path, **(blocks.labels(window) if blocks else {}),
+            **heads_unequal)
     return path
 
 
@@ -371,13 +412,13 @@ def _heads_major(t):
 
 def attn_core(q, k, v, window: int, path: str):
     """Causal (and, with ``window``, sliding) grouped-query attention of
-    one sequence.  q [kv, group, S, D] already scaled, k and v [kv, S, D];
-    returns [kv, group, S, D]."""
+    one sequence.  q [kv, group, S, D] already scaled, k [kv, S, D] and v
+    [kv, S, Dv]; returns [kv, group, S, Dv]."""
     _, group, s, _ = q.shape
     with jax.named_scope("attn_core"):
         if path == "splash":
             return jax.vmap(_splash_kernel(s, group, window, q.shape[-1],
-                                           _INTERPRET))(q, k, v)
+                                           _INTERPRET, v.shape[-1]))(q, k, v)
         return _attn_core_xla(q, k, v, window)
 
 
@@ -471,6 +512,96 @@ class AttentionLayer(LayerImpl):
         return [_per_sequence(one, bottoms[0])]
 
 
+@register_layer("LatentAttention")
+class LatentAttentionLayer(LayerImpl):
+    """Multi-head latent attention in its expanded (training) form
+    (``latent_attention_param``), causal, ``num_heads`` heads:
+
+    - ``c = x W_dkv`` (hidden -> ``kv_lora_rank``), ``ĉ = RMSNorm(c) γ_kv``
+      (``kv_norm_eps``); ``[k_nope_h | v_h] = ĉ W_ukv``, ``qk_nope_head_dim``
+      and ``v_head_dim`` a head;
+    - one rotary key ``k_r = RoPE(x W_kr)`` of ``qk_rope_head_dim``, shared
+      by every head and not normalised;
+    - ``[q_nope_h | q_r_h] = x W_q``, ``q_r_h`` rotated (``rope_theta``;
+      ``yarn_factor``, ``yarn_original_length``, ``yarn_beta_fast``,
+      ``yarn_beta_slow``; ``rope_attention_factor`` on cos and sin);
+    - ``softmax_causal(τ (q_h · [k_nope_h | k_r])) v_h``, τ
+      ``softmax_scale`` (``1/sqrt(qk_nope_head_dim + qk_rope_head_dim)``
+      if not given), then ``W_o``.
+
+    Blobs: W_q ``[hidden, heads x (nope + rope)]``, W_dkv, W_kr, γ_kv
+    ``[kv_lora_rank]``, W_ukv ``[kv_lora_rank, heads x (nope + v)]``, W_o
+    ``[heads x v, hidden]``; no bias.  As ``Attention``, a sequence at a time,
+    recomputed in the backward pass, heads-major from the projections
+    through the core to ``W_o``; the core is ``attn_core`` with heads of
+    ``nope + rope`` for q and k and of ``v_head_dim`` for v.  The latent's
+    products, its norm and the keys' assembly run under the sub-scope
+    ``mla_latent``."""
+
+    def _geom(self, lp):
+        p = lp.sub("latent_attention_param")
+        nope, rope = (int(p.get("qk_nope_head_dim", 0)),
+                      int(p.get("qk_rope_head_dim", 0)))
+        return dict(
+            heads=int(p.get("num_heads", 0)),
+            rank=int(p.get("kv_lora_rank", 0)), nope=nope, rope=rope,
+            v=int(p.get("v_head_dim", 0)),
+            eps=float(p.get("kv_norm_eps", 1e-6)),
+            scale=float(p.get("softmax_scale", (nope + rope) ** -0.5)),
+            factor=float(p.get("rope_attention_factor", 1.0)),
+            inv_freq=rope_inv_freq(
+                rope, float(p.get("rope_theta", 1e4)),
+                float(p.get("yarn_factor", 0.0)),
+                int(p.get("yarn_original_length", 0)),
+                float(p.get("yarn_beta_fast", 32.0)),
+                float(p.get("yarn_beta_slow", 1.0))))
+
+    def init(self, rng, lp, bottom_shapes):
+        g = self._geom(lp)
+        hidden, heads = bottom_shapes[0][-1], g["heads"]
+        wf = _filler(lp.sub("latent_attention_param"))
+        r = jax.random.split(rng, 5)
+        return [fill(r[0], wf, (hidden, heads * (g["nope"] + g["rope"]))),
+                fill(r[1], wf, (hidden, g["rank"])),
+                fill(r[2], wf, (hidden, g["rope"])),
+                jnp.ones((g["rank"],), jnp.float32),
+                fill(r[3], wf, (g["rank"], heads * (g["nope"] + g["v"]))),
+                fill(r[4], wf, (heads * g["v"], hidden))]
+
+    def apply(self, lp, params, bottoms, train, rng):
+        g = self._geom(lp)
+        heads, nope, rope, dv = g["heads"], g["nope"], g["rope"], g["v"]
+        wq, wdkv, wkr, gamma, wukv, wo = params
+        path = attn_lowering(bottoms[0].shape[-2], nope + rope, heads,
+                             head_dim_v=dv)
+        hidden, rank = wq.shape[0], wdkv.shape[1]
+        # one head a key/value head: the kernels' [kv, group, S, D] with a
+        # group of 1, so no tensor between the products is reshaped
+        wq = wq.reshape(hidden, heads, 1, nope + rope)
+        wukv = wukv.reshape(rank, heads, nope + dv)
+        wuk, wuv = wukv[..., :nope], wukv[..., nope:]
+        wo = wo.reshape(heads, 1, dv, hidden)
+
+        def one(x):
+            q = _heads_major(jnp.einsum("sh,hkgd->kgsd", x, wq))
+            q = apply_rope(q, g["inv_freq"], g["factor"], scale=g["scale"],
+                           offset=nope)
+            with jax.named_scope("mla_latent"):
+                c = _rms_norm(x @ wdkv, gamma, g["eps"])
+                k_r = apply_rope(x @ wkr, g["inv_freq"], g["factor"])
+                k_nope = _heads_major(jnp.einsum("sc,ckd->ksd", c, wuk))
+                v = _heads_major(jnp.einsum("sc,ckd->ksd", c, wuv))
+                # the shared rotary key beside each head's own: a
+                # concatenation on the lanes, no head transposed
+                k = _heads_major(jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_r, (heads, *k_r.shape))],
+                    axis=-1))
+            out = attn_core(q, k, v, 0, path)
+            return jnp.einsum("kgsd,kgdh->sh", _heads_major(out), wo)
+
+        return [_per_sequence(one, bottoms[0])]
+
+
 # -- short convolution --------------------------------------------------------
 
 def _conv_mix(b, c, x, taps):
@@ -554,6 +685,8 @@ def moe_geometry(lp) -> dict:
                 eps=float(p.get("norm_eps", 0.0)),
                 shared=int(p.get("shared_width", 0)),
                 select_bias=bool(p.get("select_bias", False)),
+                scoring=str(p.get("scoring", "sigmoid")),
+                norm_topk=bool(p.get("norm_topk", True)),
                 detached=bool(p.get("detach_router", False)))
 
 
@@ -570,13 +703,27 @@ def moe_row_bound(tokens: int, g: dict) -> int:
     return min(most, -(-math.ceil(1.25 * even) // _GMM_ROWS) * _GMM_ROWS)
 
 
+def _router_logits(x, w_router):
+    """``x W_r`` in float32, the product at ``HIGHEST``."""
+    return jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def _router_scores(x, w_router):
-    """``sigmoid(x W_r)`` in float32, the product at ``HIGHEST``; run again
-    in the backward pass for the sigmoid's derivative, so that what is kept
-    of it is ``x`` as it came."""
-    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    return jax.nn.sigmoid(logits)
+    """``sigmoid(x W_r)`` in float32; run again in the backward pass for the
+    sigmoid's derivative, so that what is kept of it is ``x`` as it
+    came."""
+    return jax.nn.sigmoid(_router_logits(x, w_router))
+
+
+def _router_softmax(x, w_router):
+    """``softmax(x W_r)`` over every expert in float32; like
+    ``_router_scores`` run again in the backward pass, so that nothing
+    ``[tokens, experts]`` is kept."""
+    return jax.nn.softmax(_router_logits(x, w_router), axis=-1)
+
+
+_SCORERS = {"sigmoid": _router_scores, "softmax": _router_softmax}
 
 
 def _chosen(scores, top_i):
@@ -652,11 +799,13 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 def moe_route(x, w_router, g: dict, bias=None):
     """Route ``x [tokens, hidden]`` over all the experts and list the rows
-    the held ones compute.  Scores are ``sigmoid(x W_r)`` in float32; a
+    the held ones compute.  Scores are ``sigmoid(x W_r)`` in float32, or
+    with ``g["scoring"]`` softmax ``softmax(x W_r)`` over every expert; a
     token takes the ``top_k`` experts with the largest score, or with
     ``bias [experts]`` the largest ``score + bias`` (the lower index on a
     tie); the weights are the chosen experts' scores, without the bias,
-    over their sum plus ``g["eps"]``, times ``scaling``.  Returns (token
+    over their sum plus ``g["eps"]`` (as they are where ``g["norm_topk"]``
+    is false), times ``scaling``.  Returns (token
     index, weight, rows of each held expert as sized for the products) of
     the ``moe_row_bound`` rows sorted by expert, then (rows each held
     expert was sent, rows left out because the bound bound).
@@ -669,14 +818,18 @@ def moe_route(x, w_router, g: dict, bias=None):
     tokens, held, k = x.shape[0], g["hi"] - g["lo"], g["top_k"]
     if g.get("detached"):
         x = jax.lax.stop_gradient(x)
-    scores = jax.checkpoint(_router_scores)(x, w_router)
+    scores = jax.checkpoint(_SCORERS[g.get("scoring", "sigmoid")])(
+        x, w_router)
     pick = scores if bias is None else scores + bias.astype(jnp.float32)
     _, top_i = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
     top_s = _chosen(scores, top_i)
-    total = jnp.sum(top_s, axis=-1, keepdims=True)
-    if g.get("eps"):
-        total = total + g["eps"]
-    weight = top_s / total * g["scaling"]
+    if g.get("norm_topk", True):
+        total = jnp.sum(top_s, axis=-1, keepdims=True)
+        if g.get("eps"):
+            total = total + g["eps"]
+        weight = top_s / total * g["scaling"]
+    else:
+        weight = top_s * g["scaling"]
     here = (top_i >= g["lo"]) & (top_i < g["hi"])
     key = jnp.where(here, top_i - g["lo"], held)
     rows = moe_row_bound(tokens, g)
@@ -738,7 +891,10 @@ class MixtureOfExpertsLayer(LayerImpl):
     there is one; with ``select_bias`` a bias ``[num_experts]`` last
     (``select_bias_filler``), added to the scores where the experts are
     chosen and nowhere else.  ``norm_eps`` is added to the sum the chosen
-    scores are divided by.  ``router_column_norm``, if
+    scores are divided by.  ``scoring`` is ``sigmoid`` (the default) or
+    ``softmax`` over every expert, and ``norm_topk`` false weighs a chosen
+    expert by its score as it is, not over the chosen scores' sum.
+    ``router_column_norm``, if
     given, scales each column of the filled router to that length.
     ``detach_router`` keeps the scores' gradient from the layer's input
     (the router's own weights still get theirs): for a layer that holds a
@@ -806,8 +962,11 @@ def moe_load(net, params, inputs) -> dict:
     """What each expert layer of ``net`` was sent on ``inputs``:
     ``{layer: {"rows": [per held expert], "dropped": n}}`` from a
     training-mode forward, one sequence at a time so that it fits beside a
-    training step's state.  Also counted in ``moe_rows_total{layer}`` and
-    ``moe_dropped_total``."""
+    training step's state.  ``dropped`` is what the layer leaves out when it
+    routes all of ``inputs`` at once, as a step does: a token's experts are
+    its own choice, so the batch's rows are the sum of its sequences', and
+    the row bound is the one of all its tokens (``moe_row_bound``).  Also
+    counted in ``moe_rows_total{layer}`` and ``moe_dropped_total``."""
     nodes = [n for n in net.nodes if n.lp.type == "MixtureOfExperts"]
 
     @jax.jit
@@ -817,26 +976,29 @@ def moe_load(net, params, inputs) -> dict:
         for n in nodes:
             x, g, own = blobs[n.bottoms[0]], moe_geometry(n.lp), params[
                 n.lp.name]
-            _, _, _, sent, dropped = moe_route(
+            out[n.lp.name] = moe_route(
                 x.reshape(-1, x.shape[-1]), own[0].astype(x.dtype), g,
-                own[-1] if g["select_bias"] else None)
-            out[n.lp.name] = {"rows": sent, "dropped": dropped}
+                own[-1] if g["select_bias"] else None)[3]
         return out
 
     sequences = len(next(iter(inputs.values())))
     got = [load(params, {k: v[i:i + 1] for k, v in inputs.items()})
            for i in range(sequences)]
     total = jax.tree_util.tree_map(lambda *xs: np.sum(xs, axis=0), *got)
+    tokens = math.prod(np.shape(next(iter(inputs.values()))))
     reg = telemetry.get_registry()
-    for name, sent in total.items():
+    out = {}
+    for n in nodes:
+        rows = total[n.lp.name]
+        dropped = max(0, int(rows.sum()) - moe_row_bound(
+            tokens, moe_geometry(n.lp)))
         reg.counter("moe_rows_total",
                     "rows the held experts were sent").inc(
-                        int(sent["rows"].sum()), layer=name)
+                        int(rows.sum()), layer=n.lp.name)
         reg.counter("moe_dropped_total",
-                    "rows an expert layer left out").inc(
-                        int(sent["dropped"]))
-    return {k: {"rows": v["rows"].tolist(), "dropped": int(v["dropped"])}
-            for k, v in total.items()}
+                    "rows an expert layer left out").inc(dropped)
+        out[n.lp.name] = {"rows": rows.tolist(), "dropped": dropped}
+    return out
 
 
 # -- head and loss ------------------------------------------------------------

@@ -475,3 +475,110 @@ def test_expert_layer_of_lfm2_compiles_at_real_widths(one_chip,
         (64,)]
     text = _layer_gradient(one_chip, lp, impl, shapes, 4).as_text()
     assert text.count("tpu_custom_call") >= 9      # 3 products x 3 passes
+
+
+# -- what DeepSeek-V2-Lite adds, at its published widths ------------------------
+
+def _deepseek_layer(name, sequences=1):
+    from sparknet_tpu import models
+    from sparknet_tpu.ops import get_layer_impl
+    net = models.deepseek_v2(sequences, 1, layers_kept=[0, 1], vocab=128,
+                             experts_held=(0, 8))
+    lp = next(l for l in net.layer if l.name == name)
+    impl = get_layer_impl(lp.type)
+    shapes = jax.eval_shape(
+        lambda r: impl.init(r, lp, [(sequences, 8192, 2048)]),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return lp, impl, shapes
+
+
+def test_latent_attention_takes_the_flash_kernels_at_192_and_128(
+        one_chip, chip_branch, lowered_text):
+    """One sequence of 8,192 positions through a latent attention layer of
+    the published widths, forward and backward: Mosaic takes JAX's flash
+    kernels at q/k heads of 192 and v heads of 128, the forward one and the
+    fused backward one at the blocks ``flash_blocks`` gives, nothing is
+    copied at their edges but JAX's own log-sum-exp, and no score matrix
+    is held."""
+    from sparknet_tpu.ops import sequence
+    lp, impl, shapes = _deepseek_layer("L0/attn")
+    assert [s.shape for s in shapes] == [
+        (2048, 16 * 192), (2048, 512), (2048, 64), (512,), (512, 16 * 256),
+        (16 * 128, 2048)]
+    assert sequence.flash_blocks(8192, 0, 192, 128).dkv == (512, 2048, 512)
+    compiled = _layer_gradient(one_chip, lp, impl, shapes)
+    calls, copies = lowered_text.kernel_edge_copies(compiled.as_text())
+    assert calls == {"splash_mqa_fwd_residuals": 1,
+                     "splash_mqa_dkv_no_residuals": 1}
+    assert {c["kernel"] for c in copies} <= {"splash_mqa_fwd_residuals"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("block_q,fits", [(512, True), (1024, False)])
+def test_the_fused_kernel_at_192_fits_what_the_rule_allows(
+        one_chip, monkeypatch, block_q, fits):
+    """The fused backward kernel over key/value blocks of 2,048 at 16 heads
+    of 192/128: Mosaic takes a query block of 512 and refuses one of 1,024
+    for its scoped VMEM (17.9 of 16 MB), which the heads of 128 take."""
+    from sparknet_tpu.ops import sequence
+    blocks = sequence.FlashBlocks((1024, 1024, 512), (block_q, 2048, 512),
+                                  None)
+    assert (sequence._fused_rows(block_q, 2048, 192, 128)
+            <= sequence._FUSED_ROWS_MOST) == fits
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(sequence.attn_core(q, k, v, 0, "splash")
+                       .astype(jnp.float32))
+
+    monkeypatch.setattr(sequence, "flash_blocks", lambda *a, **k: blocks)
+    sequence._splash_kernel.cache_clear()
+    try:
+        grad = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            arg(16, 1, 8192, 192), arg(16, 8192, 192), arg(16, 8192, 128))
+        if fits:
+            assert "splash_mqa_dkv_no_residuals" in grad.compile().as_text()
+        else:
+            with pytest.raises(Exception, match="vmem"):
+                grad.compile()
+    finally:
+        sequence._splash_kernel.cache_clear()
+
+
+def test_expert_layer_of_deepseek_compiles_at_real_widths(one_chip,
+                                                          chip_branch):
+    """32,768 tokens through a layer holding 8 of 64 experts of 1408, top-6
+    by a softmax, two shared experts of 1408 as one of 2816: grouped
+    kernels in tiles that divide 1408, sized for a quarter over the even
+    24,576 rows."""
+    from sparknet_tpu.ops import sequence
+    lp, impl, shapes = _deepseek_layer("L1/moe", sequences=4)
+    assert sequence.moe_row_bound(32768, sequence.moe_geometry(lp)) == 30720
+    assert [s.shape for s in shapes] == [
+        (2048, 64), (8, 2048, 1408), (8, 2048, 1408), (8, 1408, 2048),
+        (2048, 2816), (2048, 2816), (2816, 2048)]
+    text = _layer_gradient(one_chip, lp, impl, shapes, 4).as_text()
+    assert text.count("tpu_custom_call") >= 9      # 3 products x 3 passes
+
+
+def test_the_deepseek_cell_step_compiles_and_fits(monkeypatch, lowered_text):
+    """The cell's whole step (4 sequences of 8,192 ids, Adam, bfloat16) as
+    ``tools/lowered_text.py`` lowers it from shapes, compiled for the
+    described chip: it fits the chip's 16 GB with its 10.2 GB of state
+    (float32 weights and Adam's two moments are the arguments), every
+    latent layer takes the forward kernel twice (once recomputed) and the
+    fused backward kernel once, and nothing is copied at the kernels'
+    edges but JAX's own log-sum-exp."""
+    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
+    (_, (lowered, _)), = lowered_text.cell_lowered(
+        True, ["deepseek_v2_lite_train_8k"]).items()
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert 7.5e9 < memory.argument_size_in_bytes < 7.8e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    text = compiled.as_text()
+    calls, copies = lowered_text.kernel_edge_copies(text)
+    assert calls == {"splash_mqa_fwd_residuals": 12,
+                     "splash_mqa_dkv_no_residuals": 6}
+    assert {c["kernel"] for c in copies} <= {"splash_mqa_fwd_residuals"}

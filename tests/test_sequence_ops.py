@@ -684,7 +684,8 @@ def test_attention_lowering_off_the_chip_is_the_masked_scores():
 
 
 @pytest.mark.parametrize("width,tile", [(2048, 1024), (512, 512),
-                                        (1536, 768), (128, 128)])
+                                        (1536, 768), (128, 128),
+                                        (1408, 128)])
 def test_grouped_product_tiles_divide_the_width(width, tile):
     assert seq._gmm_tile(width) == tile
 
@@ -936,3 +937,199 @@ def test_transposed_head_is_the_head_on_the_transpose():
     g_plain = jax.grad(lambda w: impl.apply(plain, [w], [x, tokens], True,
                                             None)[0])(w.T)
     close(g_tied, g_plain.T, 1e-5)
+
+
+# -- multi-head latent attention (DeepSeek-V2) ---------------------------------
+
+def latent_lp(heads, rank, nope, rope, v, scale=None):
+    p = {"num_heads": heads, "kv_lora_rank": rank, "qk_nope_head_dim": nope,
+         "qk_rope_head_dim": rope, "v_head_dim": v, "rope_theta": 10000.0,
+         "yarn_factor": 40.0, "yarn_original_length": 16,
+         "kv_norm_eps": 1e-6, "weight_filler": _GAUSS}
+    if scale is not None:
+        p["softmax_scale"] = scale
+    return layer("mla", "LatentAttention", ["x"], ["y"],
+                 latent_attention_param=p)
+
+
+# (heads, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+# positions, softmax_scale): value heads narrower, as wide and wider than
+# the query/key heads, one and several heads, a given scale and the default
+LATENT_CASES = [(2, 16, 8, 8, 8, 12, 0.3), (4, 16, 16, 8, 8, 20, None),
+                (2, 32, 8, 16, 16, 7, 0.2), (3, 8, 8, 4, 12, 16, None),
+                (1, 16, 16, 16, 32, 9, 0.5)]
+
+
+@pytest.mark.parametrize("heads,rank,nope,rope,v,positions,scale",
+                         LATENT_CASES)
+def test_latent_attention_against_reference(heads, rank, nope, rope, v,
+                                            positions, scale):
+    """The layer on the XLA path (masked scores) against the reference's
+    published equations, forward and the gradients of every blob and of
+    the input."""
+    from benchmark.lib import reference_deepseek_v2 as mla_ref
+    lp = latent_lp(heads, rank, nope, rope, v, scale)
+    impl, params = init_and_apply(lp, (2, positions, HIDDEN))
+    params[3] = params[3] * jnp.linspace(0.5, 1.5, rank)    # γ_kv, not 1
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, positions, HIDDEN))
+    m = {"heads": heads, "nope": nope, "rope_dim": rope, "v": v,
+         "eps": 1e-6,
+         "rope": {"rope_type": "yarn", "partial_rotary_factor": 1,
+                  "rope_theta": 10000.0, "factor": 40.0, "beta_fast": 32.0,
+                  "beta_slow": 1.0, "original_max_position_embeddings": 16},
+         "tau": (nope + rope) ** -0.5 if scale is None else scale}
+
+    def system(p, x):
+        return impl.apply(lp, p, [x], True, None)[0]
+
+    def reference(p, x):
+        return jnp.stack([mla_ref.mla(xi, p, m) for xi in x])
+
+    close(system(params, x), highest(reference)(params, x))
+    cot = jax.random.normal(jax.random.PRNGKey(2), (2, positions, HIDDEN))
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * cot),
+                   argnums=(0, 1))(params, x)
+    want = highest(jax.grad(lambda p, x: jnp.sum(reference(p, x) * cot),
+                            argnums=(0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        close(g, w, 1e-4)
+
+
+@pytest.mark.parametrize("head_dim,offset,rotary", [(24, 16, 8), (192, 128, 64),
+                                                    (16, 0, 16), (20, 4, 8)])
+def test_rope_turns_the_dimensions_from_its_offset(head_dim, offset, rotary):
+    """``offset`` leaves the head's first dimensions as they are and turns
+    the next ``rotary`` as the sliced formula does; the scale rides along
+    over the whole head, and the gradient is the turn back."""
+    inv_freq = seq.rope_inv_freq(rotary, 10000.0, 40.0, 16, 32.0, 1.0)
+    x = 3.0 * jax.random.normal(jax.random.PRNGKey(23), (40, 3, head_dim))
+
+    def want_fn(x):
+        lead, rest = x[..., :offset], x[..., offset:]
+        return jnp.concatenate([lead * 0.5, rope_before(rest, inv_freq, 1.0,
+                                                        0.5)], -1)
+
+    got = seq.apply_rope(x.transpose(1, 0, 2), inv_freq, 1.0, 0.5, offset)
+    close(got, want_fn(x).transpose(1, 0, 2), 1e-6)
+    cot = jax.random.normal(jax.random.PRNGKey(24), (3, 40, head_dim))
+    g = jax.grad(lambda x: jnp.sum(seq.apply_rope(
+        x.transpose(1, 0, 2), inv_freq, 1.0, 0.5, offset) * cot))(x)
+    w = jax.grad(lambda x: jnp.sum(want_fn(x).transpose(1, 0, 2) * cot))(x)
+    close(g, w, 1e-6)
+
+
+# (positions, window, q/k head, v head) -> (fused, the fused kernel's query
+# block): the latent layer's heads of 192/128 halve the query block of the
+# fused kernel, the cells' heads keep theirs
+_UNEQUAL_HEADS = [((8192, 0, 192, 128), (True, 512)),
+                  ((8192, 0, 128, 128), (True, 1024)),
+                  ((8192, 0, 64, 64), (True, 1024)),
+                  ((8192, 512, 192, 128), (False, 512)),
+                  ((2048, 0, 192, 128), (True, 1024)),
+                  ((16384, 0, 192, 128), (False, 1024))]
+
+
+@pytest.mark.parametrize("shape,want", _UNEQUAL_HEADS,
+                         ids=["x".join(map(str, s)) for s, _ in _UNEQUAL_HEADS])
+def test_flash_blocks_at_unequal_heads(shape, want):
+    """The fused kernel's query block is halved until one step's rows fit
+    its VMEM (``_fused_rows`` under ``_FUSED_ROWS_MOST``), its compute
+    block with it; the other blocks are what the heads of 128 take."""
+    blocks = seq.flash_blocks(*shape)
+    positions, window, d, dv = shape
+    assert (blocks.fused, blocks.dkv[0]) == want
+    assert blocks.fwd == seq.flash_blocks(positions, window, 128).fwd
+    if blocks.fused:
+        assert seq._fused_rows(*blocks.dkv[:2], d, dv) <= seq._FUSED_ROWS_MOST
+        assert blocks.dkv[2] == min(blocks.dkv[0], seq._FLASH_COMPUTE)
+    assert seq.flash_blocks(positions, window, d, dv) == seq.flash_blocks(
+        positions, window, d, dv if dv != d else None)
+
+
+def test_fused_rows_are_counted_at_lane_padded_widths():
+    """The three readings the bound sits between (a v5e's compiler, 16
+    heads): the cells' blocks at 128, the refused 1,024 x 2,048 at 192/128
+    and the 512 x 2,048 that compiles; a head of 64 fills a lane row."""
+    assert seq._fused_rows(1024, 2048, 128, 128) == 1_441_792
+    assert seq._fused_rows(1024, 2048, 192, 128) == 2_228_224
+    assert seq._fused_rows(512, 2048, 192, 128) == 1_900_544
+    assert seq._fused_rows(1024, 2048, 64, 64) == 1_441_792
+    assert (seq._fused_rows(512, 2048, 192, 128) < seq._FUSED_ROWS_MOST
+            < seq._fused_rows(1024, 2048, 192, 128))
+
+
+def test_the_lowering_counter_names_unequal_heads(monkeypatch):
+    """A sample of the latent layer's core carries both head sizes; the
+    grouped-query layers' samples are as they were."""
+    from sparknet_tpu.utils import telemetry
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert seq.attn_lowering(8192, 192, 16, head_dim_v=128) == "splash"
+    seq.attn_lowering(8192, 128, 48, 0, head_dim_v=128)
+    samples = [s["labels"] for s in telemetry.get_registry().snapshot()[
+        "attn_lowering_total"]["samples"]]
+    assert {"path": "splash", "mask": "causal", "backward": "fused",
+            "blocks": "fwd 1024x1024x512 dkv 512x2048x512",
+            "head_dim": "192", "v_head_dim": "128"} in samples
+    assert {"path": "splash", "mask": "causal", "backward": "fused",
+            "blocks": "fwd 1024x1024x512 dkv 1024x2048x512"} in samples
+
+
+@pytest.mark.parametrize("positions,kv", [(512, 2), (1024, 1)])
+def test_flash_kernels_at_unequal_heads_against_the_masked_scores(
+        monkeypatch, positions, kv):
+    """``attn_core(path="splash")`` with q/k heads of 192 and v heads of
+    128, in Pallas' interpreter, against the masked scores: the output and
+    the three gradients, bfloat16 operands."""
+    monkeypatch.setattr(seq, "_INTERPRET", True)
+    seq._splash_kernel.cache_clear()
+    r = jax.random.split(jax.random.PRNGKey(positions), 4)
+    q = (jax.random.normal(r[0], (kv, 1, positions, 192))
+         * 192 ** -0.5).astype(jnp.bfloat16)
+    k = jax.random.normal(r[1], (kv, positions, 192)).astype(jnp.bfloat16)
+    v = jax.random.normal(r[2], (kv, positions, 128)).astype(jnp.bfloat16)
+    cot = jax.random.normal(r[3], (kv, 1, positions, 128)).astype(
+        jnp.bfloat16)
+
+    def both(path):
+        return jax.jit(lambda q, k, v: jax.vjp(
+            lambda *a: seq.attn_core(*a, 0, path), q, k, v)[1](cot) + (
+                seq.attn_core(q, k, v, 0, path),))(q, k, v)
+
+    for name, got, want in zip(("dq", "dk", "dv", "out"), both("splash"),
+                               both("xla")):
+        got, want = (np.asarray(t, np.float32) for t in (got, want))
+        assert got.shape == want.shape
+        err = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert err < 8e-3, (name, err)
+    seq._splash_kernel.cache_clear()
+
+
+# (scoring, norm_topk): the four routers the two options make
+_SCORINGS = [("softmax", False), ("softmax", True), ("sigmoid", False),
+             ("sigmoid", True)]
+
+
+@pytest.mark.parametrize("scoring,norm_topk", _SCORINGS)
+def test_router_scoring_and_normalisation(scoring, norm_topk):
+    """Scores are the softmax over every expert or the sigmoid, the top 6
+    of 64 are taken either way (the same experts: both are increasing in
+    the logit), and the weights are the chosen scores, over their sum
+    where ``norm_topk``."""
+    g = {"experts": 64, "top_k": 6, "lo": 8, "hi": 16, "scaling": 1.0,
+         "eps": 0.0, "scoring": scoring, "norm_topk": norm_topk}
+    x = jax.random.normal(jax.random.PRNGKey(30), (64, 32))
+    wr = 0.3 * jax.random.normal(jax.random.PRNGKey(31), (32, 64))
+    token, w, sized, sent, dropped = seq.moe_route(x, wr, g)
+    logits = jnp.dot(x, wr, precision="highest")
+    score = (jax.nn.softmax(logits, -1) if scoring == "softmax"
+             else jax.nn.sigmoid(logits))
+    chosen = jnp.argsort(-logits, axis=-1, stable=True)[:, :6]
+    top = jnp.take_along_axis(score, chosen, -1)
+    weight = top / jnp.sum(top, -1, keepdims=True) if norm_topk else top
+    held = (chosen >= 8) & (chosen < 16)
+    want = sorted(float(v) for v in np.asarray(weight)[np.asarray(held)])
+    got = sorted(float(v) for v in np.asarray(w) if v != 0)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert int(np.sum(sent)) == int(np.sum(held)) and int(dropped) == 0
+

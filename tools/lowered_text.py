@@ -24,9 +24,10 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   ``caffenet_train_resident`` (bfloat16, 1,024) and
   ``googlenet_train_resident`` (bfloat16, 256), the trainer's round for
   ``caffenet_rounds_x4`` (float32, 512 a chip, tau 10), and the token cells'
-  steps (``laguna_xs_2_train_8k``, ``lfm2_24b_a2b_train_8k``: bfloat16, 4
-  sequences of 8,192 ids) from the shapes of their parameters and Adam's
-  state alone, 11 and 7.5 GB that are never made.  ``--devices real``
+  steps (``laguna_xs_2_train_8k``, ``lfm2_24b_a2b_train_8k``,
+  ``deepseek_v2_lite_train_8k``: bfloat16, 4 sequences of 8,192 ids) from
+  the shapes of their parameters and Adam's state alone, 11, 7.5 and 10.2
+  GB that are never made.  ``--devices real``
   lowers for the chips JAX holds and leaves out a cell that needs more;
   ``described`` lowers for a v5e:2x2 that is described and not attached, with
   trace-time backend checks steered to the chip's branch, and needs no chip.
@@ -116,7 +117,7 @@ def net_texts() -> dict[str, tuple[str, str]]:
 
 CELLS = ("caffenet_train_resident", "googlenet_train_resident",
          "caffenet_rounds_x4", "laguna_xs_2_train_8k",
-         "lfm2_24b_a2b_train_8k")
+         "lfm2_24b_a2b_train_8k", "deepseek_v2_lite_train_8k")
 # a layer of split backward kernels calls all three splash kernels, a fused
 # one the first two: its dkv kernel makes dq as well
 KERNELS = ("relu_lrn_fwd", "relu_lrn_bwd", "splash_mqa_fwd_residuals",
