@@ -23,7 +23,7 @@ by a product and not by slicing it, and float32 lives only inside a fusion.
 
 Lowerings, one an operation, chosen here from shapes and backend and counted
 once a trace (``attn_lowering_total{path, mask, backward, blocks}``,
-``moe_lowering_total{path}``):
+``moe_lowering_total{path, gate_up, down}``):
 
 - the attention core (scores, mask, softmax, weighted sum; scope
   ``attn_core``): on a TPU, where ``flash_blocks`` can tile the positions
@@ -43,7 +43,10 @@ once a trace (``attn_lowering_total{path, mask, backward, blocks}``,
 - the experts' products (scope ``moe_experts``): rows sorted by expert and
   multiplied group by group, on a TPU by JAX's ``megablox`` grouped matrix
   kernels (``path=gmm``), elsewhere by ``jax.lax.ragged_dot``
-  (``path=ragged_dot``).
+  (``path=ragged_dot``).  Each of a product's three kernels (the forward
+  ``gmm``, the rows' gradient ``dlhs`` and the weights' ``tgmm``) takes
+  the tiles one rule, ``_gmm_tiling``, gives its own problem; the
+  counter's ``gmm`` sample carries them.
 
 The route of an expert layer (scope ``moe_route``; one path on every
 backend) is made once a step and kept: ``moe_route`` runs outside what the
@@ -62,6 +65,7 @@ in float32, and each one's transpose in the backward pass.
 from __future__ import annotations
 
 import functools
+import importlib
 import math
 import typing
 
@@ -76,6 +80,9 @@ from .fillers import fill
 from .registry import LayerImpl, register_layer
 
 _GMM_ROWS = 512         # rows a tile of the grouped product holds
+# Mosaic's scoped VMEM on a v5e is 16 MB; what a grouped product's tiles may
+# ask of it by ``_gmm_vmem``
+_GMM_VMEM_MOST = 16 * 2**20
 
 
 def _filler(p, key: str = "weight_filler") -> FillerParameter:
@@ -848,16 +855,28 @@ def moe_route(x, w_router, g: dict, bias=None):
     return slot // k, w, sized, sent, jnp.sum(sent) - ends[-1]
 
 
-def moe_lowering(rows: int, hidden: int, width: int) -> str:
-    """Which lowering the experts' grouped products take at these sizes on
-    this backend; counted in ``moe_lowering_total``."""
+def moe_lowering(rows: int, hidden: int, width: int,
+                 itemsize: int) -> str:
+    """Which lowering the experts' grouped products take at these sizes, of
+    operands of ``itemsize`` bytes, on this backend; counted in
+    ``moe_lowering_total``, whose ``gmm`` sample carries the tiles of each
+    product's three kernels (``gate_up`` and ``down``: ``fwd``, ``dlhs``,
+    ``tgmm``)."""
     gmm = (jax.default_backend() == "tpu" and rows % _GMM_ROWS == 0
            and hidden % 128 == 0 and width % 128 == 0)
     path = "gmm" if gmm else "ragged_dot"
+    tiles = {}
+    if gmm:
+        for product, (k, n) in (("gate_up", (hidden, width)),
+                                ("down", (width, hidden))):
+            tiles[product] = " ".join(
+                f"{kernel} {'x'.join(map(str, t))}" for kernel, t in
+                zip(("fwd", "dlhs", "tgmm"),
+                    gmm_tiles(rows, k, n, itemsize)))
     telemetry.get_registry().counter(
         "moe_lowering_total",
         "traces of an expert layer's grouped products, by lowering").inc(
-            path=path)
+            path=path, **tiles)
     return path
 
 
@@ -865,17 +884,103 @@ def _grouped(rows, w, sizes, path: str):
     """``rows[group i] @ w[i]`` for rows sorted by group."""
     if path != "gmm":
         return jax.lax.ragged_dot(rows, w, sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-    k, n = w.shape[1:]
-    return megablox.gmm(rows, w, sizes, rows.dtype,
-                        (_GMM_ROWS, _gmm_tile(k), _gmm_tile(n)))
+    return _gmm(rows, w, sizes,
+                gmm_tiles(rows.shape[0], *w.shape[1:],
+                          jnp.result_type(rows, w).itemsize), _INTERPRET)
 
 
-def _gmm_tile(width: int) -> int:
-    """The widest tile of whole lane rows, at most 1024, that divides
-    ``width``: no tile of a grouped product hangs over the matrix's edge."""
-    return max(t for t in range(128, min(width, 1024) + 1, 128)
-               if width % t == 0)
+def _gmm_vmem(kernel: str, tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """Bytes of scoped VMEM one of JAX's ``megablox`` kernels holds at
+    tiles ``(tm, tk, tn)`` of operands of ``itemsize`` bytes: its blocks of
+    lhs ``(tm, tk)``, of rhs (``(tk, tn)``; ``tgmm``'s ``(tm, tn)``) and of
+    its output (``(tm, tn)``; ``tgmm``'s ``(tk, tn)``) double-buffered, the
+    float32 accumulator the size of an output block, and one more copy of
+    the lhs block (the kernel's own, transposed in ``tgmm``; at four bytes
+    ``gmm`` makes none, so there the estimate is an upper bound)."""
+    out = tk * tn if kernel == "tgmm" else tm * tn
+    rhs = tm * tn if kernel == "tgmm" else tk * tn
+    return (2 * itemsize * (tm * tk + rhs + out) + 4 * out
+            + itemsize * tm * tk)
+
+
+def _gmm_tiling(kernel: str, m: int, k: int, n: int, itemsize: int) -> tuple:
+    """``(tm, tk, tn)`` for one ``megablox`` kernel (``gmm``, ``dlhs`` --
+    ``gmm`` with the right operand transposed -- or ``tgmm``) on its own
+    problem of operands of ``itemsize`` bytes: ``m`` rows in groups,
+    contracted over ``k`` into ``n``.  ``tm`` is ``_GMM_ROWS``; ``tk`` and
+    ``tn`` divide ``k`` and ``n`` in whole lane rows, so no tile hangs over
+    an edge and no contraction is masked.  Of
+    the tiles with which the kernel fits ``_GMM_VMEM_MOST`` (``_gmm_vmem``)
+    it takes those with which it reads least: the lhs once for every tile
+    of ``n``; in ``gmm`` the rhs once for every tile of rows where the
+    contraction takes more than one tile (a group's rhs block is kept from
+    one row tile to the next only where it is the whole of ``k``), in
+    ``tgmm`` the rhs once for every tile of ``k``; then the fewest steps.
+    The budget is a v5e's, and the estimate was fitted to what its compiler
+    takes and refuses at bfloat16 and checked at float32, where it refuses
+    some tilings the compiler takes; another chip generation, or a width no
+    test compiles, may ask for a refit."""
+    lanes = range(128, max(k, n) + 1, 128)
+
+    def cost(tiles):
+        tk, tn = tiles
+        if kernel == "tgmm":
+            read = m * k * (n // tn) + m * n * (k // tk)
+        else:
+            read = m * k * (n // tn) + (m // _GMM_ROWS * k * n if tk < k
+                                        else 0)
+        return read, (k // tk) * (n // tn)
+
+    fits = [(tk, tn) for tk in lanes if k % tk == 0 for tn in lanes
+            if n % tn == 0
+            and _gmm_vmem(kernel, _GMM_ROWS, tk, tn, itemsize)
+            <= _GMM_VMEM_MOST]
+    if not fits:
+        raise ValueError(f"no tiling of ({k}, {n}) fits {kernel}'s VMEM")
+    return (_GMM_ROWS, *min(fits, key=cost))
+
+
+def gmm_tiles(m: int, k: int, n: int, itemsize: int) -> tuple:
+    """The tiles of the three kernels of ``rows [m, k] @ w [groups, k, n]``
+    and its gradient, operands of ``itemsize`` bytes: the forward ``gmm``,
+    ``dlhs`` (the rows' gradient, its own problem ``(m, n, k)``) and
+    ``tgmm`` (the weights')."""
+    return tuple(_gmm_tiling(kernel, m, *kn, itemsize) for kernel, kn in
+                 (("gmm", (k, n)), ("dlhs", (n, k)), ("tgmm", (k, n))))
+
+
+def _megablox():
+    """JAX's ``megablox`` kernels themselves, ``gmm`` and ``tgmm``, without
+    the VJP that ``megablox.gmm`` puts round them."""
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(rows, w, sizes, tiles: tuple, interpret: bool):
+    """``rows[group i] @ w[i]`` by JAX's ``megablox`` kernels, each of the
+    three at its own tiles (``gmm_tiles``): ``megablox.gmm`` hands one
+    tiling to all three, and gate's forward ``gmm`` and its ``tgmm`` ask a
+    tiling callable with the same ``(m, k, n)``."""
+    return _megablox().gmm(rows, w, sizes, rows.dtype, tiles[0],
+                           interpret=interpret)
+
+
+def _gmm_fwd(rows, w, sizes, tiles, interpret):
+    return _gmm(rows, w, sizes, tiles, interpret), (rows, w, sizes)
+
+
+def _gmm_bwd(tiles, interpret, saved, dy):
+    rows, w, sizes = saved
+    kernels = _megablox()
+    d_rows = kernels.gmm(dy, w, sizes, rows.dtype, tiles[1],
+                         transpose_rhs=True, interpret=interpret)
+    d_w = kernels.tgmm(rows.swapaxes(0, 1), dy, sizes, w.dtype, tiles[2],
+                       num_actual_groups=w.shape[0], interpret=interpret)
+    return d_rows, d_w, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 @register_layer("MixtureOfExperts")
@@ -930,7 +1035,8 @@ class MixtureOfExpertsLayer(LayerImpl):
         g = moe_geometry(lp)
         shape = bottoms[0].shape
         path = moe_lowering(moe_row_bound(math.prod(shape[:-1]), g),
-                            shape[-1], params[1].shape[-1])
+                            shape[-1], params[1].shape[-1],
+                            jnp.result_type(bottoms[0], params[1]).itemsize)
         x = bottoms[0].reshape(-1, shape[-1])
         wr, eg, eu, ed, *rest = params
         bias = rest[-1] if g["select_bias"] else None

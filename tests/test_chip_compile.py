@@ -562,6 +562,53 @@ def test_expert_layer_of_deepseek_compiles_at_real_widths(one_chip,
     assert text.count("tpu_custom_call") >= 9      # 3 products x 3 passes
 
 
+# -- the grouped products of float32 operands ------------------------------------
+
+# the token cells' rows (the row bound), hidden and expert width
+_EXPERT_WIDTHS = {"deepseek": (30720, 2048, 1408),
+                  "lfm2": (20480, 2048, 1536), "laguna": (40960, 2048, 512)}
+
+
+def _up_and_down(one_chip, m, hidden, width, dtype):
+    """``rows @ up`` then ``@ down`` by ``_grouped``'s kernels over 8
+    groups, the value and the three gradients, lowered for the described
+    chip."""
+    from sparknet_tpu.ops import sequence
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(rows, up, down, sizes):
+        h = sequence._grouped(rows, up, sizes, "gmm")
+        return jnp.sum(sequence._grouped(h, down, sizes, "gmm")
+                       .astype(jnp.float32))
+
+    return jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        arg(m, hidden), arg(8, hidden, width), arg(8, width, hidden),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("cell", sorted(_EXPERT_WIDTHS))
+def test_float32_grouped_kernels_compile_at_the_cells_widths(one_chip, cell):
+    """A float32 expert layer (a ``Net`` with no ``compute_dtype``) at a
+    token cell's rows and widths: Mosaic takes each of the six kernels of
+    its two products at the tiles ``gmm_tiles`` gives four-byte operands."""
+    text = _up_and_down(one_chip, *_EXPERT_WIDTHS[cell],
+                        jnp.float32).compile().as_text()
+    assert text.count("tpu_custom_call") >= 6      # 2 products x 3 kernels
+
+
+def test_bfloat16_tiles_are_refused_at_float32(one_chip, monkeypatch):
+    """DeepSeek's bfloat16 tiles given float32 operands ask for more scoped
+    VMEM than the chip has (the forward ``gmm`` at 512x1024x1408 about 23
+    MiB): why the rule reads the operands' width."""
+    from sparknet_tpu.ops import sequence
+    tiles = sequence.gmm_tiles
+    monkeypatch.setattr(sequence, "gmm_tiles",
+                        lambda m, k, n, itemsize: tiles(m, k, n, 2))
+    with pytest.raises(Exception, match="vmem"):
+        _up_and_down(one_chip, *_EXPERT_WIDTHS["deepseek"],
+                     jnp.float32).compile()
+
+
 def test_the_deepseek_cell_step_compiles_and_fits(monkeypatch, lowered_text):
     """The cell's whole step (4 sequences of 8,192 ids, Adam, bfloat16) as
     ``tools/lowered_text.py`` lowers it from shapes, compiled for the

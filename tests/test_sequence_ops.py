@@ -683,11 +683,158 @@ def test_attention_lowering_off_the_chip_is_the_masked_scores():
     assert seq.attn_lowering(8192, 64, 32) == "xla"
 
 
-@pytest.mark.parametrize("width,tile", [(2048, 1024), (512, 512),
-                                        (1536, 768), (128, 128),
-                                        (1408, 128)])
-def test_grouped_product_tiles_divide_the_width(width, tile):
-    assert seq._gmm_tile(width) == tile
+# the token cells' expert layers: rows (the row bound), hidden, expert width
+_GMM_CELLS = {"deepseek": (30720, 2048, 1408), "lfm2": (20480, 2048, 1536),
+              "laguna": (40960, 2048, 512)}
+# the tiles the rule gives: (cell, product, kernel).  A sweep of every
+# kernel at every (tk, tn) on a v5e (PERF.md, Findings) found each of them
+# fastest but LFM2's two tgmm tilings, its second there (1.1 and 1.7% behind
+# 2048x512 and 512x2048, which read more and take fewer steps)
+_GMM_CHOSEN = {
+    **{(c, p, "fwd"): t for c, t in (("deepseek", (512, 1024, 1408)),
+                                     ("lfm2", (512, 2048, 768)),
+                                     ("laguna", (512, 2048, 512)))
+       for p in ("gate", "up")},
+    **{(c, p, "dlhs"): t for c, t in (("deepseek", (512, 1408, 1024)),
+                                      ("lfm2", (512, 1536, 1024)),
+                                      ("laguna", (512, 512, 2048)))
+       for p in ("gate", "up")},
+    **{(c, p, "tgmm"): t for c, t in (("deepseek", (512, 512, 1408)),
+                                      ("lfm2", (512, 1024, 768)),
+                                      ("laguna", (512, 2048, 512)))
+       for p in ("gate", "up")},
+    ("deepseek", "down", "fwd"): (512, 1408, 1024),
+    ("deepseek", "down", "dlhs"): (512, 1024, 1408),
+    ("deepseek", "down", "tgmm"): (512, 1408, 512),
+    ("lfm2", "down", "fwd"): (512, 1536, 1024),
+    ("lfm2", "down", "dlhs"): (512, 2048, 768),
+    ("lfm2", "down", "tgmm"): (512, 768, 1024),
+    ("laguna", "down", "fwd"): (512, 512, 2048),
+    ("laguna", "down", "dlhs"): (512, 2048, 512),
+    ("laguna", "down", "tgmm"): (512, 512, 2048),
+}
+
+
+@pytest.mark.parametrize(
+    "cell,product,kernel", sorted(_GMM_CHOSEN),
+    ids=["-".join(c) for c in sorted(_GMM_CHOSEN)])
+def test_grouped_product_tiles_divide_the_width(cell, product, kernel):
+    """Each kernel of each product of a token cell's expert layer takes
+    tiles that divide its own contraction and output in whole lane rows,
+    that fit its VMEM by ``_gmm_vmem`` at the cells' bfloat16, and that the
+    sweep measured."""
+    tm, tk, tn = _own_tiles(cell, product, kernel, 2)
+    assert (tm, tk, tn) == _GMM_CHOSEN[cell, product, kernel]
+
+
+def _own_tiles(cell, product, kernel, itemsize):
+    """The tiles ``gmm_tiles`` gives one kernel of a cell's expert layer,
+    checked to divide that kernel's own problem and to fit its VMEM."""
+    m, hidden, width = _GMM_CELLS[cell]
+    k, n = (width, hidden) if product == "down" else (hidden, width)
+    tiles = dict(zip(("fwd", "dlhs", "tgmm"),
+                     seq.gmm_tiles(m, k, n, itemsize)))
+    tm, tk, tn = tiles[kernel]
+    own_k, own_n = (n, k) if kernel == "dlhs" else (k, n)
+    assert m % tm == 0 and tk % 128 == 0 and tn % 128 == 0
+    assert own_k % tk == 0 and own_n % tn == 0
+    assert (seq._gmm_vmem("tgmm" if kernel == "tgmm" else "gmm", tm, tk, tn,
+                          itemsize) <= seq._GMM_VMEM_MOST)
+    return tm, tk, tn
+
+
+# float32 operands (a ``Net`` or ``Solver`` with no ``compute_dtype``)
+# double every block: the rule narrows the tiles to fit the same budget
+_GMM_CHOSEN_F32 = {
+    ("deepseek", "gate_up"): ((512, 256, 1408), (512, 128, 2048),
+                              (512, 256, 1408)),
+    ("deepseek", "down"): ((512, 128, 2048), (512, 256, 1408),
+                           (512, 1408, 256)),
+    ("lfm2", "gate_up"): ((512, 256, 1536), (512, 128, 2048),
+                          (512, 1024, 512)),
+    ("lfm2", "down"): ((512, 128, 2048), (512, 256, 1536), (512, 512, 1024)),
+    ("laguna", "gate_up"): ((512, 1024, 512), (512, 512, 1024),
+                            (512, 1024, 512)),
+    ("laguna", "down"): ((512, 512, 1024), (512, 1024, 512),
+                         (512, 512, 1024)),
+}
+
+
+@pytest.mark.parametrize(
+    "cell,product,kernel",
+    [(c, p, kernel) for c, p in _GMM_CHOSEN_F32
+     for kernel in ("fwd", "dlhs", "tgmm")],
+    ids=lambda v: v)
+def test_float32_grouped_tiles_fit_the_same_budget(cell, product, kernel):
+    """At float32 each kernel of a token cell's expert layer takes tiles
+    that divide its own problem and fit ``_GMM_VMEM_MOST`` with its blocks
+    at four bytes; the bfloat16 tiles would not fit there."""
+    tiles = _own_tiles(cell, product, kernel, 4)
+    i = ("fwd", "dlhs", "tgmm").index(kernel)
+    assert tiles == _GMM_CHOSEN_F32[cell, product][i]
+    bf16 = _GMM_CHOSEN[cell, "gate" if product == "gate_up" else "down",
+                       kernel]
+    if bf16 != tiles:
+        assert (seq._gmm_vmem("tgmm" if kernel == "tgmm" else "gmm", *bf16, 4)
+                > seq._GMM_VMEM_MOST)
+
+
+# (dtype, a VMEM budget at which its three kernels tile three ways, how
+# far the kernels may be from ragged_dot: both round to the dtype once)
+_GMM_INTERPRETED = [("bfloat16", 1_600_000, 2e-3),
+                    ("float32", 3_200_000, 1e-6)]
+
+
+@pytest.mark.parametrize("dtype,most,tol", _GMM_INTERPRETED,
+                         ids=[d for d, _, _ in _GMM_INTERPRETED])
+def test_grouped_kernels_at_their_own_tiles_against_ragged_dot(
+        monkeypatch, dtype, most, tol):
+    """``_grouped(path="gmm")`` in Pallas' interpreter against
+    ``lax.ragged_dot``: the product, the rows' and the weights' gradients,
+    one group empty and two that start inside a tile.  Under the budget
+    the three kernels tile their own problems three ways, so each tiling
+    reaches its own kernel: a ``tk`` or ``tn`` given to the wrong one
+    divides nothing there or misreads a block."""
+    monkeypatch.setattr(seq, "_INTERPRET", True)
+    monkeypatch.setattr(seq, "_GMM_VMEM_MOST", most)
+    m, k, n = 1024, 256, 384
+    dtype = jnp.dtype(dtype)
+    assert seq.gmm_tiles(m, k, n, dtype.itemsize) == (
+        (512, 256, 128), (512, 128, 256), (512, 128, 384))
+    r = jax.random.split(jax.random.PRNGKey(0), 3)
+    rows = jax.random.normal(r[0], (m, k)).astype(dtype)
+    w = (jax.random.normal(r[1], (4, k, n)) * 0.1).astype(dtype)
+    dy = jax.random.normal(r[2], (m, n)).astype(dtype)
+    sizes = jnp.array([300, 0, 500, 224], jnp.int32)
+
+    def both(product):
+        y, vjp = jax.vjp(lambda a, b: product(a, b, sizes), rows, w)
+        return (y, *vjp(dy))
+
+    got = jax.jit(lambda: both(
+        lambda a, b, s: seq._grouped(a, b, s, "gmm")))()
+    want = jax.jit(lambda: both(jax.lax.ragged_dot))()
+    for name, g, h in zip(("out", "d_rows", "d_w"), got, want):
+        g, h = np.asarray(g, np.float32), np.asarray(h, np.float32)
+        assert g.shape == h.shape
+        assert np.linalg.norm(g - h) / np.linalg.norm(h) < tol, name
+
+
+def test_the_expert_lowering_counter_carries_the_tiles(monkeypatch):
+    """On a TPU ``moe_lowering_total``'s sample says which tiles each
+    kernel of the two products took, as ``gmm_tiles`` gives them."""
+    from sparknet_tpu.utils import telemetry
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert seq.moe_lowering(30720, 2048, 1408, 2) == "gmm"
+    x = lambda t: "x".join(map(str, t))
+    want = {"path": "gmm"}
+    for product, (k, n) in (("gate_up", (2048, 1408)),
+                            ("down", (1408, 2048))):
+        fwd, dlhs, tgmm = seq.gmm_tiles(30720, k, n, 2)
+        want[product] = f"fwd {x(fwd)} dlhs {x(dlhs)} tgmm {x(tgmm)}"
+    samples = [s["labels"] for s in telemetry.get_registry().snapshot()[
+        "moe_lowering_total"]["samples"]]
+    assert want in samples
 
 
 BIAS_GEOM = {"experts": 8, "top_k": 2, "lo": 0, "hi": 8, "scaling": 1.0,
