@@ -9,7 +9,11 @@ positions, ``heads`` heads of ``head_dim`` (P) over ``groups`` groups of
   columns), no bias;
 - ``[x | B | C] <- silu(conv(x | B | C) + b)``, a depthwise causal
   convolution of ``conv_kernel`` taps over the positions (scope
-  ``ssm_conv``);
+  ``ssm_conv``): on a TPU two Pallas kernels a tensor, one a pass, in its
+  own layout (``ssm_kernels.conv_act``, chosen and counted by
+  ``ssm_conv_lowering`` in ``ssm_conv_lowering_total{path, blocks}``),
+  ``_conv_act``'s ``jax.numpy`` elsewhere (``path=xla``, also the kernels'
+  reference);
 - ``Δ = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the state
   ``s_t = exp(Δ_t A) s_{t-1} + Δ_t x_t ⊗ B_t`` (``[P, N]`` a head) and
   ``y_t = s_t C_t + D x_t`` (scope ``ssm_scan``);
@@ -87,6 +91,37 @@ def ssm_lowering(g: dict, positions: int, itemsize: int) -> str:
     return path
 
 
+def ssm_conv_lowering(g: dict, positions: int, itemsize: int) -> str:
+    """Which lowering the causal convolution, its bias and SiLU take over
+    one sequence of ``positions``, operands of ``itemsize`` bytes, on this
+    backend; counted once a trace in ``ssm_conv_lowering_total``.  On a
+    TPU, where the positions are whole lane rows and the kernels can block
+    both x (positions on the lanes, blocks of whole packed sublane tiles of
+    channels) and B and C (positions on the sublanes) with the taps inside
+    the tile beside a block (``ssm_kernels.conv_blocks``), the two Pallas
+    kernels (``path=pallas``, whose sample carries the ``blocks`` of x and
+    of B and C); XLA's ``_conv_act`` elsewhere (``path=xla``)."""
+    blocks = None
+    if jax.default_backend() == "tpu" and positions % 128 == 0:
+        blocks = _conv_blocks(g, positions, itemsize)
+    path = "pallas" if blocks and all(blocks) else "xla"
+    telemetry.get_registry().counter(
+        "ssm_conv_lowering_total",
+        "traces of a state-space mixer's causal convolution").inc(
+            path=path, **({"blocks": "x {} bc {}".format(
+                *(b.label() for b in blocks))} if path == "pallas" else {}))
+    return path
+
+
+def _conv_blocks(g: dict, positions: int, itemsize: int):
+    """The convolution kernels' blocks for x ``[H * P, S]`` and for B and C
+    ``[G, S, N]``."""
+    return (ssm_kernels.conv_blocks(positions, g["heads"] * g["head_dim"],
+                                    g["kernel"], itemsize, True),
+            ssm_kernels.conv_blocks(positions, g["state"], g["kernel"],
+                                    itemsize, False))
+
+
 def _pass_states(states, ends, starts):
     """The state each chunk starts from: ``states [..., chunks, P, N]``
     (what each chunk adds, at its end) carried over the chunks between, a
@@ -160,6 +195,18 @@ def _conv_act(t, taps, bias, positions_last: bool = False):
     return jax.nn.silu(acc).astype(t.dtype)
 
 
+def conv_act(t, taps, bias, positions_last: bool, path: str,
+             blocks: ssm_kernels.ConvBlocks | None = None):
+    """``silu(conv(t) + bias)`` as ``_conv_act`` gives it: by the Pallas
+    kernels of ``ssm_kernels`` in ``blocks`` where ``path`` is ``pallas``,
+    by ``_conv_act`` otherwise."""
+    if path != "pallas":
+        return _conv_act(t, taps, bias, positions_last)
+    f32 = jnp.float32
+    return ssm_kernels.conv_act(t, taps.astype(f32), bias.astype(f32),
+                                positions_last, blocks)
+
+
 def gated_group_norm(y, z, w, groups: int, eps: float):
     """``RMSNorm`` of ``y * silu(z)`` over each of ``groups`` groups of
     heads (their channels together), times ``w``: ``y, z [H, P, S]``, ``w
@@ -218,8 +265,12 @@ class StateSpaceMixerLayer(LayerImpl):
         (wz, wx, wb, wc, wdt, taps, conv_b, dt_bias, a_log, d, norm_w,
          w_out) = params
         heads, p, groups, n = g["heads"], g["head_dim"], g["groups"], g["state"]
-        path = ssm_lowering(g, bottoms[0].shape[1],
-                            jnp.result_type(bottoms[0], wx).itemsize)
+        itemsize = jnp.result_type(bottoms[0], wx).itemsize
+        positions = bottoms[0].shape[1]
+        path = ssm_lowering(g, positions, itemsize)
+        conv = ssm_conv_lowering(g, positions, itemsize)
+        xb, bcb = (_conv_blocks(g, positions, itemsize) if conv == "pallas"
+                   else (None, None))
         hidden, inner = wx.shape[0], heads * p
         wz, wx = wz.reshape(hidden, heads, p), wx.reshape(hidden, heads, p)
         wb, wc = (w.reshape(hidden, groups, n) for w in (wb, wc))
@@ -243,8 +294,9 @@ class StateSpaceMixerLayer(LayerImpl):
             dt = jnp.einsum("sd,dh->hs", h, wdt,
                             preferred_element_type=f32)
             with jax.named_scope("ssm_conv"):
-                x, b, c = (_conv_act(x, tx, bx, True), _conv_act(b, tb, bb),
-                           _conv_act(c, tc, bc))
+                x, b, c = (conv_act(x, tx, bx, True, conv, xb),
+                           conv_act(b, tb, bb, False, conv, bcb),
+                           conv_act(c, tc, bc, False, conv, bcb))
             with jax.named_scope("ssm_scan"):
                 delta = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)
                                         [:, None])

@@ -673,8 +673,33 @@ def test_mixer_layer_compiles_at_real_widths(one_chip, chip_branch):
         (2688, 4096), (2688, 4096), (2688, 1024), (2688, 1024), (2688, 64),
         (6144, 4), (6144,), (64,), (64,), (64,), (4096,), (4096, 2688)]
     compiled = _nemotron_gradient(one_chip, lp, impl, shapes)
-    assert compiled.as_text().count('"tpu_custom_call"') == 2
+    assert _kernel_calls(compiled.as_text()) == {
+        "ssd_scan_fwd": 1, "ssd_scan_bwd": 1, "ssm_conv_fwd": 3,
+        "ssm_conv_bwd": 3}
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+
+
+def _kernel_calls(text):
+    """The Pallas calls of a compiled module's text by kernel name."""
+    import collections
+    import re
+    return dict(collections.Counter(
+        re.search(r'op_name="([^"]*)"', line).group(1).rsplit("/", 2)[-2]
+        for line in text.splitlines() if '"tpu_custom_call"' in line))
+
+
+def _mixer_value_and_grad(one_chip, lp, impl, shapes):
+    bf16 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                          sharding=one_chip)
+
+    def loss(params, x):
+        return jnp.sum(impl.apply(lp, params, [x], True, None)[0]
+                       .astype(jnp.float32))
+
+    return jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        [bf16(s) for s in shapes],
+        bf16(jax.ShapeDtypeStruct((1, 8192, 2688), jnp.bfloat16))
+    ).compile().as_text()
 
 
 def test_the_scan_takes_its_kernels_at_real_widths(one_chip, chip_branch,
@@ -695,31 +720,65 @@ def test_the_scan_takes_its_kernels_at_real_widths(one_chip, chip_branch,
     for kernel, chunks in zip(("fwd", "bwd"), blocks):
         assert ssm_kernels._scan_vmem(kernel, chunks, 8, 64, 128, 128,
                                       2) <= 16 * 2**20
-    bf16 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
-                                          sharding=one_chip)
-
-    def loss(params, x):
-        return jnp.sum(impl.apply(lp, params, [x], True, None)[0]
-                       .astype(jnp.float32))
-
-    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
-        [bf16(s) for s in shapes],
-        bf16(jax.ShapeDtypeStruct((1, 8192, 2688), jnp.bfloat16))
-    ).compile().as_text()
+    text = _mixer_value_and_grad(one_chip, lp, impl, shapes)
     calls = collections.defaultdict(list)
     for line in text.splitlines():
         if '"tpu_custom_call"' in line:
             op = re.search(r'op_name="([^"]*)"', line).group(1)
             calls[op.rsplit("/", 2)[-2]].append(op)
+    # the scan's three calls by name, beside the convolution's nine
     assert sorted((k, len(v)) for k, v in calls.items()) == [
-        ("ssd_scan_bwd", 1), ("ssd_scan_fwd", 2)]
-    assert all("/ssm_scan/" in op for ops in calls.values() for op in ops)
+        ("ssd_scan_bwd", 1), ("ssd_scan_fwd", 2), ("ssm_conv_bwd", 3),
+        ("ssm_conv_fwd", 6)]
+    assert all("/ssm_scan/" in op for k in ("ssd_scan_fwd", "ssd_scan_bwd")
+               for op in calls[k])
     assert sum("rematted_computation" in op
                for op in calls["ssd_scan_fwd"]) == 1
     assert "transpose(" in calls["ssd_scan_bwd"][0]
     assert not re.findall(r"(?:bf16|f32)\[(?:\d+,)*64,128,128\]", text)
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert ssm.ssm_lowering(g, 8192, 2) == "chunked"
+
+
+def test_the_conv_takes_its_kernels_at_real_widths(one_chip, chip_branch,
+                                                   monkeypatch):
+    """At the cell's shape the convolution of x, B and C takes the Pallas
+    kernels on a TPU and XLA's ``_conv_act`` on the CPU; each kernel's
+    block fits 16 MiB of scoped VMEM by ``_conv_vmem``; and the mixer's
+    value and gradient compile to the forward kernel of each of the three
+    twice (the pass and its recomputation) and the backward one once, every
+    call inside the ``ssm_conv`` scope, with no float32 array of a
+    sequence's positions written in that scope (the parent's fusions wrote
+    ``f32[64,64,8192]`` and ``f32[8,8192,128]`` there)."""
+    import re
+    from sparknet_tpu.ops import ssm, ssm_kernels
+    lp, impl, shapes = _nemotron_layer("L0/mixer")
+    g = ssm.ssm_geometry(lp)
+    assert ssm.ssm_conv_lowering(g, 8192, 2) == "pallas"
+    for rows, positions_last in ((4096, True), (128, False)):
+        blocks = ssm_kernels.conv_blocks(8192, rows, 4, 2, positions_last)
+        assert blocks.fwd[1] == blocks.bwd[1] == 8192
+        for kernel, (chans, run) in zip(("fwd", "bwd"), blocks):
+            assert chans % 16 == 0 and ssm_kernels._conv_vmem(
+                kernel, chans, run, 4, positions_last, 2) <= 16 * 2**20
+    text = _mixer_value_and_grad(one_chip, lp, impl, shapes)
+    assert {k: v for k, v in _kernel_calls(text).items()
+            if k.startswith("ssm_conv")} == {"ssm_conv_fwd": 6,
+                                             "ssm_conv_bwd": 3}
+    conv = [line for line in text.splitlines()
+            if re.search(r'op_name="[^"]*/ssm_conv/', line)]
+    calls = [line for line in conv if '"tpu_custom_call"' in line]
+    assert len(calls) == 9
+    assert sum("rematted_computation" in line for line in calls) == 3
+    assert sum("transpose(" in line and "ssm_conv_bwd" in line
+               for line in calls) == 3
+    written = [re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+)", line)
+               for line in conv]
+    assert not [m.group(1) for m in written
+                if m and re.search(r"f32\[(?:\d+,)*8192", m.group(1))]
+    assert "f32[8,8192,128]" not in text
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ssm.ssm_conv_lowering(g, 8192, 2) == "xla"
 
 
 def test_expert_layer_of_nemotron_compiles_at_real_widths(one_chip,

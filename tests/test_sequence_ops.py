@@ -1450,6 +1450,145 @@ def test_the_scans_lowering_is_counted_with_its_blocks(monkeypatch):
                                        "heads": "8", "state": "128"}]
 
 
+def _conv_inputs(positions_last, positions, chans, dtype, kernel=4,
+                 groups=2, key=0):
+    """u in ``dtype`` as the mixer hands it over (``[H, 8, S]`` with the
+    positions on the lanes, ``[G, S, N]`` on the sublanes), the taps and
+    their bias float32 as PyTorch's default fills them, and a cotangent."""
+    r = jax.random.split(jax.random.PRNGKey(key), 4)
+    lead = (chans // 8, 8) if positions_last else (groups, chans)
+    shape = (*lead, positions) if positions_last else (
+        groups, positions, chans)
+    u = jax.random.normal(r[0], shape).astype(dtype)
+    taps = jax.random.uniform(r[1], (*lead, kernel), minval=-0.5,
+                              maxval=0.5)
+    bias = jax.random.uniform(r[2], lead, minval=-0.5, maxval=0.5)
+    return (u, taps, bias), jax.random.normal(r[3], shape).astype(dtype)
+
+
+def _conv_errors(positions_last, inputs, cot, blocks):
+    """The kernels (in Pallas' interpreter) against XLA's ``_conv_act``:
+    the value and the gradients of u, the taps and the bias, each the
+    largest difference over the largest magnitude."""
+    out = []
+    for path in ("xla", "pallas"):
+        y, vjp = jax.vjp(lambda *t: ssm.conv_act(*t, positions_last, path,
+                                                 blocks), *inputs)
+        out.append([np.asarray(t, np.float32) for t in (y, *vjp(cot))])
+    return [float(np.abs(g - w).max() / np.abs(w).max())
+            for w, g in zip(*out)]
+
+
+# (positions on the lanes, positions, channels, blocks of the forward and
+# backward kernels or None for ``conv_blocks``' own: the whole sequence)
+_CONV_CASES = [
+    (True, 512, 32, None), (True, 1024, 32, ((16, 256), (16, 512))),
+    (False, 256, 16, None), (False, 256, 128, ((128, 64), (128, 128))),
+]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "positions_last,positions,chans,blocks", _CONV_CASES,
+    ids=["lanes-one-block", "lanes-blocks", "sublanes-one-block",
+         "sublanes-blocks"])
+def test_the_conv_kernels_are_xlas_conv_act(positions_last, positions,
+                                            chans, blocks, dtype,
+                                            interpreted):
+    """The causal convolution's kernels, forward and backward, against
+    ``_conv_act`` with the positions on the lanes (x) and on the sublanes
+    (B and C), in float32 and in bfloat16, over one block and over several
+    blocks of channels and positions (the taps reaching into the block
+    before, the cotangent into the block after): the value and u's
+    gradient within a rounding of u's dtype, the taps' and bias's float32
+    gradients (sums over the positions, by block in the kernel) within
+    float32's."""
+    dtype = jnp.dtype(dtype)
+    inputs, cot = _conv_inputs(positions_last, positions, chans, dtype)
+    if blocks is None:
+        blocks = ssm_kernels.conv_blocks(positions, chans, 4, dtype.itemsize,
+                                         positions_last)
+        assert blocks.fwd[1] == blocks.bwd[1] == positions
+    else:
+        blocks = ssm_kernels.ConvBlocks(*blocks)
+    errors = _conv_errors(positions_last, inputs, cot, blocks)
+    near = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    assert max(errors[:2]) < near and max(errors[2:]) < 1e-5, errors
+
+
+@pytest.mark.parametrize("positions_last,positions,chans,blocks",
+                         [c for c in _CONV_CASES if c[3]],
+                         ids=["lanes-blocks", "sublanes-blocks"])
+def test_a_conv_kernel_that_drops_the_halo_is_told_apart(
+        monkeypatch, positions_last, positions, chans, blocks, interpreted):
+    """The same comparison refuses kernels that read zeros in place of the
+    tile beside a block (as at the sequence's ends) between blocks of
+    positions: the value, u's gradient and the taps' are far off."""
+    monkeypatch.setattr(ssm_kernels, "_halo",
+                        lambda tile, edge: jnp.zeros_like(tile))
+    inputs, cot = _conv_inputs(positions_last, positions, chans,
+                               jnp.float32)
+    errors = _conv_errors(positions_last, inputs, cot,
+                          ssm_kernels.ConvBlocks(*blocks))
+    assert min(errors[:3]) > 0.05, errors
+
+
+def test_the_convs_lowering_is_counted_with_its_blocks(monkeypatch):
+    """A trace of the mixer counts its convolution in
+    ``ssm_conv_lowering_total``: ``xla`` on the CPU; on a TPU (the
+    selection steered to its branch) ``pallas`` with the kernels' blocks of
+    x and of B and C, where the positions are whole lane rows, and ``xla``
+    where they are not."""
+    from sparknet_tpu.utils import telemetry
+
+    def traced(positions):
+        lp, impl, shapes = _mixer(positions)
+        jax.eval_shape(lambda p, x: impl.apply(lp, p, [x], True, None),
+                       shapes, jax.ShapeDtypeStruct((1, positions, 32),
+                                                    jnp.bfloat16))
+        samples = telemetry.get_registry().snapshot()[
+            "ssm_conv_lowering_total"]["samples"]
+        return [s["labels"] for s in samples]
+
+    telemetry.reset()
+    assert traced(1024) == [{"path": "xla"}]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    telemetry.reset()
+    assert traced(1024) == [{
+        "path": "pallas",
+        "blocks": "x fwd 64x1024 bwd 64x1024 bc fwd 128x1024 bwd 128x1024"}]
+    telemetry.reset()
+    assert traced(1000) == [{"path": "xla"}]
+
+
+def test_the_mixer_through_the_kernels_is_the_mixer_through_xla(
+        monkeypatch, interpreted):
+    """One mixer of a sequence of 256 on the TPU's branch, the scan's and
+    the convolution's kernels in Pallas' interpreter, against the same
+    mixer on XLA's forms: the value and the gradients of the input, the
+    taps and their bias, float32 throughout."""
+    lp, impl, shapes = _mixer(256)
+    params = impl.init(jax.random.PRNGKey(2), lp, [(1, 256, 32)])
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 256, 32))
+
+    def grads():
+        y, vjp = jax.vjp(lambda p, x: impl.apply(lp, p, [x], True, None)[0],
+                         params, x)
+        dp, dx = vjp(jax.random.normal(jax.random.PRNGKey(4), y.shape))
+        return [y, dx, dp[5], dp[6]]
+
+    from sparknet_tpu.utils import telemetry
+    with jax.default_matmul_precision("highest"):
+        want = grads()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        telemetry.reset()
+        got = grads()
+    assert [s["labels"]["path"] for s in telemetry.get_registry().snapshot()[
+        "ssm_conv_lowering_total"]["samples"]] == ["pallas"]
+    for g, w in zip(got, want):
+        assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 1e-4
+
+
 def test_gated_group_norm_normalises_each_group_of_heads():
     """``RMSNorm_grouped(y * silu(z)) * w`` over the published channel
     order ``h * P + p`` in groups of ``H * P / G`` channels; one norm over

@@ -40,8 +40,9 @@ run the same program where the lines agree; ``--out`` keeps the texts, for a
   layer whose backward pass is split, ``splash_mqa_dq_no_residuals``: a
   layer that takes the fused backward kernel has no ``dq`` call, its
   ``dkv`` call makes ``dq`` too; the state-space scan's ``ssd_scan_fwd``
-  and ``ssd_scan_bwd``): a ``copy`` or ``transpose`` that feeds
-  such a custom call, reads its result, or is attributed to its
+  and ``ssd_scan_bwd``; the mixer's causal convolution's ``ssm_conv_fwd``
+  and ``ssm_conv_bwd``): a ``copy`` or ``transpose`` that feeds such a
+  custom call, reads its result, or is attributed to its
   ``pallas_call``.  Prints ``{"edges": {program: {"kernel_calls": {...},
   "copies": [...], "copy_bytes": n, "written": {class: bytes},
   "conv_copies": [...], "dq_sums": [...]}}}`` (``written``: what the
@@ -124,7 +125,7 @@ CELLS = ("caffenet_train_resident", "googlenet_train_resident",
 # one the first two: its dkv kernel makes dq as well
 KERNELS = ("relu_lrn_fwd", "relu_lrn_bwd", "splash_mqa_fwd_residuals",
            "splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals",
-           "ssd_scan_fwd", "ssd_scan_bwd")
+           "ssd_scan_fwd", "ssd_scan_bwd", "ssm_conv_fwd", "ssm_conv_bwd")
 
 
 def cell_lowered(described: bool, names) -> dict[str, tuple[object, str]]:
